@@ -27,6 +27,9 @@ from victoriametrics_tpu.utils import costacc
 HERE = os.path.dirname(__file__)
 T0 = 1_753_700_000_000
 STEP = 60_000
+# T0 is a literal 2025-07-28: a stated retention, so that no merge drops
+# it whatever today's date is
+RETENTION_MS = 100 * 365 * 86_400_000
 
 def seed_rows():
     rows = []
@@ -43,7 +46,7 @@ class _Cluster:
     def __init__(self, tmp, n=2, **kw):
         self.stores, self.servers, nodes = [], [], []
         for k in range(n):
-            st = Storage(str(tmp / f"n{k}"))
+            st = Storage(str(tmp / f"n{k}"), retention_ms=RETENTION_MS)
             self.stores.append(st)
             h = make_storage_handlers(st)
             isrv = RPCServer("127.0.0.1", 0, HELLO_INSERT, h)
@@ -230,6 +233,11 @@ class TestProfileFanout:
         node ignoring the trailing flag simply keeps its window)."""
         monkeypatch.setenv("VM_PROFILE_HZ", "50")
         from victoriametrics_tpu.utils import profiler
+        # sample by hand only: the 50 Hz sampler that profile_v1 would
+        # start (or an earlier test left running) can tick between the
+        # nodes' reset and the assertion below
+        profiler.PROFILER.stop()
+        monkeypatch.setattr(profiler, "ensure_started", lambda: True)
         try:
             profiler.PROFILER.take_sample()
             reps = cluster.profile_report(reset=True)

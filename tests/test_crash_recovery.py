@@ -76,7 +76,9 @@ try:
 except FileNotFoundError:
     pass
 
-kw = {}
+# T0 is a literal 2025-07-28: a stated retention, so that no merge drops
+# it whatever today's date is
+kw = {"retention_ms": 100 * 365 * 86_400_000}
 if scenario == "retention":
     kw["retention_ms"] = 40 * 86_400_000
 elif scenario == "downsample":
@@ -249,7 +251,7 @@ def _verify_recovery(data_dir, ack_path, retention=False,
     """Reopen the store and check every recovery invariant; returns the
     acked batch list for extra assertions."""
     acked = _read_acked(ack_path)
-    kw = {"retention_ms": 40 * 86_400_000} if retention else {}
+    kw = {"retention_ms": (40 if retention else 100 * 365) * 86_400_000}
     s = Storage(str(data_dir), **kw)
     try:
         # crash injection never tears fsynced bytes: quarantine must stay

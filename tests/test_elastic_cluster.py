@@ -34,6 +34,9 @@ pytestmark = pytest.mark.skipif(zstd_missing,
                                 reason="no zstd codec available")
 
 T0 = 1_753_700_000_000
+# T0 is a literal 2025-07-28: a stated retention, so that no merge drops
+# it whatever today's date is
+RETENTION_MS = 100 * 365 * 86_400_000
 _REROUTES = metricslib.REGISTRY.counter("vm_reroute_reads_total")
 _MIGRATED = metricslib.REGISTRY.counter("vm_parts_migrated_total")
 _MOVED_BYTES = metricslib.REGISTRY.counter("vm_rebalance_moved_bytes_total")
@@ -43,7 +46,8 @@ class Node:
     """One in-process 'vmstorage': Storage + both RPC planes."""
 
     def __init__(self, tag: str):
-        self.store = Storage(tempfile.mkdtemp(prefix=f"elastic-{tag}-"))
+        self.store = Storage(tempfile.mkdtemp(prefix=f"elastic-{tag}-"),
+                             retention_ms=RETENTION_MS)
         handlers = make_storage_handlers(self.store)
         self.ins = RPCServer("127.0.0.1", 0, HELLO_INSERT, handlers)
         self.sel = RPCServer("127.0.0.1", 0, HELLO_SELECT, handlers)

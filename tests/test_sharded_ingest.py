@@ -38,6 +38,9 @@ needs_native = pytest.mark.requires_native
 
 T0 = 1_753_700_000_000  # 2025-07-28
 DAY = 86_400_000
+# T0 is a literal 2025-07-28: a stated retention, so that no merge drops
+# it whatever today's date is
+RETENTION_MS = 100 * 365 * 86_400_000
 
 
 def _hash_tree(root) -> dict:
@@ -61,6 +64,7 @@ def _observables(s) -> tuple:
 def _mk_store(path, shards, monkeypatch, **kw) -> "Storage":
     monkeypatch.setenv("VM_INGEST_SHARDS", str(shards))
     monkeypatch.setenv("VM_SEARCH_WORKERS", "4" if shards > 1 else "1")
+    kw.setdefault("retention_ms", RETENTION_MS)
     s = Storage(str(path), **kw)
     s._mid_gen._next = 1_000_000  # deterministic ids across runs
     return s

@@ -99,6 +99,10 @@ class TestBlocks:
 
 
 def mk_storage(tmp_path, **kw):
+    # T0 is a literal 2025-07-28: state a retention that does not depend
+    # on today's date (the 13-month default dropped it at merge from
+    # 2026-09-04 on)
+    kw.setdefault("retention_ms", 100 * 365 * 86_400_000)
     return Storage(str(tmp_path / "s"), **kw)
 
 

@@ -41,6 +41,9 @@ except ImportError:  # optional deps (zstandard) missing
 needs_native = pytest.mark.requires_native
 
 T0 = 1_753_700_000_000
+# T0 is a literal 2025-07-28: storages that merge state a retention that
+# does not depend on today's date
+RETENTION_MS = 100 * 365 * 86_400_000
 DURATION_S = 8.0
 N_WRITERS = 2
 SERIES_PER_WRITER = 24
@@ -141,7 +144,7 @@ class _Stress:
 
 @needs_native
 def test_concurrent_ingest_query_flush_snapshot(tmp_path):
-    s = Storage(str(tmp_path / "s"))
+    s = Storage(str(tmp_path / "s"), retention_ms=RETENTION_MS)
     st = _Stress(s)
     workers = [st.guard(st.writer(w)) for w in range(N_WRITERS)]
     workers += [st.guard(st.reader), st.guard(st.querier),
@@ -316,7 +319,7 @@ class TestLockTrace:
         """The real ingest/flush path runs clean under the tracer: the
         Table -> Partition -> flush-mutex hierarchy is acyclic."""
         monkeypatch.setenv("VMT_LOCKTRACE", "1")
-        s = Storage(str(tmp_path / "lt"))
+        s = Storage(str(tmp_path / "lt"), retention_ms=RETENTION_MS)
         t0 = 1_753_700_000_000
         s.add_rows([({"__name__": "lt", "i": str(i)}, t0 + i * 1000, 1.0)
                     for i in range(32)])

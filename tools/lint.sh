@@ -102,9 +102,8 @@ if [ "${VMT_NO_DOWNSAMPLE_SMOKE:-0}" != "1" ]; then
 fi
 # Persistent compile-cache smoke (devtools/compile_cache_smoke.py): a
 # second cold process must compile 0 kernels for a fleet bucket shape
-# the first process warmed — native jax cache AND the own-format
-# serialized-executable fallback.  Skips itself loudly when the runtime
-# supports neither; VMT_NO_COMPILE_CACHE_SMOKE=1 skips it outright.
+# the first process warmed (jax's persistent cache, shared through
+# JAX_COMPILATION_CACHE_DIR).  VMT_NO_COMPILE_CACHE_SMOKE=1 skips it.
 if [ "${VMT_NO_COMPILE_CACHE_SMOKE:-0}" != "1" ]; then
     env JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" \
         python -m victoriametrics_tpu.devtools.compile_cache_smoke

@@ -4,20 +4,12 @@ first process warmed.  Without this gate a jax upgrade or a config drift
 (min-compile-time threshold, cache-key salt) silently reverts every
 restart to paying the full fused-kernel compile storm.
 
-Two phases, two child processes each (same ``VM_COMPILE_CACHE_DIR``):
-
-1. ``native``  — jax's own persistent compilation cache, the production
-   path on supported runtimes;
-2. ``ownfmt``  — ``VM_OWN_EXEC_CACHE=1`` forces the own-format
-   serialized-executable fallback (query.tpu_engine.OwnExecutableCache),
-   the path for backends whose runtime jax's cache refuses.
-
-Each child compiles ONE small fleet bucket through the real mesh path
+Two child processes share one ``JAX_COMPILATION_CACHE_DIR`` (jax's own
+persistent compilation cache — the one cache there is).  Each child
+compiles ONE small fleet bucket through the real mesh path
 (parallel.mesh.cached_fleet_rollup_aggregate) and reports the
 backend-compile / cache-hit counters.  The warm child must report
-0 compiles and >= 1 hits.  A runtime where neither mechanism can work
-(compile-event telemetry unavailable, or the native cache refuses the
-backend AND serialization is unsupported) skips LOUDLY with exit 0.
+0 compiles and >= 1 hits.
 ``VMT_NO_COMPILE_CACHE_SMOKE=1`` skips from tools/lint.sh / check.sh.
 """
 
@@ -76,19 +68,15 @@ def _child() -> int:
     out = np.asarray(fn(ts, vals, counts, gids, aggr, shift, min_ts, v0))
     assert out.shape == (B, G, T), out.shape
     assert np.isfinite(out).any(), "fleet smoke kernel produced no values"
-    print(json.dumps({
-        "compiles": te.backend_compiles(),
-        "hits": te.compile_cache_hits(),
-        "telemetry": te._COMPILE_EVENTS_SET,
-        "native_refused": te.jax_cache_refused(),
-    }))
+    print(json.dumps({"compiles": te.backend_compiles(),
+                      "hits": te.compile_cache_hits()}))
     return 0
 
 
 def _warmup() -> int:
     """``tools/device.sh warmup``: pre-compile the fleet kernel for the
     deployment's common bucket shapes into the persistent cache
-    (``VM_COMPILE_CACHE_DIR``), so the serving process after the next
+    (query.tpu_engine.enable_compilation_cache), so the serving process after the next
     restart deserializes instead of paying the cold compile storm.
     ``VM_WARMUP_FUNCS`` (default rate), ``VM_WARMUP_SHAPE`` ("B,S,N,T,G"
     ladder rungs), ``VM_WARMUP_STEP_MS`` and ``VM_WARMUP_WINDOW_MS``
@@ -136,15 +124,11 @@ def _warmup() -> int:
     return 0
 
 
-def _spawn(cache_dir: str, own_fmt: bool) -> dict:
+def _spawn(cache_dir: str) -> dict:
     env = dict(os.environ)
-    env.update(VM_COMPILE_CACHE_DIR=cache_dir,
+    env.update(JAX_COMPILATION_CACHE_DIR=cache_dir,
                JAX_PLATFORMS=env.get("JAX_PLATFORMS", "cpu"),
                JAX_ENABLE_X64="1")
-    if own_fmt:
-        env["VM_OWN_EXEC_CACHE"] = "1"
-    else:
-        env.pop("VM_OWN_EXEC_CACHE", None)
     p = subprocess.run(
         [sys.executable, "-m",
          "victoriametrics_tpu.devtools.compile_cache_smoke", "--child"],
@@ -160,42 +144,27 @@ def main() -> int:
         return _child()
     if "--warmup" in sys.argv:
         return _warmup()
-    failures = []
-    for phase in ("native", "ownfmt"):
-        tmp = tempfile.mkdtemp(prefix=f"ccache-smoke-{phase}-")
-        try:
-            cold = _spawn(tmp, own_fmt=phase == "ownfmt")
-            if not cold["telemetry"]:
-                print("compile-cache smoke: SKIP (jax compile-event "
-                      "telemetry unavailable; counters are meaningless)")
-                return 0
-            if cold["compiles"] < 1:
-                failures.append(f"{phase}: cold child reported "
-                                f"{cold['compiles']} compiles; expected >=1")
-                continue
-            if phase == "native" and cold["native_refused"]:
-                print("compile-cache smoke: SKIP native phase (backend "
-                      "refuses jax's persistent cache; own-format phase "
-                      "still gates)")
-                continue
-            warm = _spawn(tmp, own_fmt=phase == "ownfmt")
-            if warm["compiles"] != 0:
-                failures.append(
-                    f"{phase}: warm child recompiled "
-                    f"{warm['compiles']} kernels for a warmed shape")
-            elif warm["hits"] < 1:
-                failures.append(f"{phase}: warm child never ticked "
-                                "vm_device_fleet_compile_cache_hits_total")
-            else:
-                print(f"compile-cache smoke: {phase} OK "
-                      f"(cold {cold['compiles']} compiles -> warm "
-                      f"{warm['compiles']}, {warm['hits']} cache hits)")
-        finally:
-            shutil.rmtree(tmp, ignore_errors=True)
-    if failures:
-        print("compile-cache smoke: FAIL\n  " + "\n  ".join(failures))
-        return 1
-    return 0
+    tmp = tempfile.mkdtemp(prefix="ccache-smoke-")
+    try:
+        cold = _spawn(tmp)
+        warm = _spawn(tmp)
+    finally:
+        shutil.rmtree(tmp, ignore_errors=True)
+    if cold["compiles"] < 1:
+        failure = (f"cold child reported {cold['compiles']} compiles; "
+                   "expected >=1")
+    elif warm["compiles"] != 0:
+        failure = (f"warm child recompiled {warm['compiles']} kernels "
+                   "for a warmed shape")
+    elif warm["hits"] < 1:
+        failure = ("warm child never ticked "
+                   "vm_device_fleet_compile_cache_hits_total")
+    else:
+        print(f"compile-cache smoke: OK (cold {cold['compiles']} compiles "
+              f"-> warm {warm['compiles']}, {warm['hits']} cache hits)")
+        return 0
+    print(f"compile-cache smoke: FAIL\n  {failure}")
+    return 1
 
 
 if __name__ == "__main__":

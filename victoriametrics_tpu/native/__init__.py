@@ -1,8 +1,9 @@
 """ctypes bindings for the native host codec kernels (codec.cpp).
 
-Auto-builds libvmcodec.so with g++ on first import if missing (and a
-compiler is available); falls back to None so callers keep their NumPy
-paths. This mirrors the reference's cgo-zstd-with-pure-Go-fallback split
+Auto-builds libvmcodec.so with g++ on first import if it is missing or
+older than its sources (and a compiler is available); falls back to None
+so callers keep their NumPy paths. This mirrors the reference's
+cgo-zstd-with-pure-Go-fallback split
 (lib/encoding/zstd/zstd_{cgo,pure}.go).
 """
 
@@ -17,7 +18,20 @@ import numpy as np
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SO = os.path.join(_DIR, "libvmcodec.so")
 
+_SOURCES = ("codec.cpp", "parse.cpp", "ingest.cpp", "Makefile")
+
 _lib = None
+
+
+def _stale() -> bool:
+    """The (git-ignored) library is missing or older than what it is
+    built from."""
+    try:
+        built = os.path.getmtime(_SO)
+    except OSError:
+        return True
+    return any(os.path.getmtime(os.path.join(_DIR, f)) > built
+               for f in _SOURCES)
 
 
 def _build() -> bool:
@@ -33,7 +47,7 @@ def _load():
     global _lib
     if _lib is not None:
         return _lib
-    if not os.path.exists(_SO) and not _build():
+    if _stale() and not _build():
         return None
     try:
         lib = _configure(ctypes.CDLL(_SO))

@@ -562,6 +562,16 @@ def rollup_tile(func: str, ts: jnp.ndarray, values: jnp.ndarray,
 AGGR_FUNCS = ("sum", "count", "avg", "min", "max", "group", "stddev", "stdvar")
 
 
+def _onehot_sum(onehot: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
+    """Group sums as [G, S] one-hot @ [S, T] on the MXU, at FULL f32
+    precision: a TPU's default matmul precision rounds f32 operands to
+    bf16 (one pass), which read 1.3e-3 relative on sum(rate) on a v5e
+    against the 1e-5 bound (chip_smoke.py, PR 22). HIGHEST is the
+    multi-pass bf16 decomposition; backends with native f32/f64 matmuls
+    ignore it."""
+    return jnp.matmul(onehot, x, precision=jax.lax.Precision.HIGHEST)
+
+
 def partial_group_moments(aggr: str, rolled: jnp.ndarray,
                           group_ids: jnp.ndarray, num_groups: int
                           ) -> dict[str, tuple[jnp.ndarray, str]]:
@@ -588,12 +598,12 @@ def partial_group_moments(aggr: str, rolled: jnp.ndarray,
         def seg(x):
             return jax.lax.cond(
                 all_finite,
-                lambda y: onehot @ y,
+                lambda y: _onehot_sum(onehot, y),
                 lambda y: jax.ops.segment_sum(y, group_ids,
                                               num_segments=num_groups),
                 x)
 
-        cnt = onehot @ present.astype(rolled.dtype)
+        cnt = _onehot_sum(onehot, present.astype(rolled.dtype))
     else:
         def seg(x):
             return jax.ops.segment_sum(x, group_ids,
@@ -679,12 +689,12 @@ def _fleet_group_aggregate(rolled: jnp.ndarray, group_ids: jnp.ndarray,
         def seg(x):
             return jax.lax.cond(
                 all_finite,
-                lambda y: onehot @ y,
+                lambda y: _onehot_sum(onehot, y),
                 lambda y: jax.ops.segment_sum(y, group_ids,
                                               num_segments=num_groups),
                 x)
 
-        cnt = onehot @ present.astype(rolled.dtype)
+        cnt = _onehot_sum(onehot, present.astype(rolled.dtype))
     else:
         def seg(x):
             return jax.ops.segment_sum(x, group_ids,

@@ -30,10 +30,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:
-    from jax import shard_map
-except ImportError:  # pre-0.5 jax exposes it under experimental only
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops.device_rollup import rollup_tile
@@ -87,8 +84,7 @@ def cached_fleet_rollup_aggregate(mesh: Mesh, rollup_func: str,
             fleet_counts, fleet_gids, fleet_aggr, fleet_shift,
             fleet_min_ts, fleet_v0)
 
-    from ..query.tpu_engine import with_executable_cache
-    return with_executable_cache(step, f"fleet_rollup:{rollup_func}")
+    return step
 
 
 @functools.lru_cache(maxsize=256)
