@@ -1061,10 +1061,10 @@ def fleet_device_main() -> None:
             plane = engine.fleet()
             walls = []
 
-            def spy(name, t_s, dur, arg=None):
+            def spy(name, t_s, dur, *rest, **kw):
                 if name == "device:fleet_launch":
                     walls.append(dur)
-                return orig_rec(name, t_s, dur, arg)
+                return orig_rec(name, t_s, dur, *rest, **kw)
 
             flightrec.rec = spy
 

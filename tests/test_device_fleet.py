@@ -309,10 +309,10 @@ def test_fleet_cost_split_sums_to_launch_total(tmp_path, monkeypatch):
     walls = []
     orig_rec = flightrec.rec
 
-    def spy(name, t0, dur, arg=None):
+    def spy(name, t0, dur, *rest, **kw):
         if name == "device:fleet_launch":
             walls.append(dur)
-        return orig_rec(name, t0, dur, arg)
+        return orig_rec(name, t0, dur, *rest, **kw)
 
     monkeypatch.setattr(flightrec, "rec", spy)
     try:

@@ -108,6 +108,34 @@ class TestRing:
         # (other threads' rings may contribute more, never less)
         assert dropped.get() - d0 >= 12
 
+    def test_ring_grows_to_what_its_thread_records(self):
+        """A ring starts small and doubles as its thread records: every
+        event before the first wrap survives each doubling, in order,
+        and growth stops at the capacity."""
+        out = {}
+
+        def run():
+            base = time.perf_counter()
+            out["size0"] = None
+            for k in range(700):
+                flightrec.rec(f"grow:{k}", base + k * 1e-7, 1e-8, None,
+                              k % 3)
+                if k == 0:
+                    out["size0"] = flightrec._tls.ring.size
+            out["ring"] = flightrec._tls.ring
+
+        t = threading.Thread(target=run)
+        t.start()
+        t.join(10)
+        ring = out["ring"]
+        assert out["size0"] == flightrec._INITIAL_SLOTS < ring.cap
+        assert ring.size == 1024 and ring.mask == 1023 and ring.i == 700
+        snap = ring.snapshot(0.0)
+        assert [e[2] for e in snap] == [f"grow:{k}" for k in range(700)]
+        assert [e[6] for e in snap] == [k % 3 for k in range(700)]
+        assert all(len(lst) == ring.size for lst in (
+            ring.t0, ring.dur, ring.name, ring.ctx, ring.arg, ring.depth))
+
     def test_taken_is_first_uncaptured_cursor(self, monkeypatch):
         """After a capture, ring.taken points at the first cursor NOT
         yet captured — so a later wrap past already-captured events
@@ -318,7 +346,7 @@ class TestCrossThreadPropagation:
             info["ctx"] = flightrec.get_ctx()
             with querytracer.current().new_child("worker side") as c:
                 c.donef("ok")
-            with flightrec.span("t:worker"):
+            with flightrec.phase("t:worker"):
                 time.sleep(0.001)
             return 42
 
@@ -486,7 +514,7 @@ class TestRaceStress:
             try:
                 n = 0
                 while not stop.is_set() and n < 20_000:
-                    with flightrec.span(f"race:w{k}", arg=n):
+                    with flightrec.phase(f"race:w{k}", arg=n):
                         n += 1
                     flightrec.instant(f"race:i{k}")
             except Exception as e:  # noqa: BLE001 — reported below

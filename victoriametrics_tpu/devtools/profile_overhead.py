@@ -25,19 +25,16 @@ import time
 
 import numpy as np
 
-from ..utils import costacc, profiler
+from ..utils import costacc, flightrec, profiler
 
 
 def _workload(arr: np.ndarray, laps: int) -> None:
-    """One simulated refresh: numpy work + the cost-accounting laps the
-    real serving path records (a tracker is installed, so the laps take
-    their real, non-short-circuited path)."""
-    t0 = time.perf_counter()
+    """One simulated refresh: numpy work inside the phases the real
+    serving path records (a tracker is installed, so their laps take
+    the real, non-short-circuited path)."""
     for k in range(laps):
-        arr[k % 8] = np.sqrt(arr[(k + 1) % 8]).sum()
-        now = time.perf_counter()
-        costacc.lap("smoke:phase", now - t0)
-        t0 = now
+        with flightrec.phase("smoke:phase"):
+            arr[k % 8] = np.sqrt(arr[(k + 1) % 8]).sum()
 
 
 def _time_workload(reps: int, laps: int, arr: np.ndarray) -> float:

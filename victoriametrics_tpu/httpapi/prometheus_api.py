@@ -108,7 +108,10 @@ class ConcurrencyGate:
         return self._rejected.get()
 
     def __enter__(self):
-        if not self._sem.acquire(timeout=self.max_queue_duration_s):
+        # serve:admission times the WAIT for a slot, not the hold
+        with flightrec.phase("serve:admission"):
+            admitted = self._sem.acquire(timeout=self.max_queue_duration_s)
+        if not admitted:
             self._rejected.inc()
             raise TimeoutError(
                 f"query queue wait exceeded {self.max_queue_duration_s}s "
@@ -254,8 +257,8 @@ class PrometheusAPI:
 
     def _register_select(self, srv: HTTPServer):
         r = srv.route
-        r("/api/v1/query", self.h_query)
-        r("/api/v1/query_range", self.h_query_range)
+        r("/api/v1/query", self.h_query, query_root=True)
+        r("/api/v1/query_range", self.h_query_range, query_root=True)
         r("/api/v1/watch", self.h_watch)
         r("/api/v1/series", self.h_series)
         r("/api/v1/labels", self.h_labels)
@@ -421,8 +424,11 @@ class PrometheusAPI:
         slow-query log (cost columns included), attaching any flight
         capture the eval noted."""
         from ..utils import costacc, querytracer
-        fctx = flightrec.new_ctx()
-        prev_ctx = flightrec.set_ctx(fctx)
+        # the request's root phase (httpapi/server.py) already gave the
+        # thread its flight context; direct callers get a fresh one
+        prev_ctx = flightrec.get_ctx()
+        fctx = prev_ctx or flightrec.new_ctx()
+        flightrec.set_ctx(fctx)
         prev_tr = querytracer.set_current(qt)
         cost = ec._cost if ec is not None else None
         prev_cost = costacc.set_current(cost)
@@ -485,13 +491,14 @@ class PrometheusAPI:
         denied = self._partial_guard(req)
         if denied is not None:
             return denied
-        result = []
-        for r in rows:
-            v = r.values[-1]
-            if math.isnan(v):
-                continue
-            result.append({"metric": r.metric_name.to_dict(),
-                           "value": [ts / 1e3, _fmt_value(v)]})
+        with flightrec.phase("serve:rows"):
+            result = []
+            for r in rows:
+                v = r.values[-1]
+                if math.isnan(v):
+                    continue
+                result.append({"metric": r.metric_name.to_dict(),
+                               "value": [ts / 1e3, _fmt_value(v)]})
         qt.donef("%d result series", len(result))
         body = {"status": "success",
                 "isPartial": bool(getattr(self.storage, "last_partial",
@@ -501,7 +508,8 @@ class PrometheusAPI:
                 "data": {"resultType": "vector", "result": result}}
         if qt.enabled:
             body["trace"] = qt.to_dict()
-        return Response.json(body)
+        with flightrec.phase("serve:json"):
+            return Response.json(body)
 
     def h_query_range(self, req: Request) -> Response:
         q = req.arg("query")
@@ -560,14 +568,16 @@ class PrometheusAPI:
         denied = self._partial_guard(req)
         if denied is not None:
             return denied
-        grid = ec.timestamps() / 1e3
-        result = []
-        for r in rows:
-            vals = [[float(t), _fmt_value(v)]
-                    for t, v in zip(grid, r.values) if not math.isnan(v)]
-            if vals:
-                result.append({"metric": r.metric_name.to_dict(),
-                               "values": vals})
+        with flightrec.phase("serve:rows"):
+            grid = ec.timestamps() / 1e3
+            result = []
+            for r in rows:
+                vals = [[float(t), _fmt_value(v)]
+                        for t, v in zip(grid, r.values)
+                        if not math.isnan(v)]
+                if vals:
+                    result.append({"metric": r.metric_name.to_dict(),
+                                   "values": vals})
         qt.donef("%d result series", len(result))
         body = {"status": "success",
                 "isPartial": bool(getattr(self.storage, "last_partial",
@@ -577,7 +587,8 @@ class PrometheusAPI:
                 "data": {"resultType": "matrix", "result": result}}
         if qt.enabled:
             body["trace"] = qt.to_dict()
-        return Response.json(body)
+        with flightrec.phase("serve:json"):
+            return Response.json(body)
 
     def h_watch(self, req: Request) -> Response:
         """Materialized-stream subscription push (``/api/v1/watch?query=
@@ -699,22 +710,20 @@ class PrometheusAPI:
         # only through this install
         from ..utils import costacc
         prev_cost = costacc.set_current(ec._cost)
-        w0 = ec._cost.local_wall_ms_total()
-        t0 = time.perf_counter()
+        # serve:other is this phase's SELF time: the refresh wall no
+        # eval / cache phase below claims (row sort/filter, result
+        # handling) — glue the cost split can SEE, not glue that
+        # vanished
+        ph = flightrec.phase("serve:other")
         try:
-            with workpool.serving():
+            with ph, workpool.serving():
                 return self._exec_range_cached_serving(ec, q, now_ms)
         finally:
-            dur = time.perf_counter() - t0
-            # refresh wall not claimed by any LOCAL phase/eval lap
-            # (cache get, row sort/filter, result handling) gets its own
-            # named bucket — the bench's >=90%-accounted honesty ratio
-            # counts glue it can SEE, not glue that vanished.  Local-lap
-            # baseline only: merged remote laps are concurrent
-            inner_ms = ec._cost.local_wall_ms_total() - w0
-            if dur * 1e3 > inner_ms:
-                costacc.lap("serve:other", dur - inner_ms / 1e3)
+            t0, dur = ph.t0, ph.dur
             costacc.set_current(prev_cost)
+            # the whole refresh as ONE container span: what the capture
+            # summary explains and the slow-query log reports beside the
+            # disjoint phases
             flightrec.rec("serve:refresh", t0, dur, arg=q[:200])
             if fresh_ctx:
                 flightrec.clear_ctx()

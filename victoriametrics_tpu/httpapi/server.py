@@ -15,7 +15,7 @@ from ..ingest.ratelimiter import RateLimitedError
 from ..ops import compress as zstd
 from ..parallel.rpc import (ClusterUnavailableError, PartialResultError,
                             RPCError)
-from ..utils import logger
+from ..utils import flightrec, logger
 from ..utils import metrics as metricslib
 from ..utils.workpool import SearchLimitError
 
@@ -94,6 +94,9 @@ class HTTPServer:
                  tls_cert_file: str = "", tls_key_file: str = ""):
         self.routes: dict[str, object] = {}
         self.prefix_routes: list[tuple[str, object]] = []
+        #: route patterns served under a request root phase (the query
+        #: routes: ``route(..., query_root=True)``)
+        self.query_routes: set[str] = set()
         self._path_metric_memo: dict[str, tuple] = {}
         self.auth_key = auth_key
         self.basic_auth = basic_auth
@@ -108,7 +111,7 @@ class HTTPServer:
             def log_message(self, fmt, *args):  # quiet
                 pass
 
-            def _handle(self):
+            def _serve(self):
                 outer._request_count.inc()
                 ln = int(self.headers.get("Content-Length") or 0)
                 body = self.rfile.read(ln) if ln else b""
@@ -134,6 +137,19 @@ class HTTPServer:
                     self._send(Response.error(
                         f"unsupported path {req.path}", 404, "not_found"))
                     return
+                if pattern in outer.query_routes:
+                    # the request's ROOT phase: body read to last byte
+                    # written, so its phases' self times (admission,
+                    # eval, fetch, cache, device, rows, json, send, and
+                    # its own = serve:other) partition the served wall
+                    with flightrec.phase("serve:other", root=True):
+                        self._handle(fn, pattern, req)
+                else:
+                    self._handle(fn, pattern, req)
+
+            def _handle(self, fn, pattern: str, req: Request):
+                """The error boundary: run the route, map what it raises
+                to a status, send the response."""
                 requests, duration, errors = outer._path_metrics(pattern)
                 requests.inc()
                 t0 = time.perf_counter()
@@ -178,22 +194,24 @@ class HTTPServer:
                 if isinstance(resp, StreamingResponse):
                     self._send_stream(resp)
                     return
-                body = resp.body
-                accept = (self.headers.get("Accept-Encoding") or "")
-                headers = dict(resp.headers)
-                if len(body) > 256 and "gzip" in accept:
-                    body = gzip.compress(body, 1)
-                    headers["Content-Encoding"] = "gzip"
-                try:
-                    self.send_response(resp.status)
-                    self.send_header("Content-Type", resp.content_type)
-                    self.send_header("Content-Length", str(len(body)))
-                    for k, v in headers.items():
-                        self.send_header(k, v)
-                    self.end_headers()
-                    self.wfile.write(body)
-                except (BrokenPipeError, ConnectionResetError):
-                    pass
+                # serve:send: gzip where asked, headers, socket write
+                with flightrec.phase("serve:send"):
+                    body = resp.body
+                    accept = (self.headers.get("Accept-Encoding") or "")
+                    headers = dict(resp.headers)
+                    if len(body) > 256 and "gzip" in accept:
+                        body = gzip.compress(body, 1)
+                        headers["Content-Encoding"] = "gzip"
+                    try:
+                        self.send_response(resp.status)
+                        self.send_header("Content-Type", resp.content_type)
+                        self.send_header("Content-Length", str(len(body)))
+                        for k, v in headers.items():
+                            self.send_header(k, v)
+                        self.end_headers()
+                        self.wfile.write(body)
+                    except (BrokenPipeError, ConnectionResetError):
+                        pass
 
             def _send_stream(self, resp: "StreamingResponse"):
                 # no Content-Length: the response ends when the chunk
@@ -232,7 +250,7 @@ class HTTPServer:
                             logger.errorf("stream on_close %s: %s",
                                           self.path, e)
 
-            do_GET = do_POST = do_PUT = do_DELETE = _handle
+            do_GET = do_POST = do_PUT = do_DELETE = _serve
 
         self._handler_cls = Handler
         self._srv = ThreadingHTTPServer((addr, port), Handler)
@@ -255,7 +273,9 @@ class HTTPServer:
     def request_count(self) -> int:
         return self._request_count.get()
 
-    def route(self, path: str, fn):
+    def route(self, path: str, fn, query_root: bool = False):
+        if query_root:
+            self.query_routes.add(path)
         if path.endswith("/"):
             self.prefix_routes.append((path, fn))
         else:

@@ -14,7 +14,6 @@ from __future__ import annotations
 
 import collections
 import os
-import time as _time
 import weakref
 
 import jax
@@ -58,23 +57,14 @@ def bytes_downloaded() -> int:
 
 
 def timed_transfer(span: str, nbytes: int, fn):
-    """Run one H2D/D2H transfer `fn`, counting its bytes and recording a
-    flight span for transfers big enough to matter — the ONE place the
-    device:upload/device:download span shape is defined (shard_put,
-    chunked_device_put and the kernel-result pull all funnel here)."""
+    """Run one H2D/D2H transfer `fn`, counting its bytes and timing it
+    as a `span` phase whatever its size (a dashboard tick's sub-MiB
+    append is a transfer too) — the ONE place the device:upload /
+    device:download phase is defined (shard_put, chunked_device_put and
+    the kernel-result pull all funnel here)."""
     (count_upload if span == "device:upload" else count_download)(nbytes)
-    if nbytes < (1 << 20):
+    with _flightrec.phase(span, arg=nbytes):
         return fn()
-    t0 = _time.perf_counter()
-    try:
-        return fn()
-    finally:
-        dt = _time.perf_counter() - t0
-        _flightrec.rec(span, t0, dt, arg=nbytes)
-        # cost plane: transfer wall is link time, not this thread's CPU
-        tr = _costacc.current()
-        if tr is not None:
-            tr.lap(span, dt, 0.0)
 
 
 # cache self-metrics (reference vm_cache_{requests,misses}_total +

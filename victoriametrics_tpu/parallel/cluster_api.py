@@ -21,7 +21,7 @@ import numpy as np
 from ..devtools.locktrace import make_lock
 from ..storage.metric_name import MetricName
 from ..storage.tag_filters import TagFilter
-from ..utils import costacc, logger, querytracer
+from ..utils import costacc, flightrec, logger, querytracer
 from ..utils import metrics as metricslib
 from . import ringfilter
 from .consistenthash import ConsistentHash
@@ -1722,6 +1722,16 @@ class ClusterStorage:
         as single-node reads. Replica overlap is handled by assemble()'s
         per-row sort fix + exact-duplicate-timestamp dedup (keep last),
         identical to the old per-series merge semantics."""
+        # the calling thread's wall while the nodes fetch (the single-
+        # node Storage.search_columns opens the same phase)
+        with flightrec.phase("fetch:wait"):
+            return self._search_columns_fanout(
+                filters, min_ts, max_ts, dedup_interval_ms, max_series,
+                tenant, tracer, deadline)
+
+    def _search_columns_fanout(self, filters, min_ts, max_ts,
+                               dedup_interval_ms, max_series, tenant,
+                               tracer, deadline):
         from ..storage.columnar import ColumnarSeries, assemble
         self._search_fanouts.inc()
         self._search_fanouts_counter.inc()

@@ -13,6 +13,8 @@ import numpy as np
 from ..ops.rollup_np import RollupConfig
 from ..storage.metric_name import MetricName
 from ..storage.tag_filters import TagFilter
+from ..utils import flightrec
+from ..utils import metrics as metricslib
 from .aggr_funcs import (PER_SERIES, SIMPLE, a_quantile, series_rank_metric,
                          topk_mask_per_ts)
 from .binary_op import ARITH_OPS, CMP_OPS, eval_binary_op
@@ -30,17 +32,12 @@ nan = np.nan
 # host-rollup share of vm_fetch_phase_seconds_total (storage/storage.py
 # owns the fetch-side phases; bench.py reads the whole family to
 # attribute a refresh between index/collect/decode/assemble/rollup)
-def _rollup_phase_lap(t0: float) -> None:
-    import time as _t
+_ROLLUP_PHASE = metricslib.REGISTRY.float_counter(
+    'vm_fetch_phase_seconds_total{phase="rollup"}')
 
-    from ..utils import costacc as _costacc
-    from ..utils import flightrec as _flightrec
-    from ..utils import metrics as _metricslib
-    now = _t.perf_counter()
-    _metricslib.REGISTRY.float_counter(
-        'vm_fetch_phase_seconds_total{phase="rollup"}').inc(now - t0)
-    _flightrec.rec("fetch:rollup", t0, now - t0)
-    _costacc.lap("fetch:rollup", now - t0)
+
+def _rollup_phase() -> flightrec.phase:
+    return flightrec.phase("fetch:rollup", counter=_ROLLUP_PHASE)
 
 
 class QueryError(ValueError):
@@ -469,22 +466,20 @@ def _rollup_from_storage_cols(ec: EvalConfig, func: str, re_: RollupExpr,
                                            step=cfg.step, window=a)
                               for a in adj]
     with admission:
-        import time as _time
-        t0r = _time.perf_counter()
         if per_series_cfg is None:
             with ec.tracer.new_child("host rollup %s (columns)",
                                      func) as qt:
-                rows = rollup_np.rollup_batch_packed(func, cols.ts,
-                                                     cols.vals, cols.counts,
-                                                     cfg, args)
+                with _rollup_phase():
+                    rows = rollup_np.rollup_batch_packed(
+                        func, cols.ts, cols.vals, cols.counts, cfg, args)
                 if rows is not None:
-                    _rollup_phase_lap(t0r)
                     qt.donef("%d series (packed)", cols.n_series)
                     return _cache_rollup(ec, ckey,
                                          _finish_rollup_cols(cols, rows,
                                                              keep_name))
                 qt.donef("fell back to per-series (non-finite values)")
-        with ec.tracer.new_child("host rollup %s (per-series)", func) as qt:
+        with ec.tracer.new_child("host rollup %s (per-series)", func) as qt, \
+                _rollup_phase():
             out_rows = []
             counts = cols.counts
             for i in range(cols.n_series):
@@ -494,7 +489,6 @@ def _rollup_from_storage_cols(ec: EvalConfig, func: str, re_: RollupExpr,
                 c = per_series_cfg[i] if per_series_cfg is not None else cfg
                 out_rows.append(rollup_series(func, cols.ts[i, :n],
                                               cols.vals[i, :n], c, args))
-            _rollup_phase_lap(t0r)
             qt.donef("%d series", cols.n_series)
         return _cache_rollup(ec, ckey,
                              _finish_rollup_cols(cols, out_rows, keep_name))
@@ -1352,6 +1346,28 @@ def _try_device_fused_aggr(ec: EvalConfig, ae: AggrFuncExpr
     return _emit(out, group_keys)
 
 
+def _host_rollup_rows(ec: EvalConfig, func: str, cols, cfg,
+                      per_series_cfg, T: int):
+    """[S, T] host rollup of a ColumnarSeries for the fused aggregates:
+    the packed batch kernel, else (non-finite values / per-series
+    windows) one rollup per series."""
+    from ..ops import rollup_np
+    rows = None
+    if per_series_cfg is None:
+        rows = rollup_np.rollup_batch_packed(
+            func, cols.ts, cols.vals, cols.counts, cfg, ())
+    if rows is None:
+        counts = cols.counts
+        rows = np.empty((cols.n_series, T))
+        for i in range(cols.n_series):
+            if i % 256 == 0:
+                ec.check_deadline()
+            c = per_series_cfg[i] if per_series_cfg is not None else cfg
+            rows[i] = rollup_series(func, cols.ts[i, :counts[i]],
+                                    cols.vals[i, :counts[i]], c, ())
+    return rows
+
+
 _CHUNK_AGGRS = frozenset({"sum", "count", "avg", "min", "max"})
 
 
@@ -1478,24 +1494,9 @@ def _try_host_chunked_aggr(ec: EvalConfig, ae) -> list[Timeseries] | None:
                             RollupConfig(start=start, end=end,
                                          step=ec.step, window=a)
                             for a in adj]
-                import time as _time
-                t0r = _time.perf_counter()
-                rows = None
-                if per_series_cfg is None:
-                    rows = rollup_np.rollup_batch_packed(
-                        func, cols.ts, cols.vals, cols.counts, cfg, ())
-                if rows is None:  # non-finite values / per-series windows
-                    counts = cols.counts
-                    rows = np.empty((cols.n_series, T))
-                    for i in range(cols.n_series):
-                        if i % 256 == 0:
-                            ec.check_deadline()
-                        c = (per_series_cfg[i]
-                             if per_series_cfg is not None else cfg)
-                        rows[i] = rollup_series(
-                            func, cols.ts[i, :counts[i]],
-                            cols.vals[i, :counts[i]], c, ())
-                _rollup_phase_lap(t0r)
+                with _rollup_phase():
+                    rows = _host_rollup_rows(ec, func, cols, cfg,
+                                             per_series_cfg, T)
                 rows = np.asarray(rows, dtype=np.float64)
                 gids = np.empty(cols.n_series, np.int64)
                 for i, mn in enumerate(cols.metric_names):
@@ -1663,25 +1664,10 @@ def _host_fused_aggr_compute(ec: EvalConfig, ae, func: str, rarg,
                         RollupConfig(start=cfg.start, end=cfg.end,
                                      step=cfg.step, window=a)
                         for a in adj]
-            import time as _time
-            t0r = _time.perf_counter()
-            rows = None
-            if per_series_cfg is None:
-                rows = rollup_np.rollup_batch_packed(
-                    func, cols.ts, cols.vals, cols.counts, cfg, ())
-            if rows is None:  # non-finite values / per-series windows
-                counts = cols.counts
-                rows = np.empty((cols.n_series, T))
-                for i in range(cols.n_series):
-                    if i % 256 == 0:
-                        ec.check_deadline()
-                    c = (per_series_cfg[i]
-                         if per_series_cfg is not None else cfg)
-                    rows[i] = rollup_series(func, cols.ts[i, :counts[i]],
-                                            cols.vals[i, :counts[i]], c,
-                                            ())
-            rows = np.asarray(rows, dtype=np.float64)
-            _rollup_phase_lap(t0r)
+            with _rollup_phase():
+                rows = np.asarray(
+                    _host_rollup_rows(ec, func, cols, cfg, per_series_cfg,
+                                      T), dtype=np.float64)
             group_keys, order, group_rows = _fused_group_ids(
                 ec, ae, cols, keep_name, f"{func}|{rarg}")
             G = len(group_keys)

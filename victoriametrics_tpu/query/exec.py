@@ -8,6 +8,7 @@ import threading
 
 import numpy as np
 
+from ..utils import costacc, flightrec
 from .eval import QueryError, eval_expr
 from .metricsql import parse
 from .metricsql.ast import Expr
@@ -54,35 +55,24 @@ _SORT_FUNCS = frozenset({
 def exec_query(ec: EvalConfig, q: str) -> list[Timeseries]:
     """Range query: returns series on the ec grid, sorted by labels unless
     the top-level function imposes its own order (exec.go:80-100 analog)."""
-    expr = parse_cached(q)
     # every storage/cache/device seam under this eval accounts into the
     # query's CostTracker (workpool propagates it to fan-out workers);
     # nested evals over the same shared tracker re-install it, harmless
-    import time as _time
-
-    from ..utils import costacc
     prev_cost = costacc.set_current(ec._cost)
-    t0 = _time.perf_counter()
-    w0 = ec._cost.local_wall_ms_total()
     try:
-        rows = eval_expr(ec, expr)
+        # eval:other is this phase's SELF time: parse, plan, the AST
+        # walk, name resolution, group ids, result emit and sort — what
+        # no fetch / cache / device phase below it claims
+        with flightrec.phase("eval:other"):
+            expr = parse_cached(q)
+            rows = eval_expr(ec, expr)
+            # drop all-NaN series (absent everywhere)
+            out = [ts for ts in rows if not np.isnan(ts.values).all()]
+            from .metricsql.ast import FuncExpr
+            if not (isinstance(expr, FuncExpr) and expr.name in _SORT_FUNCS):
+                out.sort(key=lambda ts: ts.metric_name.marshal())
     finally:
-        # name the leftover: eval wall not claimed by any LOCAL phase
-        # lap (parse/AST walk/series glue) lands in eval:other instead
-        # of silently vanishing from the cost split.  Baseline is the
-        # local-lap total only — remote nodes' laps merged in during a
-        # fan-out accrue concurrently and may sum past local wall,
-        # which would wrongly suppress this bucket
-        dt_ms = (_time.perf_counter() - t0) * 1e3
-        inner_ms = ec._cost.local_wall_ms_total() - w0
-        if dt_ms > inner_ms:
-            costacc.lap("eval:other", (dt_ms - inner_ms) / 1e3)
         costacc.set_current(prev_cost)
-    # drop all-NaN series (absent everywhere)
-    out = [ts for ts in rows if not np.isnan(ts.values).all()]
-    from .metricsql.ast import FuncExpr
-    if not (isinstance(expr, FuncExpr) and expr.name in _SORT_FUNCS):
-        out.sort(key=lambda ts: ts.metric_name.marshal())
     return out
 
 

@@ -45,13 +45,11 @@ from __future__ import annotations
 import itertools
 import os
 import threading
-import time as _time
 import weakref
 
 import numpy as np
 
 from ..storage.metric_name import MetricName
-from ..utils import costacc as _costacc
 from ..utils import flightrec as _flightrec
 from ..utils import metrics as metricslib
 from .types import EvalConfig, Timeseries
@@ -367,6 +365,10 @@ class RollupResultCache:
             ) -> tuple[CacheHit | None, int]:
         """Returns (hit covering [ec.start, cov_end], first timestamp
         still to compute). (None, ec.start) on miss."""
+        with _flightrec.phase("cache:get"):
+            return self._get(ec, q)
+
+    def _get(self, ec: EvalConfig, q: str) -> tuple[CacheHit | None, int]:
         _CACHE_REQUESTS.inc()
         with self._lock:
             key = self._key(ec, q)
@@ -386,12 +388,8 @@ class RollupResultCache:
 
     def put(self, ec: EvalConfig, q: str, rows: list[Timeseries],
             now_ms: int, trust_raw: bool = True) -> None:
-        t0 = _time.perf_counter()
-        _costacc.restamp()
-        try:
+        with _flightrec.phase("cache:put"):
             self._put(ec, q, rows, now_ms, trust_raw)
-        finally:
-            _costacc.lap("cache:put", _time.perf_counter() - t0)
 
     def _put(self, ec: EvalConfig, q: str, rows: list[Timeseries],
              now_ms: int, trust_raw: bool = True) -> None:
@@ -466,33 +464,31 @@ class RollupResultCache:
         same key).  Fallback/oracle path: block-at-a-time rebuild — the
         cached prefix is one 2D copy; only the (small) fresh suffix is
         touched per series."""
-        t0 = _time.perf_counter()
-        kind = "rebuild"
+        # the inplace-vs-rebuild DECISION rides the phase's flight event
+        # as its arg: a rebuild where inplace was expected is itself a
+        # latency clue
+        ph = _flightrec.phase("cache:merge", arg="rebuild")
         try:
-            # partial results must NEVER be committed: the in-place path
-            # mutates the live entry before the caller's put() guard runs,
-            # so the guard is applied here — a partial suffix takes the
-            # pure rebuild path (served, never cached; same contract as
-            # the skipped put)
-            partial = ec._partial[0] or \
-                getattr(ec.storage, "last_partial", False)
-            if ring_enabled() and not partial:
-                rows = self._merge_inplace(hit, fresh, ec, new_start,
-                                           trust_raw, now_ms)
-                if rows is not None:
-                    _INPLACE.inc()
-                    kind = "inplace"
-                    return rows
-            _REBUILD.inc()
-            return self._merge_rebuild(hit, fresh, ec, new_start,
-                                       trust_raw)
+            with ph:
+                # partial results must NEVER be committed: the in-place
+                # path mutates the live entry before the caller's put()
+                # guard runs, so the guard is applied here — a partial
+                # suffix takes the pure rebuild path (served, never
+                # cached; same contract as the skipped put)
+                partial = ec._partial[0] or \
+                    getattr(ec.storage, "last_partial", False)
+                if ring_enabled() and not partial:
+                    rows = self._merge_inplace(hit, fresh, ec, new_start,
+                                               trust_raw, now_ms)
+                    if rows is not None:
+                        _INPLACE.inc()
+                        ph.arg = "inplace"
+                        return rows
+                _REBUILD.inc()
+                return self._merge_rebuild(hit, fresh, ec, new_start,
+                                           trust_raw)
         finally:
-            now = _time.perf_counter()
-            _MERGE_SECONDS.inc(now - t0)
-            # the inplace-vs-rebuild DECISION on the flight timeline: a
-            # rebuild where inplace was expected is itself a latency clue
-            _flightrec.rec("rcache:" + kind, t0, now - t0)
-            _costacc.lap("cache:merge", now - t0)
+            _MERGE_SECONDS.inc(ph.dur)
 
     def _merge_inplace(self, hit: CacheHit, fresh: list[Timeseries],
                        ec: EvalConfig, new_start: int, trust_raw: bool,

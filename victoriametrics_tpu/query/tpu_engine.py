@@ -17,6 +17,7 @@ import numpy as np
 
 from ..ops import rollup_np
 from ..ops.rollup_np import RollupConfig
+from ..utils import flightrec
 from ..utils import metrics as metricslib
 
 # (kernel, phase) -> histogram handle; keeps name formatting and the
@@ -44,30 +45,22 @@ def timed_kernel_call(kernel: str, jit_fn, *args, **kw):
     thing to look at when p99 spikes — a 'compile' sample on a steady
     workload means a shape/dtype churned a cached kernel."""
     import jax
-
-    from ..utils import flightrec as _flightrec
     cache_size = getattr(jit_fn, "_cache_size", None)
     before = cache_size() if callable(cache_size) else None
-    t0 = time.perf_counter()
-    out = jit_fn(*args, **kw)
-    # async dispatch returns immediately; without this sync the histogram
-    # would record dispatch overhead, not the kernel (callers convert the
-    # result to numpy right after, so no extra blocking is introduced)
-    jax.block_until_ready(out)
-    dt = time.perf_counter() - t0
-    phase = "execute"
-    if before is not None and cache_size() > before:
-        phase = "compile"
-    _kernel_histogram(kernel, phase).update(dt)
-    # a device-leg flight capture attributes transfer vs compile vs
-    # execute: uploads are spanned at the put seams, this is the rest
-    _flightrec.rec(f"device:{phase}", t0, dt, arg=kernel)
-    # cost plane: device kernel wall into the query's tracker (CPU 0 —
-    # the work ran on the accelerator, not this thread)
-    from ..utils import costacc as _costacc
-    _tr = _costacc.current()
-    if _tr is not None:
-        _tr.lap(f"device:{phase}", dt, 0.0)
+    # one phase from dispatch to ready: the flight event (arg = kernel),
+    # the query's device:execute / device:compile cost bucket and query
+    # phase counter, the profiler annotation — uploads are phased at the
+    # put seams, this is the rest of the device leg
+    with flightrec.phase("device:execute", arg=kernel) as ph:
+        out = jit_fn(*args, **kw)
+        # async dispatch returns immediately; without this sync the
+        # phase would time dispatch overhead, not the kernel (callers
+        # convert the result to numpy right after, so no extra blocking
+        # is introduced)
+        jax.block_until_ready(out)
+        if before is not None and cache_size() > before:
+            ph.name = "device:compile"
+    _kernel_histogram(kernel, ph.name[len("device:"):]).update(ph.dur)
     return out
 
 
@@ -266,6 +259,11 @@ class TPUEngine:
 
     def __post_init__(self):
         enable_compilation_cache()
+        # every flightrec.phase shows on the profiler's host plane as
+        # vm:<name>, on the device trace's clock (flightrec itself must
+        # import without jax, so the engine hands it the annotation)
+        import jax
+        flightrec.set_annotator(jax.profiler.TraceAnnotation)
         if self.value_dtype is None:
             self.value_dtype = auto_value_dtype()
 
@@ -606,6 +604,14 @@ def _dispatch_fused(engine: TPUEngine, aggr: str, func: str, tiles,
 
 
 def _upload_tiles(engine: TPUEngine, series, cfg: RollupConfig):
+    """Cold tile build + upload.  device:tile_build is the host staging
+    (float_to_decimal, delta-plane pack, padding); the puts nest inside
+    it as device:upload phases and are charged there, not here."""
+    with flightrec.phase("device:tile_build"):
+        return _build_tiles(engine, series, cfg)
+
+
+def _build_tiles(engine: TPUEngine, series, cfg: RollupConfig):
     """Cold upload: prefer compact delta planes decoded on device (~2-5
     B/sample over the link, SURVEY §7 'compressed columns cross the
     boundary'); fall back to dense tiles when the data needs >int32.
@@ -790,7 +796,21 @@ def advance_rolling(engine: TPUEngine, rt: RollingTile, storage, filters,
     [fetch_lo, end]: fetch only the slice newer than the tile's covered
     range and append it on device. Returns False when the tile cannot be
     advanced (late/backfilled data, deletes, new series, capacity/int32
-    exhausted) — the caller rebuilds via the cold path."""
+    exhausted) — the caller rebuilds via the cold path.
+
+    Timed as device:tile_build: its self time is the host bookkeeping
+    and staging around the slice fetch (fetch:wait), the window slide
+    (device:execute) and the append's put (device:upload), which nest
+    inside it and are charged to themselves."""
+    with flightrec.phase("device:tile_build"):
+        return _advance_rolling(engine, rt, storage, filters, start,
+                                fetch_lo, end, max_series, tenant,
+                                drop_stale, tracer)
+
+
+def _advance_rolling(engine: TPUEngine, rt: RollingTile, storage, filters,
+                     start: int, fetch_lo: int, end: int, max_series,
+                     tenant, drop_stale: bool, tracer) -> bool:
     def no(reason: str) -> bool:
         engine.last_roll_decline = reason
         return False
@@ -958,15 +978,20 @@ def _append_cols(engine: TPUEngine, rt: RollingTile, cols,
         # the tile rows are already padded to the mesh multiple, so these
         # shard_puts never re-pad — they just place per the rule table
         from ..parallel.partition import shard_put
-        new_ts_d = shard_put(engine.mesh, "ts", new_ts)
-        new_vals_d = shard_put(engine.mesh, "values", new_vals)
-        new_counts_d = shard_put(engine.mesh, "counts", new_counts)
+        rt.tiles = append_tile(
+            ts_t, v_t, counts_t, shard_put(engine.mesh, "ts", new_ts),
+            shard_put(engine.mesh, "values", new_vals),
+            shard_put(engine.mesh, "counts", new_counts)) + (v0,)
     else:
-        from ..models.tile_cache import count_upload
-        count_upload(new_ts.nbytes + new_vals.nbytes + new_counts.nbytes)
-        new_ts_d, new_vals_d, new_counts_d = new_ts, new_vals, new_counts
-    rt.tiles = append_tile(ts_t, v_t, counts_t, new_ts_d, new_vals_d,
-                           new_counts_d) + (v0,)
+        # one device: the staged NumPy tail rides the jitted call's
+        # arguments, so that call IS the put (async: it returns once the
+        # transfer and the kernel are issued, it does not wait for them)
+        from ..models.tile_cache import timed_transfer
+        rt.tiles = timed_transfer(
+            "device:upload",
+            new_ts.nbytes + new_vals.nbytes + new_counts.nbytes,
+            lambda: append_tile(ts_t, v_t, counts_t, new_ts, new_vals,
+                                new_counts)) + (v0,)
     rt.counts_host[rows_idx] = new_n
     rt.n_samples += cols.n_samples
     rt.appends += 1

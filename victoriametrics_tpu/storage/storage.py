@@ -200,22 +200,16 @@ class _ColumnarSpace:
             km.close()
 
 
-def _phase_lap(phase: str, t0: float) -> float:
-    """Account wall time since t0 to a fetch phase (counter + flight
-    event + the current query's CostTracker); returns the new t0."""
-    now = time.perf_counter()
-    _PHASE[phase].inc(now - t0)
-    flightrec.rec("fetch:" + phase, t0, now - t0)
-    costacc.lap("fetch:" + phase, now - t0)
-    return now
+def _phase_lap(ph: flightrec.phase, phase: str) -> None:
+    """The fetch stage `ph` times ends here; `phase` begins (each stage
+    is a flight event, a lap in the query's CostTracker and its member
+    of vm_fetch_phase_seconds_total)."""
+    ph.lap("fetch:" + phase, _PHASE[phase])
 
 
-def _ingest_lap(phase: str, t0: float) -> float:
-    """Account wall time since t0 to an ingest phase; returns the new t0."""
-    now = time.perf_counter()
-    _ING_PHASE[phase].inc(now - t0)
-    flightrec.rec("ingest:" + phase, t0, now - t0)
-    return now
+def _ingest_lap(ph: flightrec.phase, phase: str) -> None:
+    """The ingest stage `ph` times ends here; `phase` begins."""
+    ph.lap("ingest:" + phase, _ING_PHASE[phase])
 
 
 class SeriesData:
@@ -569,7 +563,11 @@ class Storage:
         """
         if self._readonly:
             raise RuntimeError("storage is read-only")
-        t0 = time.perf_counter()
+        with flightrec.phase("ingest:resolve",
+                             counter=_ING_PHASE["resolve"]) as ph:
+            return self._add_rows_phased(rows, tenant, ph)
+
+    def _add_rows_phased(self, rows, tenant, ph: flightrec.phase) -> int:
         out = []
         regs = []       # (mn, tsid, date) needing per-day registration
         reg_seen = set()  # batch-local (mid, date) dedup: one regs entry
@@ -633,10 +631,10 @@ class Storage:
             reg_seen.add((mid, date))
             regs.append((mn, tsid, date))
             out.append((tsid, ts, val))
-        t0 = _ingest_lap("resolve", t0)
+        _ingest_lap(ph, "register")
         if regs:
             self._register_days(regs)
-        t0 = _ingest_lap("register", t0)
+        _ingest_lap(ph, "append")
         n = len(out)
         if n == 0:
             return 0
@@ -649,7 +647,6 @@ class Storage:
         if oldest < fasttime.unix_ms() - OFFSET_MS:
             GLOBAL.reset()
         self.table.add_rows(out)
-        _ingest_lap("append", t0)
         _INGEST_ROWS.inc(n)
         with self._lock:
             # monotonic stat, written under _lock; the /metrics reader
@@ -733,7 +730,13 @@ class Storage:
         """
         if self._readonly:
             raise RuntimeError("storage is read-only")
-        t0 = time.perf_counter()
+        with flightrec.phase("ingest:resolve",
+                             counter=_ING_PHASE["resolve"]) as ph:
+            return self._add_rows_columnar_phased(cr, tenant, transform,
+                                                  drop_stats, ph)
+
+    def _add_rows_columnar_phased(self, cr, tenant, transform, drop_stats,
+                                  ph: flightrec.phase) -> int:
         sp = self._acquire_cspace(tenant)  # returns with sp.lock HELD
         try:
             ids, n_new = sp.keymap.resolve(cr.keybuf, cr.key_off, cr.key_len)
@@ -803,11 +806,11 @@ class Storage:
                        d_clip + (1 << 20))
                 _, first = np.unique(key, return_index=True)
                 roll = roll[first]
-            t0 = _ingest_lap("resolve", t0)
+            _ingest_lap(ph, "register")
             if roll.size:
                 self._register_columnar_days(sp, cr, ids, dates, sel, roll,
                                              transform)
-            t0 = _ingest_lap("register", t0)
+            _ingest_lap(ph, "append")
         finally:
             sp.lock.release()
         oldest = int(tss.min())
@@ -815,7 +818,6 @@ class Storage:
         if oldest < fasttime.unix_ms() - OFFSET_MS:
             GLOBAL.reset()
         self.table.add_rows_columnar(sp, ids, tss, vals)
-        _ingest_lap("append", t0)
         n = int(ids.size)
         _INGEST_ROWS.inc(n)
         with self._lock:
@@ -1094,7 +1096,9 @@ class Storage:
         the on-disk part itself and each chunk decodes only its own
         blocks). The per-series density estimate starts at the 15s scrape
         grid and adapts to what the first chunk actually returned."""
-        tsids = self._search_tsids_union(filters, min_ts, max_ts, tenant)
+        with flightrec.phase("fetch:wait"):  # closed before any yield
+            tsids = self._search_tsids_union(filters, min_ts, max_ts,
+                                             tenant)
         if not tsids:
             return
         est = max((max_ts - min_ts) // 15_000 + 2, 1)
@@ -1187,18 +1191,25 @@ class Storage:
                     else dedup_interval_ms)
         budget = (_ScanBudget(deadline, on_abort=_DEADLINE_ABORTS.inc)
                   if deadline else None)
+        # fetch:wait is the CALLING thread's wall for the whole fetch:
+        # the gate queue, the stages below when they run inline, the
+        # wait for pool workers when they fan out.  The stages keep
+        # their own family (vm_fetch_phase_seconds_total) either way.
         # per-tenant QoS admission: a tenant at its VM_TENANT_QUOTAS cap
         # queues (and sheds) against itself instead of starving others
-        with workpool.SEARCH_GATE.admit(tenant):
+        with flightrec.phase("fetch:wait"), \
+                workpool.SEARCH_GATE.admit(tenant):
             # chaos seam, INSIDE the admission slot: an injected delay
             # occupies real gate capacity, which is how the chaos suite
             # saturates one tenant's quota without touching another's
             if faultinject.active():
                 faultinject.fire(
                     f"storage:search:{tenant[0]}:{tenant[1]}")
-            return self._search_columns_gated(
-                filters, min_ts, max_ts, interval, max_series, tenant,
-                _tsids, ColumnarSeries, assemble, budget, ds)
+            with flightrec.phase("fetch:index_search",
+                                 counter=_PHASE["index_search"]) as ph:
+                return self._search_columns_gated(
+                    filters, min_ts, max_ts, interval, max_series, tenant,
+                    _tsids, ColumnarSeries, assemble, budget, ds, ph)
 
     def _resolve_ordered_names(self, uniq: np.ndarray):
         """Raw-name resolution + canonical (raw-sorted) row order for a
@@ -1246,9 +1257,9 @@ class Storage:
 
     def _search_columns_gated(self, filters, min_ts, max_ts, interval,
                               max_series, tenant, _tsids, ColumnarSeries,
-                              assemble, budget=None, ds=None):
-        t_ph = time.perf_counter()
-        costacc.restamp()  # start of this thread's phase-lap chain
+                              assemble, budget, ds, ph: flightrec.phase):
+        """The fetch behind the gate; `ph` is the open
+        ``fetch:index_search`` stage, lapped on from stage to stage."""
         if budget is not None:
             budget.check()  # gate queue wait burned the budget already?
         tsids = (self._search_tsids_union(
@@ -1257,7 +1268,6 @@ class Storage:
                      scan_check=budget.check if budget is not None
                      else None)
                  if _tsids is None else _tsids)
-        t_ph = _phase_lap("index_search", t_ph)
         empty = ColumnarSeries.empty()
         if not tsids:
             return empty
@@ -1282,13 +1292,14 @@ class Storage:
         # correctness oracle the equality tests diff against
         from .. import native as _native
         fused = _native.assemble_enabled()
+        _phase_lap(ph, "assemble_native" if fused else "collect")
         pieces = self.table.collect_columns(
             tsid_set, min_ts, max_ts,
             tsid_lo=tsids[0].sort_key(), tsid_hi=tsids[-1].sort_key(),
             as_float=fused,
             check=budget.check if budget is not None else None,
             ds=ds, note=note)
-        t_ph = _phase_lap("assemble_native" if fused else "collect", t_ph)
+        _phase_lap(ph, "assemble" if fused else "decode")
         if note:
             if note.get("partial_res"):
                 # per-request flag, surfaced as partialResolution in the
@@ -1340,7 +1351,7 @@ class Storage:
                 from ..ops import decimal as dec_ops
                 dec_ops.decimal_to_float_blocks_py(mant_all, goff, scales,
                                                    vals_f, pool=workpool.POOL)
-            t_ph = _phase_lap("decode", t_ph)
+            _phase_lap(ph, "assemble")
         # cost accounting: the raw column bytes this fetch pulled out of
         # parts (timestamps + decoded values) — the "bytesRead" column
         # of top_queries/usage
@@ -1417,7 +1428,6 @@ class Storage:
         if cols.metric_names:
             self.track_name_usage(
                 {mn.metric_group for mn in cols.metric_names})
-        _phase_lap("assemble", t_ph)
         return cols
 
     @staticmethod

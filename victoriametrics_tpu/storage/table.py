@@ -207,7 +207,7 @@ class Table:
         with self._lock:
             parts = list(self._partitions.values())
         written = 0
-        with flightrec.span("downsample:table", arg=len(parts)):
+        with flightrec.phase("downsample:table", arg=len(parts)):
             for p in parts:
                 written += p.run_downsample(tiers, deleted_ids, now_ms)
         return written
@@ -239,13 +239,13 @@ class Table:
         # the fan span shows the WHOLE flush window on the flight
         # timeline (per-partition flush:part spans nest inside it on
         # whichever threads the pool ran them)
-        with flightrec.span("flush:table", arg=len(parts)):
+        with flightrec.phase("flush:table", arg=len(parts)):
             self._fan_partitions(parts, lambda p: p.flush_to_disk())
 
     def force_merge(self, deleted_ids=None, min_valid_ts=None):
         with self._lock:
             parts = list(self._partitions.values())
-        with flightrec.span("merge:table", arg=len(parts)):
+        with flightrec.phase("merge:table", arg=len(parts)):
             self._fan_partitions(
                 parts, lambda p: p.force_merge(deleted_ids, min_valid_ts))
 

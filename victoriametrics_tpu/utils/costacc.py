@@ -16,13 +16,15 @@ by every child config the way ``_samples_scanned`` is) and accumulates:
   storage nodes during the query's fan-out
 - ``device_up`` / ``device_down`` — H2D/D2H bytes of the device plane
 - ``rows``          — result rows (series) returned to the client
-- per-bucket wall/CPU laps (``wall_ms`` / ``cpu_ms`` keyed by the
-  existing phase-seam names: ``fetch:index_search``,
-  ``fetch:assemble_native``, ``fetch:rollup``, ``cache:merge``, ...) —
-  CPU measured on the THREAD clock (``time.thread_time``), so a lap
-  says what the query burned, not what it waited for.
+- per-bucket wall/CPU laps (``wall_ms`` / ``cpu_ms`` keyed by phase
+  name: ``fetch:index_search``, ``fetch:assemble_native``,
+  ``fetch:rollup``, ``cache:merge``, ``eval:other``, ...), fed by
+  ``utils/flightrec.phase`` with each phase's SELF time (a nested
+  phase stops its parent's clock, so the buckets of one thread never
+  overlap) — CPU measured on the THREAD clock (``time.thread_time``),
+  so a lap says what the query burned, not what it waited for.
 
-The tracker is reached from the storage/cache/device seams through a
+The tracker is reached from the phase seam through a
 thread-local "current tracker" (:func:`set_current`), installed by
 ``exec_query`` / the HTTP observability bracket / the vmstorage RPC
 handlers and propagated to pool workers by ``utils/workpool`` the same
@@ -39,7 +41,6 @@ sourced tenant ids can never grow the registry unbounded).
 from __future__ import annotations
 
 import threading
-import time
 
 from . import metrics as metricslib
 
@@ -52,8 +53,7 @@ class CostTracker:
 
     __slots__ = ("_lock", "samples", "storage_samples", "part_bytes",
                  "rpc_bytes", "device_up", "device_down", "rows",
-                 "wall_ms", "cpu_ms", "local_wall_ms", "remote_nodes",
-                 "cost_partial")
+                 "wall_ms", "cpu_ms", "remote_nodes", "cost_partial")
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -66,11 +66,6 @@ class CostTracker:
         self.rows = 0
         self.wall_ms: dict[str, float] = {}
         self.cpu_ms: dict[str, float] = {}
-        #: wall ms recorded by THIS process's laps only (merge_remote
-        #: excluded): the denominator the eval:other/serve:other
-        #: leftover buckets subtract from — remote nodes' laps accrue
-        #: CONCURRENTLY and may sum past the local wall clock
-        self.local_wall_ms = 0.0
         #: storage nodes that shipped a cost frame during the fan-out
         self.remote_nodes = 0
         #: True when at least one fan-out leg could NOT ship cost (an
@@ -112,7 +107,6 @@ class CostTracker:
                 + wall_s * 1e3
             self.cpu_ms[bucket] = self.cpu_ms.get(bucket, 0.0) \
                 + cpu_s * 1e3
-            self.local_wall_ms += wall_s * 1e3
 
     # -- cross-RPC merge --------------------------------------------------
 
@@ -167,13 +161,6 @@ class CostTracker:
         with self._lock:
             return sum(self.wall_ms.values())
 
-    def local_wall_ms_total(self) -> float:
-        """Wall ms of this process's OWN laps (remote merges excluded) —
-        the only valid baseline for leftover-bucket computation: merged
-        per-node laps run concurrently and can sum past local wall."""
-        with self._lock:
-            return self.local_wall_ms
-
     def summary(self) -> dict:
         """The cost columns surfaced in top_queries/slow_queries and
         the bench artifact."""
@@ -200,36 +187,14 @@ class CostTracker:
 
 def set_current(tracker: CostTracker | None) -> CostTracker | None:
     """Install `tracker` as this thread's cost sink; returns the
-    previous one (restore it when the bracket exits).  Re-stamps the
-    thread-CPU lap clock so the first lap never inherits another
-    query's CPU."""
+    previous one (restore it when the bracket exits)."""
     prev = getattr(_tls, "current", None)
     _tls.current = tracker
-    _tls.cpu0 = time.thread_time()
     return prev
 
 
 def current() -> CostTracker | None:
     return getattr(_tls, "current", None)
-
-
-def restamp() -> None:
-    """Reset this thread's CPU lap stamp (call at the start of a lap
-    chain, e.g. right after taking the wall t0 for the first phase)."""
-    _tls.cpu0 = time.thread_time()
-
-
-def lap(bucket: str, wall_s: float) -> None:
-    """Account one phase lap to the current tracker: `wall_s` of wall
-    time plus the thread-CPU delta since the previous lap/restamp on
-    this thread.  No tracker installed == one TLS read."""
-    tr = getattr(_tls, "current", None)
-    now_cpu = time.thread_time()
-    cpu0 = getattr(_tls, "cpu0", None)
-    _tls.cpu0 = now_cpu
-    if tr is None:
-        return
-    tr.lap(bucket, wall_s, now_cpu - cpu0 if cpu0 is not None else 0.0)
 
 
 def add_samples(n: int) -> None:

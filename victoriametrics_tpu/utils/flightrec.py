@@ -5,14 +5,16 @@ log: lib/querytracer sees one query's own spans, this sees what ELSE the
 process was doing while the query ran).
 
 Always-on, low-overhead: every thread that records owns a private
-fixed-capacity ring of (t0, dur, name, ctx, arg) event slots.  The ring
-arrays are preallocated at first use; the record path is index
-arithmetic + five slot stores + one integer bump — no allocation, no
-lock, no syscall.  Writers never synchronize with readers: a capture
-snapshots each ring's write cursor and walks backward, and any slot the
-writer overtook mid-read is discarded by re-checking the cursor (the
-classic seqlock-reader discipline, per-slot granularity is one event so
-a torn event can only be dropped, never misattributed).
+bounded ring of (t0, dur, name, ctx, arg, depth) event slots.  The ring
+starts small and doubles up to its capacity as the thread records (a
+per-connection thread's few dozen events never pay for 8192 slots);
+between doublings the record path is index arithmetic + six slot
+stores + one integer bump — no allocation, no lock, no syscall.
+Writers never synchronize with readers: a capture snapshots each ring's
+write cursor and walks backward, and any slot the writer overtook
+mid-read is discarded by re-checking the cursor (the classic
+seqlock-reader discipline, per-slot granularity is one event so a torn
+event can only be dropped, never misattributed).
 
 Event model: COMPLETE spans (Chrome trace ``"ph": "X"``) recorded at
 END time — callers time the region themselves (they already do, for the
@@ -20,6 +22,21 @@ phase counters) and call :func:`rec` once.  Instant events
 (``"ph": "i"``) mark decisions (cache inplace/rebuild, merge-gate
 yields).  Timestamps are ``time.perf_counter()`` floats — one monotonic
 clock shared by every thread, so cross-thread overlap is meaningful.
+
+The phase seam: :func:`phase` is the ONE way a timed region of the
+served query is recorded.  ``with flightrec.phase("cache:put"):`` keeps
+a per-thread stack, so every phase is charged its SELF time (entering a
+child stops the parent's clock, leaving it restarts it) and the self
+times of a request partition the wall between its root's entry and
+exit exactly.  On exit one call feeds the ring (inclusive ``dur`` plus
+the nesting ``depth``), the current query's cost tracker (self wall +
+self thread-CPU, bucket = name), ``vm_query_phase_seconds_total{phase=}``
+(only on a thread that holds a request root) and — through
+:func:`set_annotator`, installed by the device engine, because this
+module must import without jax — a ``vm:<name>`` annotation on the
+profiler's host plane.  Regions with no natural block (background
+flush/merge workers, the pool's task wrapper, instants) still call
+:func:`rec` directly.
 
 Cross-thread attribution: a serving thread opens a *flight context*
 (:func:`set_ctx`, an integer id per refresh/query); utils/workpool
@@ -50,12 +67,22 @@ import os
 import threading
 import time
 
-__all__ = ["enabled", "rec", "instant", "span", "new_ctx", "set_ctx",
-           "get_ctx", "ctx_events", "clear_ctx", "RECORDER",
-           "FlightRecorder", "reconfigure"]
+from . import costacc
+from . import metrics as metricslib
+
+__all__ = ["enabled", "rec", "instant", "phase", "set_annotator",
+           "new_ctx", "set_ctx", "get_ctx", "ctx_events", "clear_ctx",
+           "RECORDER", "FlightRecorder", "reconfigure"]
 
 #: ring capacity per thread (events); power of two for mask arithmetic
 _DEFAULT_CAP = 1 << 13
+#: slots a new ring starts with; it doubles up to its capacity as events
+#: arrive.  A thread that serves one HTTP connection records a few dozen
+#: events and its ring outlives it by the capture window: at full
+#: capacity, a few hundred such rings are millions of list slots that
+#: every full gc collection walks (read as ~8 % of a dashboard refresh's
+#: latency on the chip host, PERF.md PR 28)
+_INITIAL_SLOTS = 1 << 8
 
 
 def _env_enabled() -> bool:
@@ -92,26 +119,45 @@ class _Ring:
     """One thread's event ring.  Only the owner thread writes; capture
     threads read racily and validate against the cursor afterward.
 
-    Slots are parallel preallocated lists (not tuples): a record is five
-    slot stores + one cursor bump, allocating nothing."""
+    Slots are parallel lists (not tuples), grown by doubling up to the
+    capacity: between doublings a record is six slot stores + one cursor
+    bump, allocating nothing."""
 
-    __slots__ = ("t0", "dur", "name", "ctx", "arg", "i", "w", "cap",
-                 "mask", "tid", "tname", "taken", "thread")
+    __slots__ = ("t0", "dur", "name", "ctx", "arg", "depth", "i", "w",
+                 "cap", "size", "mask", "tid", "tname", "taken", "thread")
 
     def __init__(self, cap: int, thread: threading.Thread):
-        self.t0 = [0.0] * cap
-        self.dur = [0.0] * cap
-        self.name = [""] * cap
-        self.ctx = [0] * cap
-        self.arg = [None] * cap
+        n = min(cap, _INITIAL_SLOTS)
+        self.t0 = [0.0] * n
+        self.dur = [0.0] * n
+        self.name = [""] * n
+        self.ctx = [0] * n
+        self.arg = [None] * n
+        self.depth = [0] * n  # phase nesting depth (0 = a plain rec)
         self.i = 0          # monotonic write cursor (slot = i & mask)
         self.w = -1         # cursor mid-store marker: w == i <=> in rec()
-        self.cap = cap
-        self.mask = cap - 1
+        self.cap = cap      # the most events the ring will ever hold
+        self.size = n       # slots allocated so far (doubles up to cap)
+        self.mask = n - 1
         self.tid = thread.ident or 0
         self.tname = thread.name
         self.taken = 0      # first cursor NOT yet included in a capture
         self.thread = thread    # liveness probe for ring reclamation
+
+    def grow(self) -> None:
+        """Double the slot lists (owner thread only, with the cursor AT
+        `size`: nothing has wrapped yet, so event k sits in slot k under
+        the old mask and the new one alike, and a racing reader that
+        holds either sees the same events)."""
+        n = self.size
+        self.t0.extend([0.0] * n)
+        self.dur.extend([0.0] * n)
+        self.name.extend([""] * n)
+        self.ctx.extend([0] * n)
+        self.arg.extend([None] * n)
+        self.depth.extend([0] * n)
+        self.size = 2 * n
+        self.mask = 2 * n - 1
 
     def newest_t0(self) -> float:
         """t0 of the most recent event (0.0 when empty); racy read, only
@@ -127,18 +173,19 @@ class _Ring:
         end = self.i
         lo = max(end - self.cap, 0)
         out = []
-        t0s, durs, names, ctxs, args = (self.t0, self.dur, self.name,
-                                        self.ctx, self.arg)
+        t0s, durs, names, ctxs, args, depths = (
+            self.t0, self.dur, self.name, self.ctx, self.arg, self.depth)
         mask = self.mask
         for k in range(lo, end):
             j = k & mask
             t0 = t0s[j]
             if t0 < min_t0:
                 continue
-            out.append((t0, durs[j], names[j], ctxs[j], args[j], k))
+            out.append((t0, durs[j], names[j], ctxs[j], args[j], k,
+                        depths[j]))
         # validate: any slot the writer lapped during the walk holds a
         # NEWER event than its cursor position promised — discard those.
-        # STRICT bound: the writer stores the five slots BEFORE bumping
+        # STRICT bound: the writer stores the six slots BEFORE bumping
         # the cursor, so the slot at cursor (i - cap) may be mid-store
         # (torn) while i still reads one low — drop it too.  Costs at
         # most the single oldest event of an idle full ring; keeps the
@@ -188,10 +235,12 @@ def _new_ring() -> _Ring:
     return ring
 
 
-def rec(name: str, t0: float, dur: float, arg=None) -> None:
+def rec(name: str, t0: float, dur: float, arg=None, depth: int = 0) -> None:
     """Record one complete span [t0, t0+dur) (perf_counter seconds) on
     the calling thread's ring.  The hot-path primitive: one flag check,
-    one TLS lookup, five slot stores, one cursor bump."""
+    one TLS lookup, six slot stores, one cursor bump.  `depth` is the
+    phase seam's nesting depth (1 = outermost phase on its thread); a
+    direct call leaves it 0, "not a phase"."""
     if not _ENABLED:
         return
     ring = getattr(_tls, "ring", None)
@@ -199,16 +248,19 @@ def rec(name: str, t0: float, dur: float, arg=None) -> None:
         ring = _tls.ring = _new_ring()
     i = ring.i
     # w == i marks this slot mid-store: the gc hook (which can fire
-    # DURING these stores — the cursor bump's int allocation can
-    # trigger a collection) checks it and stands down instead of
-    # interleaving a second event into the same slot
+    # DURING these stores — the cursor bump's int allocation, or the
+    # ring's growth, can trigger a collection) checks it and stands down
+    # instead of interleaving a second event into the same slot
     ring.w = i
+    if i == ring.size and i < ring.cap:
+        ring.grow()
     j = i & ring.mask
     ring.t0[j] = t0
     ring.dur[j] = dur
     ring.name[j] = name
     ring.ctx[j] = getattr(_tls, "ctx", 0)
     ring.arg[j] = arg
+    ring.depth[j] = depth
     ring.i = i + 1
 
 
@@ -219,27 +271,184 @@ def instant(name: str, arg=None) -> None:
     rec(name, time.perf_counter(), 0.0, arg)
 
 
-class _Span:
-    """``with flightrec.span("name"):`` — times the body and records one
-    complete event on exit (even when the body raises)."""
+# -- the phase seam -----------------------------------------------------------
 
-    __slots__ = ("name", "arg", "t0")
+#: the served query's phases, all registered at import so every process
+#: exports every member (0 where nothing ran) and a reader never has to
+#: tell "absent" from "idle".  Self times of one request's phases
+#: partition its root's wall, so the family sums to
+#: ``vm_query_wall_seconds_total``.  Other names create their member on
+#: first use.
+QUERY_PHASES = (
+    "serve:admission", "serve:rows", "serve:json", "serve:send",
+    "serve:other", "eval:other", "fetch:wait",
+    "cache:get", "cache:put", "cache:merge",
+    "device:tile_build", "device:upload", "device:execute",
+    "device:download", "device:compile")
 
-    def __init__(self, name: str, arg=None):
+
+def _query_phase_counter(name: str):
+    return metricslib.REGISTRY.float_counter(
+        f'vm_query_phase_seconds_total{{phase="{name}"}}')
+
+
+_QUERY_PHASE = {n: _query_phase_counter(n) for n in QUERY_PHASES}
+_QUERY_WALL = metricslib.REGISTRY.float_counter(
+    "vm_query_wall_seconds_total")
+
+# name -> context-manager factory for the profiler's host plane
+# (jax.profiler.TraceAnnotation once the device engine has started);
+# None in processes that never start one
+_annotator = None
+
+
+def set_annotator(factory) -> None:
+    """Install the host-plane annotation hook: ``factory(label)`` must
+    return a context manager.  The device engine passes
+    ``jax.profiler.TraceAnnotation`` when it starts, so a profiler
+    session shows every phase as ``vm:<name>`` on the same clock as the
+    device's ops; off a session the annotation is a sub-microsecond
+    no-op.  ``None`` removes the hook."""
+    global _annotator
+    _annotator = factory
+
+
+class phase:
+    """``with flightrec.phase("serve:rows"):`` — one timed region of
+    the served query.  Phases nest per thread and each is charged its
+    SELF time: wall and thread-CPU between entry and exit, less what its
+    child phases took.  On exit (also when the body raises) the region
+    is recorded once, everywhere: a ring event (inclusive duration +
+    nesting depth), a lap of the self time in the current query's cost
+    tracker under ``name``, the phase's counter, and the profiler
+    annotation held open since entry.
+
+    `counter` names a family of the phase's OWN
+    (``vm_fetch_phase_seconds_total``, ``vm_ingest_phase_seconds_total``):
+    it gets the inclusive duration, and the phase never charges
+    ``vm_query_phase_seconds_total`` — its time stays with the enclosing
+    query phase there (an inline fetch reads as ``fetch:wait`` exactly
+    as a pooled one does).  Without `counter` the self time goes to
+    ``vm_query_phase_seconds_total{phase=name}``, but only on a thread
+    that holds a request root: that family is a partition of one
+    thread's wall and must never sum past it.
+
+    ``root=True`` opens a request: the outermost such phase on a thread
+    gives the request its flight context (unless one is installed) and
+    adds its whole wall to ``vm_query_wall_seconds_total`` on exit.
+
+    :meth:`lap` ends the region under its current name and starts the
+    next one in the same frame — a chain of back-to-back stages inside
+    one ``with``.  ``name`` may be reassigned before exit (a kernel call
+    learns only afterwards that it compiled)."""
+
+    __slots__ = ("name", "arg", "counter", "root", "t0", "dur", "_child",
+                 "_acct", "_cpu", "_cpu_mark", "_ann", "_own_ctx")
+
+    def __init__(self, name: str, arg=None, counter=None,
+                 root: bool = False):
         self.name = name
         self.arg = arg
+        self.counter = counter
+        self.root = root
+        self.dur = 0.0
 
     def __enter__(self):
+        stack = getattr(_tls, "stack", None)
+        if stack is None:
+            stack = _tls.stack = []
+        self._own_ctx = False
+        if self.root:
+            if stack:
+                self.root = False   # only the outermost root is one
+            elif getattr(_tls, "ctx", 0) == 0:
+                _tls.ctx = new_ctx()
+                self._own_ctx = True
+        self._open()
+        now_cpu = time.thread_time()
+        if stack:
+            parent = stack[-1]
+            parent._cpu += now_cpu - parent._cpu_mark
+        stack.append(self)
+        self._cpu_mark = now_cpu
         self.t0 = time.perf_counter()
         return self
 
+    def _open(self) -> None:
+        # _child: inclusive seconds of direct children (self = dur - it);
+        # _acct: seconds inside this region that descendants already
+        # charged to the query family
+        self._child = self._acct = self._cpu = 0.0
+        ann = None
+        if _annotator is not None and _ENABLED:
+            ann = _annotator("vm:" + self.name)
+            ann.__enter__()
+        self._ann = ann
+
+    def lap(self, name: str, counter=None) -> None:
+        """End this region now and start the next, named `name`, at the
+        same instant and depth."""
+        now = time.perf_counter()
+        now_cpu = time.thread_time()
+        stack = _tls.stack
+        self._cpu += now_cpu - self._cpu_mark
+        self._close(now, stack[-2] if len(stack) > 1 else None,
+                    len(stack), stack[0].root)
+        self.name = name
+        self.counter = counter
+        self._open()
+        self._cpu_mark = now_cpu
+        self.t0 = now
+
     def __exit__(self, *exc):
-        rec(self.name, self.t0, time.perf_counter() - self.t0, self.arg)
+        now = time.perf_counter()
+        now_cpu = time.thread_time()
+        stack = _tls.stack
+        # a child left open by a generator abandoned mid-phase must not
+        # outlive its parent on the stack
+        while stack and stack.pop() is not self:
+            pass
+        self._cpu += now_cpu - self._cpu_mark
+        if stack:
+            self._close(now, stack[-1], len(stack) + 1, stack[0].root)
+            stack[-1]._cpu_mark = now_cpu
+            return False
+        self._close(now, None, 1, self.root)
+        if self.root:
+            _QUERY_WALL.inc(self.dur)
+            if self._own_ctx:
+                _tls.ctx = 0
         return False
 
-
-def span(name: str, arg=None) -> _Span:
-    return _Span(name, arg)
+    def _close(self, now: float, parent, depth: int, rooted: bool) -> None:
+        """Record [t0, now) everywhere.  `rooted`: this thread holds a
+        request root, so the query family is charged."""
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        name = self.name
+        dur = self.dur = now - self.t0
+        rec(name, self.t0, dur, self.arg, depth)
+        tr = costacc.current()
+        if tr is not None:
+            tr.lap(name, dur - self._child, self._cpu)
+        if parent is not None:
+            parent._child += dur
+        if self.counter is not None:
+            # a family of its own: the time no descendant charged stays
+            # with the enclosing query phase
+            self.counter.inc(dur)
+            if parent is not None:
+                parent._acct += self._acct
+            return
+        if parent is not None:
+            parent._acct += dur
+        if rooted:
+            c = _QUERY_PHASE.get(name)
+            if c is None:
+                # benign double-create: REGISTRY.float_counter dedups by
+                # name, so two racing fills store the same object
+                c = _QUERY_PHASE[name] = _query_phase_counter(name)  # vmt: disable=VMT015
+            c.inc(max(dur - self._acct, 0.0))
 
 
 # -- flight context (cross-thread query attribution) --------------------------
@@ -293,21 +502,46 @@ def ctx_events(ctx: int, window_s: float = 120.0) -> list[tuple]:
         rings = list(_rings)
     out = []
     for ring in rings:
-        for t0, dur, name, c, _arg, _k in ring.snapshot(min_t0):
+        for t0, dur, name, c, _arg, _k, _depth in ring.snapshot(min_t0):
             if c == ctx:
                 out.append((t0, dur, name, ring.tid))
     out.sort(key=lambda e: e[0])
     return out
 
 
+def self_times(snap: list[tuple]) -> list[float]:
+    """Self seconds of each event of ONE ring's snapshot: a phase's
+    duration less its direct child phases' (recomputed offline from the
+    nesting depth — children complete, and so are recorded, before
+    their parent).  Plain recs (depth 0) keep their whole duration."""
+    out = []
+    pending: dict[int, float] = {}  # depth -> unclaimed child seconds
+    for ev in snap:
+        dur, depth = ev[1], ev[6]
+        if depth == 0:
+            out.append(dur)
+            continue
+        out.append(max(dur - pending.pop(depth + 1, 0.0), 0.0))
+        pending[depth] = pending.get(depth, 0.0) + dur
+    return out
+
+
 def phase_split(ctx: int, window_s: float = 120.0) -> dict[str, float]:
     """Per-name span seconds for one flight context (the slow-query
     log's per-phase split), summed across every thread that worked on
-    the query."""
+    the query.  Phases count their SELF time, so nested phases stay
+    disjoint."""
+    if ctx == 0:
+        return {}
+    min_t0 = time.perf_counter() - window_s
+    with _rings_lock:
+        rings = list(_rings)
     split: dict[str, float] = {}
-    for _t0, dur, name, _tid in ctx_events(ctx, window_s):
-        if dur > 0.0:
-            split[name] = split.get(name, 0.0) + dur
+    for ring in rings:
+        snap = ring.snapshot(min_t0)
+        for ev, self_s in zip(snap, self_times(snap)):
+            if ev[3] == ctx and ev[1] > 0.0:
+                split[ev[2]] = split.get(ev[2], 0.0) + self_s
     return split
 
 
@@ -333,7 +567,6 @@ class FlightRecorder:
         self._captures: "collections.deque[dict]" = collections.deque(
             maxlen=max(max_captures, 1))
         self._next_id = 0
-        from . import metrics as metricslib
         self._captures_total = metricslib.REGISTRY.counter(
             "vm_flight_captures_total")
         self._dropped_total = metricslib.REGISTRY.counter(
@@ -436,7 +669,7 @@ class FlightRecorder:
                 trace_events.append({
                     "name": "thread_name", "ph": "M", "pid": pid,
                     "tid": tid, "args": {"name": tname}})
-                for t0, dur, name, ctx, arg, _k in snap:
+                for t0, dur, name, ctx, arg, _k, depth in snap:
                     ev = {"name": name, "ph": "X", "pid": pid, "tid": tid,
                           "ts": round((t0 - epoch) * 1e6, 1),
                           "dur": round(dur * 1e6, 1)}
@@ -449,6 +682,8 @@ class FlightRecorder:
                         args["ctx"] = ctx
                     if arg is not None:
                         args["arg"] = arg
+                    if depth:
+                        args["depth"] = depth
                     if args:
                         ev["args"] = args
                     trace_events.append(ev)
@@ -636,7 +871,6 @@ def install_gc_events() -> None:
     triggered it (gc pauses are a serving-latency suspect).  Piggybacks
     on utils/metrics' single gc callback — the one timing of each
     collection feeds both vm_gc_pause_seconds_total and the timeline."""
-    from . import metrics as metricslib
     if _gc_hook not in metricslib.gc_pause_hooks:
         metricslib.gc_pause_hooks.append(_gc_hook)
 
