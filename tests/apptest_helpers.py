@@ -141,3 +141,24 @@ def free_ports(n: int) -> list:
     for s in socks:
         s.close()
     return ports
+
+
+def tree_matrix_body(grid_s, series, head: dict, trace=None) -> bytes:
+    """A ``query_range`` body the way it was made before the native
+    matrix writer, kept as the oracle of its tests: a Python list a
+    point, a dict a row, one ``json.dumps`` over the whole tree."""
+    import math
+
+    from victoriametrics_tpu.query.format_value import fmt_value
+    result = []
+    for r in series:
+        vals = [[float(t), fmt_value(v)]
+                for t, v in zip(grid_s, r.values)
+                if not math.isnan(v)]
+        if vals:
+            result.append({"metric": r.metric_name.to_dict(),
+                           "values": vals})
+    body = dict(head, data={"resultType": "matrix", "result": result})
+    if trace is not None:
+        body["trace"] = trace
+    return json.dumps(body).encode()

@@ -23,7 +23,9 @@ def app(tmp_path):
                         "-httpListenAddr=127.0.0.1:0"])
     storage, srv, api = build(args)
     srv.start()
-    yield Client(srv.port)
+    client = Client(srv.port)
+    client.api = api  # for tests that evaluate beside the server
+    yield client
     srv.stop()
     storage.close()
 
@@ -727,3 +729,71 @@ class TestOpsEndpoints:
         finally:
             srv.stop()
             storage.close()
+
+
+class TestMatrixWriter:
+    """``query_range``'s body, written by the native matrix writer or by
+    its Python fallback, is byte for byte one ``json.dumps`` over the
+    tree of rows the evaluator answers."""
+
+    QUERY = 'mw_metric'
+    START, END, STEP = T0, T0 + 600_000, 20_000  # on the step's grid
+
+    @pytest.fixture()
+    def served(self, app):
+        lines = []
+        for i, esc in enumerate(['plain', 'q\\"uote\\\\back', 'é\\nline']):
+            # row 1 starts late, row 2 ends early: absent points at both ends
+            for j in [range(41), range(20, 41), range(10)][i]:
+                v = [j / 7.0, float(j), float(np.float32(j * 0.3))][i]
+                lines.append(f'mw_metric{{idx="{i}",esc="{esc}"}} {v!r} '
+                             f'{T0 + j * 15_000}')
+        code, resp = app.post("/api/v1/import/prometheus",
+                              "\n".join(lines).encode())
+        assert code == 204, resp
+        return app, app.api
+
+    def _counted(self, client):
+        code, text = client.get("/metrics")
+        assert code == 200
+        return {w: int(float(line.rsplit(" ", 1)[1]))
+                for line in text.decode().splitlines()
+                for w in ("native", "python")
+                if line.startswith(
+                    f'vm_http_matrix_points_total{{writer="{w}"}}')}
+
+    @pytest.mark.requires_native
+    @pytest.mark.parametrize("masked", [False, True],
+                             ids=["native", "native_masked"])
+    @pytest.mark.parametrize("trace", ["", "1"], ids=["plain", "trace"])
+    def test_body_is_the_tree_dump(self, served, monkeypatch, trace, masked):
+        from tests.apptest_helpers import tree_matrix_body
+        from victoriametrics_tpu import native
+        from victoriametrics_tpu.query.exec import exec_query
+        client, api = served
+        if masked:
+            monkeypatch.setattr(native, "available", lambda: False)
+        before = self._counted(client)
+        code, body = client.get(
+            "/api/v1/query_range", query=self.QUERY, start=self.START / 1e3,
+            end=self.END / 1e3, step=self.STEP // 1000, nocache="1",
+            **({"trace": "1"} if trace else {}))
+        assert code == 200, body
+        after = self._counted(client)
+
+        ec = api._ec(self.START, self.END, self.STEP)
+        ec.disable_cache = True
+        rows = exec_query(ec, self.QUERY)
+        answer = json.loads(body)
+        assert ("trace" in answer) == bool(trace)
+        want = tree_matrix_body(
+            ec.timestamps() / 1e3, rows,
+            {"status": "success", "isPartial": False,
+             "partialResolution": False}, answer.get("trace"))
+        assert body == want
+        assert b'q\\"uote\\\\back' in body and b"\\u00e9\\nline" in body
+        points = sum(len(r["values"]) for r in answer["data"]["result"])
+        assert 0 < points < len(rows) * ec.n_points  # absent points skipped
+        wrote, idle = ("python", "native") if masked else ("native", "python")
+        assert after[wrote] - before[wrote] == points
+        assert after[idle] == before[idle]

@@ -38,6 +38,7 @@ from ..storage.metric_name import MetricName
 from ..utils import fasttime, flightrec, logger
 from ..utils import metrics as metricslib
 from ..utils.workpool import SearchLimitError
+from . import matrix
 from .server import HTTPServer, Request, Response, StreamingResponse
 
 #: scatter-gather responses that came back incomplete (a storage node
@@ -569,26 +570,16 @@ class PrometheusAPI:
         if denied is not None:
             return denied
         with flightrec.phase("serve:rows"):
-            grid = ec.timestamps() / 1e3
-            result = []
-            for r in rows:
-                vals = [[float(t), _fmt_value(v)]
-                        for t, v in zip(grid, r.values)
-                        if not math.isnan(v)]
-                if vals:
-                    result.append({"metric": r.metric_name.to_dict(),
-                                   "values": vals})
+            result = matrix.rows(ec.timestamps() / 1e3, rows)
         qt.donef("%d result series", len(result))
-        body = {"status": "success",
+        head = {"status": "success",
                 "isPartial": bool(getattr(self.storage, "last_partial",
                                           False)),
                 "partialResolution": bool(getattr(
-                    self.storage, "last_partial_resolution", False)),
-                "data": {"resultType": "matrix", "result": result}}
-        if qt.enabled:
-            body["trace"] = qt.to_dict()
+                    self.storage, "last_partial_resolution", False))}
         with flightrec.phase("serve:json"):
-            return Response.json(body)
+            return Response.matrix(head, result,
+                                   qt.to_dict() if qt.enabled else None)
 
     def h_watch(self, req: Request) -> Response:
         """Materialized-stream subscription push (``/api/v1/watch?query=

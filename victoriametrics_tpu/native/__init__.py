@@ -18,7 +18,8 @@ import numpy as np
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SO = os.path.join(_DIR, "libvmcodec.so")
 
-_SOURCES = ("codec.cpp", "parse.cpp", "ingest.cpp", "Makefile")
+_SOURCES = ("codec.cpp", "parse.cpp", "ingest.cpp", "format.cpp",
+            "Makefile")
 
 _lib = None
 
@@ -154,6 +155,9 @@ def _configure(lib):
     lib.vm_keymap_size.argtypes = [i64]
     lib.vm_keymap_resolve.restype = i64
     lib.vm_keymap_resolve.argtypes = [i64, p8, pi64, pi64, i64, pi64]
+    lib.vm_write_matrix.restype = i64
+    lib.vm_write_matrix.argtypes = [pf64, i64, pf64, i64, p8, i64, pi64,
+                                    pi64]
     return lib
 
 
@@ -739,3 +743,40 @@ def marshal_i64_many(vals: np.ndarray, offsets: np.ndarray):
     if n < 0:
         raise ValueError("native batched marshal failed")
     return out.raw[:n], types, firsts, lens
+
+
+#: the most bytes one point of a matrix answer takes in ``write_matrix``'s
+#: text: ``[`` t ``, "`` v ``"]`` and the ``, `` before the next, with t
+#: and v at 24 each (the longest ``repr(float)``); a row adds 2 brackets
+MATRIX_POINT_MAX = 56
+
+
+def write_matrix(grid_s: np.ndarray, block: np.ndarray):
+    """The ``values`` text of a ``query_range`` answer in one native
+    pass: grid_s = float64 [T] seconds, block = float64 [R, T] (NaN =
+    absent).  Returns (buf, row_ends int64 [R], n_points): row i's text
+    ``[[t, "v"], ...]`` is ``buf[row_ends[i-1]:row_ends[i]]``, empty for
+    a row with no point; byte for byte what ``json.dumps`` makes of
+    ``[[float(t), fmt_value(v)], ...]``.  None when the native library
+    is unavailable."""
+    lib = _load()
+    if lib is None:
+        return None
+    grid_s = np.ascontiguousarray(grid_s, dtype=np.float64)
+    block = np.ascontiguousarray(block, dtype=np.float64)
+    if block.ndim != 2 or grid_s.shape != block.shape[1:]:
+        raise ValueError(
+            f"grid {grid_s.shape} does not fit a block {block.shape}")
+    r, t = block.shape
+    cap = r * (t * MATRIX_POINT_MAX + 2)
+    out = np.empty(cap, dtype=np.uint8)
+    row_ends = np.empty(r, dtype=np.int64)
+    n_points = ctypes.c_int64()
+    pf64 = ctypes.POINTER(ctypes.c_double)
+    n = lib.vm_write_matrix(
+        grid_s.ctypes.data_as(pf64), t, block.ctypes.data_as(pf64), r,
+        out.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)), cap,
+        _as_i64_ptr(row_ends), ctypes.byref(n_points))
+    if not 0 <= n <= cap:
+        raise ValueError(f"native matrix writer: {n} of {cap} bytes")
+    return memoryview(out)[:n], row_ends, n_points.value
