@@ -115,7 +115,9 @@ def _eval_func(ec: EvalConfig, fe: FuncExpr) -> list[Timeseries]:
             # everything else is a series list; scalar params unwrap via
             # _scalar_arg (const scalars become 1-series constants)
             args.append(eval_expr(ec, a))
-    out = tf(ec, args)
+    # the transform's own work, its arguments' evaluation left outside
+    with flightrec.phase("eval:transform"):
+        out = tf(ec, args)
     if fe.keep_metric_names:
         srcs = [a for a in args if isinstance(a, list)]
         if srcs and len(srcs[0]) == len(out):
@@ -1017,6 +1019,15 @@ def device_window_ready(ec: EvalConfig, e: Expr) -> bool:
     from ..models.tile_cache import device_resident_enabled
     if not device_resident_enabled():
         return False
+    # a transform of one series argument (histogram_quantile(phi, aggr))
+    # is served as its argument is: the full eval advances the resident
+    # window for the aggregate and transforms the [G, T] block it answers
+    while isinstance(e, FuncExpr) and e.name in TRANSFORM_FUNCS:
+        series_args = [a for a in e.args
+                       if not isinstance(a, (NumberExpr, StringExpr))]
+        if len(series_args) != 1:
+            return False
+        e = series_args[0]
     if not isinstance(e, AggrFuncExpr):
         return False
     shape = _device_aggr_shape(e)

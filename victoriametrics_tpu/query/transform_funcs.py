@@ -13,9 +13,13 @@ import re
 import numpy as np
 
 from ..storage.metric_name import MetricName
+from ..utils import metrics as metricslib
 from .types import EvalConfig, Timeseries, const_series, new_series
 
 nan = np.nan
+
+# (group, step) points histogram_quantile answered
+_HQ_POINTS = metricslib.REGISTRY.counter("vm_histogram_quantile_points_total")
 
 
 # -- helpers -----------------------------------------------------------------
@@ -670,73 +674,69 @@ def tf_histogram_quantile(ec, args):
     series = _vmrange_to_le(list(args[1]))
     bounds_label = args[2].encode() if len(args) > 2 and \
         isinstance(args[2], str) else None
-    out = []
-    for key, (mn, buckets) in _group_buckets(series).items():
+    # groups with the same bucket bounds (every group of a real panel)
+    # go through the transform as ONE [groups, buckets, T] block
+    blocks: dict[tuple, list] = {}
+    for mn, buckets in _group_buckets(series).values():
         buckets.sort(key=lambda b: b[0])
         buckets = _merge_same_le(buckets)
-        les = np.array([b[0] for b in buckets])
-        m = np.vstack([b[1] for b in buckets])  # [B, T] cumulative counts
+        blocks.setdefault(tuple(b[0] for b in buckets), []).append(
+            (mn, np.stack([b[1] for b in buckets])))
+    out = []
+    for key, members in blocks.items():
+        les = np.array(key)
         with np.errstate(all="ignore"):
-            vals = _hist_quantile_cols(phis, les, m)
+            vals = _hist_quantile_block(
+                phis, les, np.stack([m for _, m in members]))
+        _HQ_POINTS.inc(vals.size)
         if bounds_label:
             # lower/upper bucket-edge bound series (prometheus issue 5706)
-            lo = np.full(vals.shape, nan)
-            hi = np.full(vals.shape, nan)
             fin = np.isfinite(vals)
-            if fin.any():
-                for j in np.flatnonzero(fin):
-                    i = int(np.searchsorted(les, vals[j], side="left"))
-                    lo[j] = les[i - 1] if i > 0 else 0.0
-                    hi[j] = les[min(i, les.size - 1)]
-            for tag, bvals in ((b"lower", lo), (b"upper", hi)):
-                b = MetricName(mn.metric_group,
-                               [(k, v) for k, v in mn.labels
-                                if k != bounds_label] +
-                               [(bounds_label, tag)])
-                b.sort_labels()
-                out.append(Timeseries(b, bvals))
-        out.append(Timeseries(mn, vals))
+            i = np.searchsorted(les, np.where(fin, vals, 0.0), side="left")
+            lo = np.where(fin, np.where(i > 0, les[i - 1], 0.0), nan)
+            hi = np.where(fin, les[np.minimum(i, les.size - 1)], nan)
+        for g, (mn, _) in enumerate(members):
+            if bounds_label:
+                for tag, bvals in ((b"lower", lo[g]), (b"upper", hi[g])):
+                    b = MetricName(mn.metric_group,
+                                   [(k, v) for k, v in mn.labels
+                                    if k != bounds_label] +
+                                   [(bounds_label, tag)])
+                    b.sort_labels()
+                    out.append(Timeseries(b, bvals))
+            out.append(Timeseries(mn, vals[g]))
     return out
 
 
-def _hist_quantile_cols(phi, les: np.ndarray, m: np.ndarray) -> np.ndarray:
-    T = m.shape[1]
-    phi_arr = np.broadcast_to(np.asarray(phi, dtype=np.float64), (T,))
-    out = np.full(T, nan)
-    if not np.isfinite(les[-1]) and les.size < 2:
-        return out
-    for j in range(T):
-        phi = float(phi_arr[j])
-        counts = m[:, j]
-        if np.isnan(counts).all():
-            continue
-        counts = np.nan_to_num(counts)
-        # enforce monotonicity (float jitter)
-        counts = np.maximum.accumulate(counts)
-        total = counts[-1]
-        if total == 0:
-            continue
-        if phi < 0:
-            out[j] = -np.inf
-            continue
-        if phi > 1:
-            out[j] = np.inf
-            continue
-        rank = phi * total
-        idx = int(np.searchsorted(counts, rank, side="left"))
-        idx = min(idx, les.size - 1)
-        if not np.isfinite(les[idx]):
-            # +Inf bucket: return the upper bound of the previous bucket
-            out[j] = les[idx - 1] if idx > 0 else nan
-            continue
-        lo = les[idx - 1] if idx > 0 else 0.0
-        c_lo = counts[idx - 1] if idx > 0 else 0.0
-        c_hi = counts[idx]
-        if c_hi <= c_lo:
-            out[j] = les[idx]
-            continue
-        out[j] = lo + (les[idx] - lo) * (rank - c_lo) / (c_hi - c_lo)
-    return out
+def _hist_quantile_block(phi, les: np.ndarray, m: np.ndarray) -> np.ndarray:
+    """[G, T] quantiles of [G, B, T] cumulative counts over the ascending
+    bounds `les` [B]; `phi` a float or a [T] array.  One float64 array
+    pass; every edge is a mask, applied in the order upstream's per-point
+    loop (transform.go transformHistogramQuantile) decides them."""
+    G, B, T = m.shape
+    if not np.isfinite(les[-1]) and B < 2:
+        return np.full((G, T), nan)
+    phi = np.broadcast_to(np.asarray(phi, dtype=np.float64), (T,))
+    # NaN counts as 0; the running maximum enforces monotonicity
+    c = np.maximum.accumulate(np.nan_to_num(m), axis=1)
+    total = c[:, -1]
+    rank = phi * total
+    # the first bucket at or above the rank (a NaN rank finds none)
+    idx = np.minimum((~(c >= rank[:, None])).sum(axis=1), B - 1)
+    below = np.maximum(idx - 1, 0)
+    first = idx == 0
+    le_hi = les[idx]
+    le_lo = np.where(first, 0.0, les[below])         # lowest bucket from 0
+    c_hi = np.take_along_axis(c, idx[:, None], axis=1)[:, 0]
+    c_lo = np.where(first, 0.0,
+                    np.take_along_axis(c, below[:, None], axis=1)[:, 0])
+    out = le_lo + (le_hi - le_lo) * (rank - c_lo) / (c_hi - c_lo)
+    out = np.where(c_hi <= c_lo, le_hi, out)
+    # +Inf bucket: the upper bound of the bucket before it
+    out = np.where(np.isfinite(le_hi), out, np.where(first, nan, les[below]))
+    out = np.where(phi > 1, np.inf, out)
+    out = np.where(phi < 0, -np.inf, out)
+    return np.where(np.isnan(m).all(axis=1) | (total == 0), nan, out)
 
 
 def tf_histogram_avg(ec, args):

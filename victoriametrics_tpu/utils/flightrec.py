@@ -281,7 +281,7 @@ def instant(name: str, arg=None) -> None:
 #: first use.
 QUERY_PHASES = (
     "serve:admission", "serve:rows", "serve:json", "serve:send",
-    "serve:other", "eval:other", "fetch:wait",
+    "serve:other", "eval:other", "eval:transform", "fetch:wait",
     "cache:get", "cache:put", "cache:merge",
     "device:tile_build", "device:upload", "device:execute",
     "device:download", "device:compile")
