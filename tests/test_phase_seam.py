@@ -130,6 +130,45 @@ def test_self_times_partition_the_root_wall(monkeypatch):
         tr.wall_ms["device:tile_build"] / 1e3, abs=1e-6)
 
 
+@pytest.mark.parametrize("between", [False, True],
+                         ids=["direct_child", "through_a_plain_phase"])
+def test_a_stage_carved_out_of_a_stage_keeps_the_familys_sum(between):
+    """A phase with a counter nested in one (`fetch:pending_convert` in
+    the collect stage): each member gets its own share, the family sums
+    to the outer stage's duration, the query family reads none of it."""
+    outer = metricslib.REGISTRY.float_counter(
+        'vm_fetch_phase_seconds_total{phase="assemble_native"}')
+    inner = metricslib.REGISTRY.float_counter(
+        'vm_fetch_phase_seconds_total{phase="pending_convert"}')
+    m0 = _metrics()
+    with flightrec.phase("serve:other", root=True):
+        with flightrec.phase("fetch:wait"):
+            with flightrec.phase("fetch:index_search", counter=outer) as ph:
+                ph.lap("fetch:assemble_native", outer)
+                time.sleep(0.002)
+                if between:
+                    with flightrec.phase("device:upload"), \
+                            flightrec.phase("fetch:pending_convert",
+                                            counter=inner) as conv:
+                        time.sleep(0.004)
+                else:
+                    with flightrec.phase("fetch:pending_convert",
+                                         counter=inner) as conv:
+                        time.sleep(0.004)
+                t_lap = time.perf_counter()
+                stage = t_lap - ph.t0
+                ph.lap("fetch:assemble", outer)
+    m1 = _metrics()
+    d = _delta(m0, m1, "vm_fetch_phase_seconds_total{")
+    got_inner = d['vm_fetch_phase_seconds_total{phase="pending_convert"}']
+    got_outer = d['vm_fetch_phase_seconds_total{phase="assemble_native"}']
+    assert got_inner == pytest.approx(conv.dur, abs=1e-9)
+    assert got_inner >= 0.004 and got_outer >= 0.002
+    assert got_inner + got_outer == pytest.approx(stage, abs=2e-4)
+    fam = _delta(m0, m1, FAMILY)
+    assert fam[_member("fetch:wait")] >= (0.002 if between else 0.006)
+
+
 def test_abandoned_child_does_not_outlive_its_parent():
     """A phase left open (a generator dropped mid-phase) is swept off
     the stack when its parent exits; the next request starts clean."""

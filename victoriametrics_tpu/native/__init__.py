@@ -19,7 +19,7 @@ _DIR = os.path.dirname(os.path.abspath(__file__))
 _SO = os.path.join(_DIR, "libvmcodec.so")
 
 _SOURCES = ("codec.cpp", "parse.cpp", "ingest.cpp", "format.cpp",
-            "Makefile")
+            "pending.cpp", "Makefile")
 
 _lib = None
 
@@ -158,6 +158,11 @@ def _configure(lib):
     lib.vm_write_matrix.restype = i64
     lib.vm_write_matrix.argtypes = [pf64, i64, pf64, i64, p8, i64, pi64,
                                     pi64]
+    pp = ctypes.POINTER(ctypes.c_void_p)
+    lib.vm_pending_order.restype = i64
+    lib.vm_pending_order.argtypes = [pp, pp, pp, pi64, i64, pi32, i64, i64,
+                                     ctypes.c_void_p, i64, pi64, pf64, pi64,
+                                     ctypes.c_void_p, pi64]
     return lib
 
 
@@ -780,3 +785,44 @@ def write_matrix(grid_s: np.ndarray, block: np.ndarray):
     if not 0 <= n <= cap:
         raise ValueError(f"native matrix writer: {n} of {cap} bytes")
     return memoryview(out)[:n], row_ends, n_points.value
+
+
+def pending_order(chunks: list, rank: np.ndarray, n_ranks: int,
+                  mid: np.ndarray, max_block_rows: int):
+    """The rows of a batch of pending chunks (`(ids, ts, vals)` arrays of
+    each, ingest order) in (TSID, ts) order, stable: `rank` is the id
+    space's int32 rank of every dense id in TSID order (equal keys share
+    one, `n_ranks` of them), `mid` its uint64 metric-id column.  Returns
+    (ts, vals, ids, mids) of the ordered rows and the start row of every
+    block (a run of one metric id, cut every `max_block_rows`); None when
+    the library is missing or an id lies outside the rank."""
+    lib = _load()
+    if lib is None:
+        return None
+    k = len(chunks)
+    cols = [(np.ascontiguousarray(i, np.int64),
+             np.ascontiguousarray(t, np.int64),
+             np.ascontiguousarray(v, np.float64)) for i, t, v in chunks]
+    if any(not i.size == t.size == v.size for i, t, v in cols):
+        raise ValueError("a pending chunk's columns differ in length")
+    ptrs = [(ctypes.c_void_p * k)(*[c[j].ctypes.data for c in cols])
+            for j in range(3)]
+    lens = np.fromiter((c[0].size for c in cols), np.int64, k)
+    n = int(lens.sum())
+    rank = np.ascontiguousarray(rank, np.int32)
+    mid = np.ascontiguousarray(mid, np.uint64)
+    ts = np.empty(n, np.int64)
+    vals = np.empty(n, np.float64)
+    ids = np.empty(n, np.int64)
+    mids = np.empty(n, np.uint64)
+    starts = np.empty(n, np.int64)
+    nb = lib.vm_pending_order(
+        ptrs[0], ptrs[1], ptrs[2], _as_i64_ptr(lens), k,
+        rank.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
+        min(rank.size, mid.size), int(n_ranks), mid.ctypes.data,
+        int(max_block_rows), _as_i64_ptr(ts),
+        vals.ctypes.data_as(ctypes.POINTER(ctypes.c_double)),
+        _as_i64_ptr(ids), mids.ctypes.data, _as_i64_ptr(starts))
+    if nb < 0:
+        return None
+    return ts, vals, ids, mids, starts[:nb].copy()

@@ -48,6 +48,17 @@ _ACTIVE_MERGES = metricslib.REGISTRY.gauge(
 _ING_FLUSH = metricslib.ingest_phase("flush")
 _ING_MERGE = metricslib.ingest_phase("merge")
 _SPILL_ERRORS = metricslib.REGISTRY.counter("vm_ingest_spill_errors_total")
+# rows turned from pending rows into an InmemoryPart, by the code that
+# ordered them (one inc a conversion, on whichever thread ran it): the
+# native pass over one id space's chunks, or the lexsort of anything else
+_CONVERT_ROWS = {
+    path: metricslib.REGISTRY.counter(
+        f'vm_pending_convert_rows_total{{path="{path}"}}')
+    for path in ("native", "lexsort")}
+# the calling thread's wait for fresh rows to become readable parts
+# (storage/storage.py _PHASE lists the family)
+_PENDING_CONVERT = metricslib.REGISTRY.float_counter(
+    'vm_fetch_phase_seconds_total{phase="pending_convert"}')
 # torn/corrupt parts moved aside at open instead of being served or
 # silently dropped (one series per store kind; mergeset ticks its own)
 _PARTS_QUARANTINED = metricslib.REGISTRY.counter(
@@ -241,6 +252,56 @@ def _rows_to_inmemory_part(rows: list, precision_bits: int = 64) -> InmemoryPart
     (float_to_decimal_grouped): per-series scrape flushes produce thousands
     of ~tens-of-rows blocks, where per-block conversion overhead dominates
     the flush."""
+    part = _chunks_to_inmemory_part(rows, precision_bits)
+    if part is not None:
+        _CONVERT_ROWS["native"].inc(part.rows)
+        return part
+    part = _lexsort_to_inmemory_part(rows, precision_bits)
+    _CONVERT_ROWS["lexsort"].inc(part.rows)
+    return part
+
+
+def _chunks_to_inmemory_part(items: list, precision_bits: int):
+    """The conversion in one native pass (native/pending.cpp), for what
+    columnar ingest parks: PendingChunks of ONE id space.  The space's
+    TSID rank of its ids (`tsid_rank`) stands in for the six key columns
+    of the lexsort, so the rows are counted into place and only ordered by
+    timestamp inside a series' short run.  Builds the part
+    `_mixed_to_inmemory_part` builds, array for array.  None where the
+    input is anything else (a legacy tuple, two tenants' spaces), the
+    library is missing or the space's rank is not worth reading yet: the
+    caller then sorts."""
+    if not items or not all(isinstance(x, PendingChunk) for x in items):
+        return None
+    sp = items[0].space
+    if any(x.space is not sp for x in items):
+        return None
+    from .. import native
+    if not native.available():
+        return None
+    n = sum(len(x) for x in items)
+    if n == 0:
+        return InmemoryPart([])
+    ranked = sp.tsid_rank(n)
+    if ranked is None:
+        return None
+    ordered = native.pending_order([(x.ids, x.ts, x.vals) for x in items],
+                                   *ranked, sp.mid, MAX_ROWS_PER_BLOCK)
+    if ordered is None:
+        return None
+    from ..ops.decimal import float_to_decimal_grouped
+    all_ts, all_vals, loc, mid, starts = ordered
+    ends = np.append(starts[1:], n)
+    tsids = sp.tsids
+    m_all, exps = float_to_decimal_grouped(all_vals, starts)
+    return InmemoryPart.from_seg_arrays(
+        starts, ends, mid, lambda r: tsids[loc[r]], all_ts, m_all, exps,
+        precision_bits)
+
+
+def _lexsort_to_inmemory_part(rows: list, precision_bits: int) -> InmemoryPart:
+    """The conversion by sorting: whatever `_chunks_to_inmemory_part` does
+    not take, and its oracle."""
     if any(isinstance(r, PendingChunk) for r in rows):
         return _mixed_to_inmemory_part(rows, precision_bits)
     from ..ops.decimal import float_to_decimal_grouped
@@ -1080,8 +1141,12 @@ class Partition:
         ``note`` (dict) reports the choice: ``ds_res`` (max resolution
         actually served) and ``partial_res``."""
         while True:
-            self._drain_inflight()
-            pend, gen = self._pending_views()
+            # the visibility barrier: every row acknowledged before this
+            # query is a readable part before the part lists are read
+            with flightrec.phase("fetch:pending_convert",
+                                 counter=_PENDING_CONVERT):
+                self._drain_inflight()
+                pend, gen = self._pending_views()
             with self._lock:
                 if self._pending_gen == gen and not self._pending_inflight:
                     mems = list(self._mem_parts)

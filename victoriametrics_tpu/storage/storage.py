@@ -49,11 +49,14 @@ _PHASE = {
     ph: metricslib.REGISTRY.float_counter(
         f'vm_fetch_phase_seconds_total{{phase="{ph}"}}')
     for ph in ("index_search", "collect", "decode", "assemble",
-               "assemble_native", "queue_wait")
+               "assemble_native", "queue_wait", "pending_convert")
 }
 # phase="queue_wait" (time queued at the SearchGate before the fetch
 # starts) is INCREMENTED in utils/workpool.SearchGate — listed here so
-# the family is complete at import and the split sums to wall time
+# the family is complete at import and the split sums to wall time;
+# likewise phase="pending_convert" (the calling thread's wait for fresh
+# rows to become readable parts), carved out of the collect stage by
+# storage/partition.py Partition.collect_units
 
 # write-path twin of _PHASE: where ingest time goes (the flush/merge
 # phases are fed by partition.py / mergeset.py)
@@ -114,7 +117,8 @@ class _ColumnarSpace:
     of a dropped series are filtered with one mask, never re-judged."""
 
     __slots__ = ("keymap", "tsids", "acc", "proj", "grp", "job", "inst",
-                 "mid", "drop", "last_date", "_cap", "lock", "retired")
+                 "mid", "drop", "last_date", "_cap", "lock", "retired",
+                 "_rank", "_n_keyed", "_rank_debt", "_rank_lock")
 
     #: distinct raw keys per tenant space before the whole space is rebuilt
     #: — same bound (and rationale) as the legacy raw TSID cache clear at
@@ -142,6 +146,13 @@ class _ColumnarSpace:
         self.mid = z.copy()
         self.drop = np.zeros(0, np.uint8)
         self.last_date = np.zeros(0, np.int64)
+        # the ids' TSID order (tsid_rank), under its own lock: _rank is
+        # (rank, n_ranks) of the first _n_keyed ids, None once their key
+        # columns changed; _rank_debt the rows converted without it since
+        self._rank = None
+        self._n_keyed = 0
+        self._rank_debt = 0
+        self._rank_lock = make_lock("storage._ColumnarSpace._rank_lock")
 
     def _grow(self, need: int) -> None:
         """Amortized-doubling growth of the per-id columns (append_ids runs
@@ -178,6 +189,7 @@ class _ColumnarSpace:
             self.drop[i] = d
             self.last_date[i] = -(1 << 62)
         self.tsids.extend(tsids)
+        self._keys_changed()
 
     def set_tsid(self, i: int, tsid) -> None:
         """Re-admits a previously dropped id (cardinality retry)."""
@@ -190,6 +202,51 @@ class _ColumnarSpace:
         self.mid[i] = tsid.metric_id
         self.drop[i] = 0
         self.last_date[i] = -(1 << 62)
+        self._keys_changed()
+
+    def _keys_changed(self) -> None:
+        """append_ids and set_tsid end here: a rank read before is void.
+        One that was being read meanwhile is stored before this runs, so
+        dropped here too."""
+        with self._rank_lock:
+            self._rank = None
+            self._n_keyed = len(self.tsids)
+
+    def tsid_rank(self, n_rows: int):
+        """(rank, n_ranks): the int32 rank of every registered id in
+        TSID order (acc, proj, grp, job, inst, mid), ids of equal keys
+        sharing one — what orders a batch of pending rows without
+        sorting it by seven keys (partition._chunks_to_inmemory_part).
+        A property of the space: read once, and again after append_ids
+        or set_tsid.  Reading it sorts every id, so a changed space
+        reads it only once the rows converted without it (`n_rows` a
+        call) outnumber its ids: a space that registers series with
+        every batch never pays more for the rank than the batches' own
+        sorts cost; None until then.
+
+        Called without `lock`, beside a writer: a conversion's ids were
+        registered, and their keys final, before its chunk was parked,
+        so a rank read since orders them right, whatever a registration
+        in flight is doing to newer ids."""
+        with self._rank_lock:
+            if self._rank is not None:
+                return self._rank
+            n = self._n_keyed
+            self._rank_debt += n_rows
+            if self._rank_debt < n:
+                return None
+            keys = [getattr(self, f)[:n] for f in
+                    ("mid", "inst", "job", "grp", "proj", "acc")]
+            order = np.lexsort(keys)
+            first = np.zeros(n, bool)
+            for k in keys:
+                ks = k[order]
+                first[1:] |= ks[1:] != ks[:-1]
+            rank = np.empty(n, np.int32)
+            rank[order] = np.cumsum(first, dtype=np.int32)
+            self._rank = (rank, int(rank[order[-1]]) + 1 if n else 0)
+            self._rank_debt = 0
+            return self._rank
 
     def close(self):
         # every caller holds self.lock via acquire/release bracketing the

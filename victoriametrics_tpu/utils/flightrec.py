@@ -325,7 +325,9 @@ class phase:
 
     `counter` names a family of the phase's OWN
     (``vm_fetch_phase_seconds_total``, ``vm_ingest_phase_seconds_total``):
-    it gets the inclusive duration, and the phase never charges
+    it gets the duration less what nested phases with a counter took
+    (a stage carved out of another, so a family still sums to the time
+    its stages covered), and the phase never charges
     ``vm_query_phase_seconds_total`` — its time stays with the enclosing
     query phase there (an inline fetch reads as ``fetch:wait`` exactly
     as a pooled one does).  Without `counter` the self time goes to
@@ -343,7 +345,7 @@ class phase:
     learns only afterwards that it compiled)."""
 
     __slots__ = ("name", "arg", "counter", "root", "t0", "dur", "_child",
-                 "_acct", "_cpu", "_cpu_mark", "_ann", "_own_ctx")
+                 "_acct", "_staged", "_cpu", "_cpu_mark", "_ann", "_own_ctx")
 
     def __init__(self, name: str, arg=None, counter=None,
                  root: bool = False):
@@ -377,8 +379,9 @@ class phase:
     def _open(self) -> None:
         # _child: inclusive seconds of direct children (self = dur - it);
         # _acct: seconds inside this region that descendants already
-        # charged to the query family
-        self._child = self._acct = self._cpu = 0.0
+        # charged to the query family; _staged: seconds that descendants
+        # charged to counters of their own
+        self._child = self._acct = self._staged = self._cpu = 0.0
         ann = None
         if _annotator is not None and _ENABLED:
             ann = _annotator("vm:" + self.name)
@@ -436,12 +439,14 @@ class phase:
         if self.counter is not None:
             # a family of its own: the time no descendant charged stays
             # with the enclosing query phase
-            self.counter.inc(dur)
+            self.counter.inc(max(dur - self._staged, 0.0))
             if parent is not None:
                 parent._acct += self._acct
+                parent._staged += dur
             return
         if parent is not None:
             parent._acct += dur
+            parent._staged += self._staged
         if rooted:
             c = _QUERY_PHASE.get(name)
             if c is None:
