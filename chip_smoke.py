@@ -40,7 +40,7 @@ import numpy as np
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
 
-# the dashboard deployment (bench.py's, BASELINE.md config 2 shape)
+# the dashboard deployment (BASELINE.md config 2 shape)
 N_SERIES = 8192
 N_SAMPLES = 1440          # 6 h at 15 s
 N_INSTANCES = 256

@@ -178,7 +178,7 @@ def test_four_chip_series_sharded_step(topo, chip_regime):
 
     from victoriametrics_tpu.parallel.mesh import (make_mesh,
                                                    sharded_rollup_aggregate)
-    mesh = make_mesh(n_series=4, n_time=1, devices=topo.devices)
+    mesh = make_mesh(topo.devices)
     at = _on_mesh(mesh)
     fn = sharded_rollup_aggregate(mesh, "rate", "sum", _cfg("rate"), G)
     c = jax.jit(fn).lower(
@@ -198,7 +198,7 @@ def test_four_chip_decode_and_append(topo, chip_regime):
     from victoriametrics_tpu.ops.device_decode import decode_tiles
     from victoriametrics_tpu.ops.device_rollup import append_tile
     from victoriametrics_tpu.parallel.mesh import make_mesh
-    mesh = make_mesh(n_series=4, n_time=1, devices=topo.devices)
+    mesh = make_mesh(topo.devices)
     at = _on_mesh(mesh)
     v = lambda name, dt: at(name, (S,), dt)  # noqa: E731
     c = decode_tiles.lower(

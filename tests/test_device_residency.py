@@ -37,7 +37,7 @@ def _mesh8():
     devs = jax.devices()
     if len(devs) < 8:
         pytest.skip("needs 8 virtual devices")
-    return make_mesh(n_series=8, n_time=1, devices=devs[:8])
+    return make_mesh(devs[:8])
 
 
 def _mk_store(path, n_samples=NN, name="resg"):

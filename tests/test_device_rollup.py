@@ -125,7 +125,7 @@ class TestMesh:
     @pytest.mark.parametrize("aggr", ["sum", "avg", "max", "count"])
     def test_series_sharded_matches_single_device(self, aggr):
         series, ts, vals, counts, gids = self._data()
-        mesh = meshlib.make_mesh(n_series=8, n_time=1)
+        mesh = meshlib.make_mesh(jax.devices()[:8])
         fn = meshlib.sharded_rollup_aggregate(mesh, "rate", aggr, CFG, 5)
         from victoriametrics_tpu.ops.device_rollup import MIN_TS_NONE
         got = np.asarray(fn(jnp.asarray(ts), jnp.asarray(vals),
@@ -136,37 +136,6 @@ class TestMesh:
         want = np.asarray(aggregate_groups(aggr, rolled, jnp.asarray(gids), 5))
         np.testing.assert_allclose(got, want, rtol=1e-9, atol=1e-9,
                                    equal_nan=True)
-
-    @pytest.mark.parametrize("func", ["rate", "sum_over_time", "timestamp",
-                                      "max_over_time", "changes"])
-    def test_time_sharded_matches_single_device(self, func):
-        # sequence-parallel: samples split into contiguous time chunks
-        rng = np.random.default_rng(31)
-        S, N = 8, 512
-        interval = 10_000
-        ts = np.tile(np.arange(N, dtype=np.int64) * interval, (S, 1))
-        vals = np.cumsum(rng.integers(0, 20, (S, N)), axis=1).astype(np.float64)
-        cfg = RollupConfig(start=0, end=N * interval - interval,
-                           step=interval * 4, window=interval * 8)
-        T = (cfg.end - cfg.start) // cfg.step + 1
-        assert T % 4 == 0
-        mesh = meshlib.make_mesh(n_series=2, n_time=4)
-        valid = np.ones((S, N), dtype=bool)
-        halo = 16  # > window/interval + 1
-        fn = meshlib.time_sharded_rollup(mesh, func, cfg, halo)
-        got = np.asarray(fn(jnp.asarray(ts.astype(np.int32)),
-                            jnp.asarray(vals), jnp.asarray(valid)))
-        counts = np.full(S, N, dtype=np.int32)
-        want = np.asarray(rollup_tile(func, jnp.asarray(ts.astype(np.int32)),
-                                      jnp.asarray(vals), jnp.asarray(counts),
-                                      cfg))
-        np.testing.assert_allclose(got, want, rtol=1e-9, atol=1e-9,
-                                   equal_nan=True)
-
-    def test_time_sharded_rejects_whole_series_funcs(self):
-        mesh = meshlib.make_mesh(n_series=2, n_time=4)
-        with pytest.raises(ValueError, match="whole-series"):
-            meshlib.time_sharded_rollup(mesh, "lifetime", CFG, 8)
 
 
 class TestDeviceDecode:

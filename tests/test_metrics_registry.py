@@ -89,7 +89,7 @@ class TestRegistry:
             r.counter('bad{unclosed="')
 
     def test_histogram_vmrange_buckets(self):
-        from victoriametrics_tpu.query.vmhistogram import vmrange_for
+        from victoriametrics_tpu.utils.vmhistogram import vmrange_for
         r = MetricsRegistry()
         h = r.histogram('t_dur_seconds{path="/q"}')
         for v in (0.0015, 0.0015, 2.5):
@@ -102,7 +102,7 @@ class TestRegistry:
         h2 = r.histogram("t_inf_seconds")
         h2.update(float("inf"))
         assert h2.get_count() == 1
-        from victoriametrics_tpu.query.vmhistogram import (UPPER_RANGE,
+        from victoriametrics_tpu.utils.vmhistogram import (UPPER_RANGE,
                                                            vmrange_for)
         assert vmrange_for(float("inf")) == UPPER_RANGE
         text = r.write_prometheus(include_process=False)

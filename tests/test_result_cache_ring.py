@@ -137,6 +137,33 @@ class TestRingServedEqualsCold:
             assert _sha(served) == _sha(_cold(s, q, start, end))
         s.close()
 
+    @pytest.mark.parametrize("age_ms,emptied", [(3 * DUR, True),
+                                                (rrc.OFFSET_MS // 2, False)])
+    @pytest.mark.parametrize("path", ["add_rows", "add_rows_columnar"])
+    def test_write_listener_resets_on_backfill_only(self, tmp_path, path,
+                                                    age_ms, emptied):
+        """Storage publishes each batch's oldest timestamp and knows no
+        cache; the cache's own listener keeps the rule: a batch reaching
+        back past OFFSET_MS empties a populated GLOBAL, a fresher one
+        leaves it, on both ingest paths."""
+        s, end = _mk_store(tmp_path)
+        api = PrometheusAPI(s)
+        api._exec_range_cached(
+            EvalConfig(start=end - DUR, end=end, step=STEP, storage=s),
+            QUERIES[0], int(time.time() * 1000))
+        assert rrc.GLOBAL.stats()["entries"] > 0
+        ts = int(time.time() * 1000) - age_ms
+        if path == "add_rows":
+            n = s.add_rows([({"__name__": "ringm", "i": "0", "g": "g0"},
+                             ts, 1.0)])
+        else:
+            from victoriametrics_tpu import native
+            n = s.add_rows_columnar(native.parse_prom_columnar(
+                b'ringm{i="0",g="g0"} 1 %d\n' % ts, ts))
+        assert n == 1
+        assert (rrc.GLOBAL.stats()["entries"] == 0) == emptied
+        s.close()
+
     @pytest.mark.parametrize("q", QUERIES)
     def test_ring_on_off_identical_rows(self, tmp_path, q):
         """Acceptance: VM_RESULT_CACHE_RING=0 and =1 produce identical

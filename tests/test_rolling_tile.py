@@ -195,7 +195,7 @@ class TestRollingTile:
         devs = jax.devices()
         if len(devs) < 8:
             pytest.skip("needs 8 virtual devices")
-        mesh = make_mesh(n_series=8, n_time=1, devices=devs[:8])
+        mesh = make_mesh(devs[:8])
         store = _mk_store(tmp_path, n_series=81)  # pad path
         try:
             engine = TPUEngine(min_series=4, mesh=mesh)

@@ -55,7 +55,7 @@ def test_direct_advancing_refresh_matches_cold_on_device(tmp_path):
         q = "sum by (instance)(rate(da[5m]))"
         dur = (NN - 1) * 15_000 - 300_000
         # round UP past all initial jittered samples (counter
-        # monotonicity across the first refresh; see bench.py)
+        # monotonicity across the first refresh)
         end = t0 + -(-((NN - 1) * 15_000 + JITTER_MS) // STEP) * STEP
         kw = dict(step=STEP, storage=s, tpu=eng)
         exec_query(EvalConfig(start=end - dur, end=end, **kw), q)
@@ -136,7 +136,7 @@ def test_served_refresh_matches_cold_on_device(tmp_path):
         q = "sum by (instance)(rate(dv[5m]))"
         dur = (NN - 1) * 15_000 - 300_000
         # round UP past all initial jittered samples (counter
-        # monotonicity across the first refresh; see bench.py)
+        # monotonicity across the first refresh)
         end = t0 + -(-((NN - 1) * 15_000 + JITTER_MS) // STEP) * STEP
         kw = dict(step=STEP, storage=s, tpu=eng)
         api._exec_range_cached(EvalConfig(start=end - dur, end=end, **kw),

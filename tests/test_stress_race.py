@@ -455,8 +455,8 @@ class TestRaceTrace:
         assert b.n == 3
 
     def test_disabled_is_plain_attribute(self, monkeypatch):
-        """With the sanitizer off, traced classes carry no descriptor (the
-        zero-overhead guarantee bench.py relies on)."""
+        """With the sanitizer off, traced classes carry no descriptor:
+        no overhead where nobody asked for the trace."""
         if racetrace.enabled():
             pytest.skip("suite running under VMT_RACETRACE=1")
         monkeypatch.setenv("VMT_LOCKTRACE", "0")

@@ -689,7 +689,7 @@ class PrometheusAPI:
         # not just the storage-fetch slice the SearchGate covers
         from ..utils import workpool
         # a flight context per refresh (reuse the HTTP handler's when one
-        # is installed — bench and tests call this directly)
+        # is installed — tests call this directly)
         fctx = flightrec.get_ctx()
         fresh_ctx = fctx == 0
         if fresh_ctx:
@@ -697,7 +697,7 @@ class PrometheusAPI:
             flightrec.set_ctx(fctx)
         # the whole refresh accounts into the query's CostTracker — the
         # HTTP bracket installs it too (re-install is idempotent), but
-        # direct callers (bench, tests) get the cache merge/put laps
+        # direct callers (tests) get the cache merge/put laps
         # only through this install
         from ..utils import costacc
         prev_cost = costacc.set_current(ec._cost)
@@ -732,8 +732,8 @@ class PrometheusAPI:
                     # refresh latency that tripped it (observer effect)
                     defer_build=True)
                 # note the id only when an outer handler frame exists to
-                # consume it (fresh_ctx means a direct call — bench and
-                # tests — where a leftover note would misattach to the
+                # consume it (fresh_ctx means a direct call, a test's,
+                # where a leftover note would misattach to the
                 # NEXT slow query this thread happens to serve)
                 if cap is not None and not fresh_ctx:
                     flightrec.note_capture(cap["id"])
@@ -1430,7 +1430,7 @@ class PrometheusAPI:
         tenant, most CPU-expensive tenant first.  On a vmselect these
         totals are CLUSTER-wide: the fan-out merges each node's shipped
         cost frame before the bracket records it.  ``?reset=1`` clears
-        the table (bench/test hygiene)."""
+        the table (test hygiene)."""
         from ..utils import costacc
         rows = costacc.TENANT_USAGE.snapshot(
             reset=req.arg("reset") == "1")

@@ -31,7 +31,7 @@ UPLOAD_CHUNK_BYTES = 8 << 20
 # device-plane link accounting: EVERY host->device and device->host byte
 # of the query engine funnels through count_upload/count_download (the
 # residency guard test asserts a rolling refresh uploads only tail
-# columns, and a bench leg divides link traffic by refresh)
+# columns, and the benchmark divides the uploaded bytes by queries)
 _BYTES_UPLOADED = metricslib.REGISTRY.counter(
     "vm_device_bytes_uploaded_total")
 _BYTES_DOWNLOADED = metricslib.REGISTRY.counter(
@@ -50,10 +50,6 @@ def count_download(nbytes: int) -> None:
 
 def bytes_uploaded() -> int:
     return _BYTES_UPLOADED.get()
-
-
-def bytes_downloaded() -> int:
-    return _BYTES_DOWNLOADED.get()
 
 
 def timed_transfer(span: str, nbytes: int, fn):
@@ -278,12 +274,6 @@ class TileCache:
             self._sizes[key] = size
             self._bytes += size
         return dev_tree
-
-    def get_or_put(self, key, make_host_tree):
-        cached = self.get(key)
-        if cached is not None:
-            return cached
-        return self.put(key, make_host_tree())
 
     def invalidate(self, key=None):
         with self._lock:

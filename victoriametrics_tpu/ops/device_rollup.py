@@ -2,17 +2,27 @@
 
 This is the device half of the query engine's north-star hot loop (the
 reference's rollupConfig.doInternal window walk, rollup.go:688-825, and the
-unpack+merge workers around it). Instead of a per-series sliding-window scan,
-everything is expressed as dense, fixed-shape array ops XLA can fuse and tile:
+unpack+merge workers around it). Instead of a per-series sliding-window scan
+with index gathers, every windowed quantity is a masked reduction over the
+sample axis, which XLA fuses into plain VPU work:
 
-- window endpoints: vmapped ``searchsorted`` over padded timestamp rows
-  (the idx-hint binary search of rollup.go:825 becomes one batched gather)
-- sum/count/avg/stddev/stdvar/deriv: cumulative-moment prefix sums, window
-  value = cum[hi] - cum[lo]
-- min/max: sparse-table RMQ (O(N log N) precompute, two gathers per window)
+- ``_masked_window_reduce`` is the core: ONE ``lax.scan`` over chunks of
+  the sample axis; each step compares a ``[S, chunk, 1]`` slice of the
+  timestamps with the ``[T]`` output grid (``ts <= grid[t]``,
+  ``ts <= grid[t] - lookback``), and every requested reduction
+  (sum / max / min of an ``[S, N]`` plane, or a count) is one
+  ``[S, chunk, T]`` compare-select-reduce added into its ``[S, T]`` carry.
+  Cost O(S*N*T); no searchsorted, no prefix-sum or sparse-table tables.
+- ``rollup_tile`` asks that pass for the window's sample-index bounds
+  (``lo``, ``hi``) and the previous sample's timestamp, plus what the
+  function needs (window sums and moments, min / max, first / last as
+  min / max of monotone planes), and finishes on ``[S, T]`` blocks; the
+  few row gathers left (``_gather``) read neighbours of ``lo`` / ``hi``.
 - counter resets: prefix sum of negative jumps (removeCounterResets,
-  rollup.go:921, as an associative scan)
-- rate/delta/increase continuity: "real previous value" = gather at lo-1
+  rollup.go:921) over the whole row, before the pass.
+- ``rollup_aggregate_tile`` / ``fleet_rollup_aggregate_*`` fuse the group
+  reduction (a one-hot matmul for sums, segment min / max) behind it;
+  ``append_tile`` / ``compact_tile`` maintain the resident window.
 
 Inputs are padded ragged tiles:
   ts:     int32 [S, N]  sample timestamps, ms, relative to cfg.start,
@@ -27,7 +37,6 @@ module must agree with bit-for-bit up to float association order.
 from __future__ import annotations
 
 import functools
-from typing import Callable
 
 import jax
 import jax.numpy as jnp
@@ -60,84 +69,14 @@ def _valid_mask(counts: jnp.ndarray, n: int) -> jnp.ndarray:
     return jnp.arange(n, dtype=jnp.int32)[None, :] < counts[:, None]
 
 
-def _cum0(x: jnp.ndarray) -> jnp.ndarray:
-    """Prefix sum with leading zero along axis 1: out[:, i] = sum(x[:, :i])."""
-    return jnp.pad(jnp.cumsum(x, axis=1), ((0, 0), (1, 0)))
-
-
+# sample-axis chunk of _masked_window_reduce's scan
 _BOUNDS_CHUNK = 256
-
-
-def _window_bounds(ts: jnp.ndarray, cfg: RollupConfig) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Returns (lo, hi) int32 [S, T]: half-open sample index range per output
-    step, plus the relative output grid.
-
-    Computed as a chunked compare-and-reduce over the sample axis
-    (hi[s,t] = sum_i [ts[s,i] <= grid[t]]) instead of a vmapped binary
-    search: XLA fuses the [S, chunk, T] comparison into the reduction so
-    it runs at VPU rate, while searchsorted lowers to per-element while
-    loops that serialize on TPU (measured 1.25s -> ~10ms at 8192x1984x355).
-    """
-    T = (cfg.end - cfg.start) // cfg.step + 1
-    # int32 throughout: tile timestamps are rebased so the grid fits, and
-    # this keeps the kernel independent of the jax_enable_x64 flag.
-    grid = (jnp.arange(T, dtype=jnp.int32) * np.int32(cfg.step))
-    lo_t = grid - np.int32(cfg.lookback)
-    S, N = ts.shape
-    ch = min(_BOUNDS_CHUNK, N)
-    n_ch = (N + ch - 1) // ch
-    tp = ts if n_ch * ch == N else jnp.pad(
-        ts, ((0, 0), (0, n_ch * ch - N)), constant_values=TS_PAD)
-    chunks = jnp.moveaxis(tp.reshape(S, n_ch, ch), 1, 0)  # [n_ch, S, ch]
-
-    def body(carry, chunk):
-        lo_a, hi_a = carry
-        c = chunk[:, :, None]
-        hi_a = hi_a + jnp.sum(c <= grid[None, None, :], axis=1,
-                              dtype=jnp.int32)
-        lo_a = lo_a + jnp.sum(c <= lo_t[None, None, :], axis=1,
-                              dtype=jnp.int32)
-        return (lo_a, hi_a), None
-
-    zeros = jnp.zeros((S, T), jnp.int32)
-    (lo, hi), _ = jax.lax.scan(body, (zeros, zeros), chunks)
-    return lo, hi, grid
 
 
 def _gather(x: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
     """Row-wise gather: x [S, N], idx [S, T] -> [S, T], idx clipped."""
     idx = jnp.clip(idx, 0, x.shape[1] - 1)
     return jnp.take_along_axis(x, idx, axis=1)
-
-
-def _rmq_tables(x: jnp.ndarray, op: Callable, pad_val) -> list[jnp.ndarray]:
-    """Sparse-table RMQ precompute: tables[l][s, i] = op over x[s, i:i+2^l]."""
-    n = x.shape[1]
-    levels = max(int(np.ceil(np.log2(max(n, 1)))) + 1, 1)
-    t = x
-    tables = [t]
-    for l in range(1, levels):
-        half = 1 << (l - 1)
-        shifted = jnp.concatenate(
-            [t[:, half:], jnp.full((x.shape[0], half), pad_val, x.dtype)], axis=1)
-        t = op(t, shifted)
-        tables.append(t)
-    return tables
-
-
-def _rmq_query(tables: list[jnp.ndarray], lo: jnp.ndarray, hi: jnp.ndarray,
-               op: Callable) -> jnp.ndarray:
-    """Range op over [lo, hi) via two overlapping power-of-two windows."""
-    length = jnp.maximum(hi - lo, 1)
-    k = jnp.floor(jnp.log2(length.astype(jnp.float32))).astype(jnp.int32)
-    k = jnp.clip(k, 0, len(tables) - 1)
-    stacked = jnp.stack(tables)  # [L, S, N]
-    S, T = lo.shape
-    s_idx = jnp.arange(S, dtype=jnp.int32)[:, None]
-    a = stacked[k, s_idx, jnp.clip(lo, 0, tables[0].shape[1] - 1)]
-    b_pos = jnp.clip(hi - (1 << k), 0, tables[0].shape[1] - 1)
-    b = stacked[k, s_idx, b_pos]
-    return op(a, b)
 
 
 def _remove_counter_resets(v: jnp.ndarray, valid: jnp.ndarray,

@@ -1288,7 +1288,7 @@ class ClusterStorage:
         # per-tenant raw-key -> send-key verdicts (relabel applied once
         # per distinct series key; see add_rows_columnar)
         self._key_verdicts: dict[tuple, dict] = {}
-        from ..query.rollup_result_cache import next_storage_token
+        from ..storage.storage import next_storage_token
         self.cache_token = next_storage_token()
         # per-instance counters (metrics() is per-cluster; tests build
         # several ClusterStorages per process), mirrored into the process

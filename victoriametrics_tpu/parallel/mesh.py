@@ -7,20 +7,12 @@ series axis over a `jax.sharding.Mesh` and let GSPMD partition the
 segment-reduction — the cross-shard merge is the XLA-inserted all-reduce,
 not a hand-written psum loop.
 
-Two parallel axes are first-class:
-
-- AXIS_SERIES ("series"): data-parallel over series. The single-device
-  fused kernel (ops.device_rollup.rollup_aggregate_tile) is jit'd with
-  declarative in/out shardings from the partition-rule table
-  (parallel/partition.py); each device rolls up its series shard and XLA
-  reduces the [G, T] group moments across shards.
-- AXIS_TIME ("time"): sequence-parallel over the *sample* axis (the
-  long-context analog). Each device holds a contiguous time-slice of every
-  series' samples; rollup windows crossing the slice boundary need the tail
-  of the left neighbor, exchanged with `lax.ppermute` (ring halo exchange,
-  like ring attention passes KV blocks). This path keeps an explicit
-  shard_map: the halo exchange is a genuinely manual collective that has
-  no declarative spelling.
+AXIS_SERIES ("series") is data-parallel over series: the single-device
+fused kernel (ops.device_rollup.rollup_aggregate_tile) is jit'd with
+declarative in/out shardings from the partition-rule table
+(parallel/partition.py); each device rolls up its series shard and XLA
+reduces the [G, T] group moments across shards.  AXIS_STREAM shards the
+fleet's stacked windows the same way (make_fleet_mesh).
 """
 
 from __future__ import annotations
@@ -30,26 +22,18 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import shard_map
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh
 
-from ..ops.device_rollup import rollup_tile
 from ..ops.rollup_np import RollupConfig
-from .partition import (AXIS_SERIES, AXIS_STREAM, AXIS_TIME,
-                        input_shardings, replicated, sharding_for)
+from .partition import (AXIS_SERIES, AXIS_STREAM, input_shardings,
+                        replicated, sharding_for)
 
 
-def make_mesh(n_series: int | None = None, n_time: int = 1,
-              devices=None) -> Mesh:
-    """Build a (series, time) mesh over the available devices."""
+def make_mesh(devices=None) -> Mesh:
+    """One-axis mesh sharding the series axis over the given devices
+    (every visible one by default)."""
     devices = devices if devices is not None else jax.devices()
-    n = len(devices)
-    if n_series is None:
-        n_series = n // n_time
-    if n_series * n_time != n:
-        raise ValueError(f"mesh {n_series}x{n_time} != {n} devices")
-    arr = np.asarray(devices).reshape(n_series, n_time)
-    return Mesh(arr, (AXIS_SERIES, AXIS_TIME))
+    return Mesh(np.asarray(devices), (AXIS_SERIES,))
 
 
 def make_fleet_mesh(devices=None) -> Mesh:
@@ -132,85 +116,3 @@ def sharded_rollup_aggregate(mesh: Mesh, rollup_func: str, aggr: str,
                     jnp.int32(min_ts), v0)
 
     return call
-
-
-def time_sharded_rollup(mesh: Mesh, rollup_func: str, cfg: RollupConfig,
-                        halo: int):
-    """Sequence-parallel rollup: the sample axis is sharded over AXIS_TIME.
-
-    Each device holds a contiguous chunk of every series' samples (padded to
-    equal chunk length; chunk boundaries aligned to time so chunk i's samples
-    all precede chunk i+1's). Before rolling up, each device receives the
-    trailing `halo` samples of its left neighbor via lax.ppermute — enough to
-    cover one lookback window plus the real-prev-value gather — then computes
-    only the output steps whose windows it owns.
-
-    Output-step ownership: step j belongs to the device whose time range
-    contains the step's timestamp; here we simply split the T output steps
-    contiguously across AXIS_TIME and all-gather at the end.
-
-    Counter-reset correction stays exact across chunks because the halo
-    overlap lets each device reconstruct resets local to its windows; resets
-    older than one window+halo do not affect windowed rollups (they cancel in
-    the window difference).
-    """
-    if rollup_func in _TIME_SHARD_UNSUPPORTED:
-        raise ValueError(
-            f"{rollup_func} needs whole-series context (first sample) and "
-            "cannot run on the time-sharded path; use series sharding")
-    n_time = mesh.shape[AXIS_TIME]
-    T_total = (cfg.end - cfg.start) // cfg.step + 1
-    if T_total % n_time:
-        raise ValueError(f"T={T_total} not divisible by time axis {n_time}")
-    t_shard = T_total // n_time
-
-    @functools.partial(
-        shard_map, mesh=mesh,
-        in_specs=(P(AXIS_SERIES, AXIS_TIME), P(AXIS_SERIES, AXIS_TIME),
-                  P(AXIS_SERIES, AXIS_TIME)),
-        out_specs=P(AXIS_SERIES, AXIS_TIME))
-    def step(ts, values, valid):
-        # ring halo: receive left neighbor's tail
-        idx = jax.lax.axis_index(AXIS_TIME)
-        perm = [(i, (i + 1) % n_time) for i in range(n_time)]
-        tail_ts = jax.lax.ppermute(ts[:, -halo:], AXIS_TIME, perm)
-        tail_v = jax.lax.ppermute(values[:, -halo:], AXIS_TIME, perm)
-        tail_ok = jax.lax.ppermute(valid[:, -halo:], AXIS_TIME, perm)
-        # device 0 has no left neighbor: its received halo is garbage; mask.
-        tail_ok = jnp.where(idx == 0, False, tail_ok)
-        ts_ext = jnp.concatenate([tail_ts, ts], axis=1)
-        v_ext = jnp.concatenate([tail_v, values], axis=1)
-        ok_ext = jnp.concatenate([tail_ok, valid], axis=1)
-        counts = jnp.sum(ok_ext, axis=1).astype(jnp.int32)
-        # Compact valid samples to the front (stable sort on the invalid
-        # flag keeps time order: halo precedes local by construction).
-        order = jnp.argsort(jnp.where(ok_ext, 0, 1), axis=1, stable=True)
-        ts_c = jnp.take_along_axis(jnp.where(ok_ext, ts_ext, 2**31 - 1), order, axis=1)
-        v_c = jnp.take_along_axis(jnp.where(ok_ext, v_ext, 0.0), order, axis=1)
-        # local output grid slice
-        local_cfg = RollupConfig(
-            start=cfg.start, end=cfg.start + (t_shard - 1) * cfg.step,
-            step=cfg.step, window=cfg.window)
-        shift = idx * t_shard * cfg.step
-        rolled = rollup_tile_shifted(rollup_func, ts_c, v_c, counts,
-                                     local_cfg, shift)
-        return rolled
-
-    return jax.jit(step)
-
-
-# Funcs needing whole-series context that chunked time sharding cannot see.
-_TIME_SHARD_UNSUPPORTED = frozenset({"lifetime"})
-
-# Funcs returning absolute times: rollup_tile adds cfg.start back, so the
-# chunk's grid shift must be re-added on top.
-_TIME_VALUED = frozenset({"tfirst_over_time", "tlast_over_time", "timestamp"})
-
-
-def rollup_tile_shifted(func, ts, values, counts, cfg, shift):
-    """rollup_tile with the output grid shifted by a traced offset (used by
-    time-sharded evaluation where each device owns a grid slice)."""
-    out = rollup_tile(func, ts - shift, values, counts, cfg)
-    if func in _TIME_VALUED:
-        out = out + shift.astype(out.dtype) / 1e3
-    return out

@@ -1,4 +1,4 @@
-"""Declarative partition rules for the (series, time) device mesh.
+"""Declarative partition rules for the device mesh.
 
 The serving engine used to wire hand-rolled ``shard_map`` closures per
 kernel (manual in_specs/out_specs + explicit psum of partial moments) and
@@ -35,7 +35,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 AXIS_SERIES = "series"
-AXIS_TIME = "time"
 AXIS_STREAM = "stream"
 
 # regex -> spec-per-rank: rank 1 leaves drop the trailing None axes.
@@ -80,11 +79,6 @@ def sharding_for(mesh: Mesh, name: str, ndim: int) -> NamedSharding:
 
 def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
-
-
-def row_multiple(mesh: Mesh) -> int:
-    """Series-axis padding multiple for row-sharded tiles."""
-    return int(mesh.shape[AXIS_SERIES])
 
 
 def axis_multiple(mesh: Mesh, axis: str) -> int:

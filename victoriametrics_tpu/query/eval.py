@@ -30,8 +30,8 @@ from .types import EvalConfig, Timeseries, const_series, new_series
 nan = np.nan
 
 # host-rollup share of vm_fetch_phase_seconds_total (storage/storage.py
-# owns the fetch-side phases; bench.py reads the whole family to
-# attribute a refresh between index/collect/decode/assemble/rollup)
+# owns the fetch-side phases; the benchmark reads the whole family as
+# fetch_ms_per_query)
 _ROLLUP_PHASE = metricslib.REGISTRY.float_counter(
     'vm_fetch_phase_seconds_total{phase="rollup"}')
 
@@ -681,7 +681,7 @@ def _eval_multi_value_rollup(ec: EvalConfig, func: str, re_: RollupExpr,
 
     def _series_rows(func, s_ts, s_vals, src_mn, cfg):
         from .format_value import fmt_value as _fmt_value
-        from .vmhistogram import histogram_counts
+        from ..utils.vmhistogram import histogram_counts
         out_ts = cfg.out_timestamps()
         T = out_ts.size
         lo = np.searchsorted(s_ts, out_ts - cfg.lookback, side="right")
@@ -1877,7 +1877,7 @@ def _eval_histogram_aggr(ec, ae, series) -> list[Timeseries]:
     emitted as CUMULATIVE le= buckets with zero-filled gaps — the
     reference converts through vmrangeBucketsToLE (aggr.go:256-285)."""
     from .transform_funcs import _vmrange_to_le
-    from .vmhistogram import vmrange_for
+    from ..utils.vmhistogram import vmrange_for
     groups, names = _group_series(series, ae.grouping, ae.without)
     out = []
     for key, rows in groups.items():

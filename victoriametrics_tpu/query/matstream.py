@@ -667,7 +667,7 @@ class MatStreamRegistry:
                 return
 
     def advance_due(self, now_ms: int | None = None) -> int:
-        """Advance every due stream once (bench/test driver; HTTP
+        """Advance every due stream once (test driver; HTTP
         subscribers normally pump their own streams).  Returns how many
         streams advanced."""
         now = now_ms if now_ms is not None else fasttime.unix_ms()

@@ -42,14 +42,15 @@ bounded single-entry cache instead of thrashing.
 
 from __future__ import annotations
 
-import itertools
 import os
 import threading
 import weakref
 
 import numpy as np
 
+from ..storage import storage as _storage
 from ..storage.metric_name import MetricName
+from ..utils import fasttime
 from ..utils import flightrec as _flightrec
 from ..utils import metrics as metricslib
 from .types import EvalConfig, Timeseries
@@ -105,15 +106,6 @@ def _default_max_bytes() -> int:
     except (ValueError, OSError, AttributeError):
         total = 8 << 30
     return max(total // 8, 64 << 20)
-
-
-_storage_tokens = itertools.count(1)
-
-
-def next_storage_token() -> int:
-    """Unique per-storage-instance token for cache keys: id() could be
-    reused after GC, silently serving another storage's entries."""
-    return next(_storage_tokens)
 
 
 def _copy_name(mn: MetricName) -> MetricName:
@@ -512,7 +504,6 @@ class RollupResultCache:
         if len(set(fresh_raws)) != len(fresh_raws):
             return None  # duplicate identities: rebuild's last-wins rules
         if now_ms is None:
-            from ..utils import fasttime
             now_ms = fasttime.unix_ms()
         cov_end = ec.start + (
             (min(ec.end, now_ms - OFFSET_MS) - ec.start) // step) * step
@@ -666,3 +657,14 @@ class RollupResultCache:
 
 
 GLOBAL = RollupResultCache()
+
+
+def _reset_on_backfill(oldest_ms: int) -> None:
+    """Storage write listener: a batch reaching back past the cache's
+    offset may change points a cached tail already holds, so every entry
+    goes (ResetRollupResultCacheIfNeeded)."""
+    if oldest_ms < fasttime.unix_ms() - OFFSET_MS:
+        GLOBAL.reset()
+
+
+_storage.add_write_listener(_reset_on_backfill)

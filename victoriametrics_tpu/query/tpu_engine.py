@@ -1,5 +1,5 @@
 """TPU query backend: routes supported rollups onto the device kernels
-(the -search.tpuBackend analog; see models/rollup_pipeline.py).
+(the -search.tpuBackend analog).
 
 try_rollup_tpu returns per-series rollup rows for ORACLE funcs, or None to
 fall back to the host path. Series are packed into padded tiles; tiles are
@@ -324,7 +324,7 @@ def auto_mesh():
     if len(devs) < 2:
         return None
     from ..parallel.mesh import make_mesh
-    return make_mesh(n_series=len(devs), n_time=1, devices=devs)
+    return make_mesh(devs)
 
 
 def _fingerprint(series, start_ms: int) -> tuple:

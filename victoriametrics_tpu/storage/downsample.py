@@ -161,7 +161,7 @@ def note_pass(duration_s: float) -> None:
 def read_enabled() -> bool:
     """``VM_DOWNSAMPLE_READ=0`` disables tier SELECTION at query time (the
     raw oracle escape hatch); the background rewrite keeps running.
-    Re-read per call so tests and bench A/B legs can flip it live."""
+    Re-read per call so tests can flip it live."""
     return os.environ.get("VM_DOWNSAMPLE_READ", "1") != "0"
 
 

@@ -12,6 +12,7 @@ ForceMerge — re-shaped for a Python host plane feeding a TPU query engine.
 from __future__ import annotations
 
 import fcntl
+import itertools
 import os
 import shutil
 import threading
@@ -38,7 +39,7 @@ from .tsid import MetricIDGenerator, TSID, generate_tsid
 
 DEFAULT_RETENTION_MS = 31 * 13 * 86_400_000  # ~13 months, like the reference
 
-# per-phase fetch attribution (bench.py and /metrics read these): seconds
+# per-phase fetch attribution (/metrics carries these): seconds
 # spent in each stage of the columnar read path, labeled like the
 # reference's per-stage vmselect metrics.  The fused VM_NATIVE_ASSEMBLE
 # kernel merges collect+decode+clip into one native call per part — its
@@ -75,6 +76,33 @@ _FANOUT_MIN_REGS = 64
 # the dead query's full server-side cost
 _DEADLINE_ABORTS = metricslib.REGISTRY.counter(
     "vm_storage_deadline_aborts_total")
+
+_storage_tokens = itertools.count(1)
+
+
+def next_storage_token() -> int:
+    """Unique per-storage-instance token for cache keys: id() could be
+    reused after GC, silently serving another storage's entries."""
+    return next(_storage_tokens)
+
+
+# Write listeners: each is called with an accepted batch's oldest
+# timestamp (ms) before the rows reach the table, so a cache above the
+# storage can decide for itself what a backfill makes stale (storage
+# knows nothing of caches).  A tuple replaced whole at registration,
+# which happens while a module is imported; ingest threads read one
+# consistent snapshot without a lock.
+_write_listeners: tuple = ()
+
+
+def add_write_listener(fn) -> None:
+    global _write_listeners
+    _write_listeners = _write_listeners + (fn,)
+
+
+def _publish_write(oldest_ms: int) -> None:
+    for fn in _write_listeners:
+        fn(oldest_ms)
 
 
 class _ScanBudget(Budget):
@@ -386,7 +414,6 @@ class Storage:
         # so cluster RPCs can serve them; lib/storage/metricnamestats)
         self._name_usage: dict = {}
         self.metadata: dict[str, dict] = {}
-        from ..query.rollup_result_cache import next_storage_token
         self.cache_token = next_storage_token()
         # series this node must ALWAYS serve regardless of ring
         # ownership (parallel/ringfilter): adopted via part migration or
@@ -472,7 +499,7 @@ class Storage:
     def run_downsample_cycle(self, now_ms: int | None = None) -> int:
         """One background re-rollup pass over every partition x tier
         (the historicalMergeWatcher cadence; also called directly by
-        tests/bench/smoke to force aging).  Flushes first — tier
+        tests and the smoke to force aging).  Flushes first — tier
         coverage must only ever run over DURABLE raw parts."""
         if not self.downsample_tiers:
             return 0
@@ -695,14 +722,11 @@ class Storage:
         n = len(out)
         if n == 0:
             return 0
-        # backfill older than the result-cache offset invalidates cached
-        # rollup tails (ResetRollupResultCacheIfNeeded) — at STORAGE
-        # level so library/embedded writers are covered too; the batch
-        # minimum is computed ONCE and reused for the append log
+        # published at STORAGE level so library/embedded writers reach
+        # the listeners too (a backfill resets the rollup result cache);
+        # the batch minimum is computed ONCE and reused for the append log
         oldest = min(r[1] for r in out)
-        from ..query.rollup_result_cache import GLOBAL, OFFSET_MS
-        if oldest < fasttime.unix_ms() - OFFSET_MS:
-            GLOBAL.reset()
+        _publish_write(oldest)
         self.table.add_rows(out)
         _INGEST_ROWS.inc(n)
         with self._lock:
@@ -871,9 +895,7 @@ class Storage:
         finally:
             sp.lock.release()
         oldest = int(tss.min())
-        from ..query.rollup_result_cache import GLOBAL, OFFSET_MS
-        if oldest < fasttime.unix_ms() - OFFSET_MS:
-            GLOBAL.reset()
+        _publish_write(oldest)
         self.table.add_rows_columnar(sp, ids, tss, vals)
         n = int(ids.size)
         _INGEST_ROWS.inc(n)

@@ -162,8 +162,7 @@ class CostTracker:
             return sum(self.wall_ms.values())
 
     def summary(self) -> dict:
-        """The cost columns surfaced in top_queries/slow_queries and
-        the bench artifact."""
+        """The cost columns surfaced in top_queries/slow_queries."""
         with self._lock:
             out = {"samplesScanned": self.samples,
                    "bytesRead": self.part_bytes,

@@ -836,8 +836,7 @@ RECORDER = FlightRecorder()
 def slow_refresh_threshold_ms() -> float:
     """``VM_SLOW_REFRESH_MS``: refreshes slower than this trigger a
     flight capture on the serving path (0 disables the trigger; the
-    default 1000ms only fires on genuinely pathological refreshes —
-    bench.py lowers it adaptively around its measured baseline)."""
+    default 1000ms only fires on genuinely pathological refreshes)."""
     try:
         return float(os.environ.get("VM_SLOW_REFRESH_MS", "1000"))
     except ValueError:

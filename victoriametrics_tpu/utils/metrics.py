@@ -10,7 +10,7 @@ reference library::
         .update(dt)
 
 Histograms reuse the storage engine's own vmrange bucketing
-(query/vmhistogram.py), so self-metrics use the same exposition the data
+(utils/vmhistogram.py), so self-metrics use the same exposition the data
 plane stores: ``<name>_bucket{...,vmrange="l...u"}``, ``<name>_sum``,
 ``<name>_count``.  ``write_prometheus()`` renders the whole registry as
 parseable Prometheus text (``# TYPE`` lines, escaped label values) plus
@@ -26,8 +26,7 @@ import os
 import re
 import threading
 
-from ..query import vmhistogram
-from . import fasttime
+from . import fasttime, vmhistogram
 
 _NAME_RE = re.compile(
     r'^[a-zA-Z_:][a-zA-Z0-9_:.]*(\{([a-zA-Z_][a-zA-Z0-9_]*="'
@@ -157,7 +156,7 @@ class Gauge:
 
 class Histogram:
     """VictoriaMetrics-native histogram: log-spaced vmrange buckets
-    (18/decade, query/vmhistogram.py) storing only non-empty buckets,
+    (18/decade, utils/vmhistogram.py) storing only non-empty buckets,
     plus _sum and _count series.  NaN and negative values are skipped,
     matching the reference (histogram.go:85)."""
 
@@ -434,8 +433,7 @@ def ingest_phase(phase: str) -> FloatCounter:
     stage of the ingestion pipeline.  Phases: ``resolve`` (raw key ->
     TSID), ``register`` (per-day index registration), ``append``
     (partition pending append), ``flush`` (part encode+fsync), ``merge``
-    (background part merges).  Shared by storage/partition/mergeset and
-    read by bench.py's per-refresh split."""
+    (background part merges).  Shared by storage/partition/mergeset."""
     return REGISTRY.float_counter(
         f'vm_ingest_phase_seconds_total{{phase="{phase}"}}')
 

@@ -4,7 +4,8 @@ vendor/github.com/VictoriaMetrics/metrics/histogram.go:12-30,215-230).
 Log-spaced buckets: 18 per decade over [1e-9, 1e18), multiplier
 10^(1/18); vmrange labels are "%.3e...%.3e" bounds, with "0...1.000e-09"
 and "1.000e+18...+Inf" catch-alls. Shared by the histogram_over_time
-rollup and the histogram() aggregate.
+rollup, the histogram() aggregate and the process's own /metrics
+histograms (utils/metrics.py).
 """
 
 from __future__ import annotations
