@@ -378,3 +378,40 @@ print("ok")
                          capture_output=True, text=True, timeout=120)
     assert out.returncode == 0, out.stderr
     assert out.stdout.strip() == "ok"
+
+
+def test_the_writers_counters_read_0_on_a_server_that_answered_nothing():
+    """(e) the matrix writer's families (points by writer, the parallel
+    points, the metric memo's hits and misses) are on /metrics at 0 from
+    the server's start: the benchmark's ratios read 0, never "absent"."""
+    code = """
+import tempfile, urllib.request
+from victoriametrics_tpu.httpapi.prometheus_api import PrometheusAPI
+from victoriametrics_tpu.httpapi.server import HTTPServer
+from victoriametrics_tpu.storage.storage import Storage
+with tempfile.TemporaryDirectory() as d:
+    s = Storage(d)
+    srv = HTTPServer("127.0.0.1", 0)
+    PrometheusAPI(s).register(srv)
+    srv.start()
+    try:
+        with urllib.request.urlopen(
+                f"http://127.0.0.1:{srv.port}/metrics", timeout=30) as r:
+            text = r.read().decode()
+    finally:
+        srv.stop()
+        s.close()
+lines = dict(l.rsplit(" ", 1) for l in text.splitlines()
+             if l.startswith("vm_http_matrix_"))
+assert lines == {
+    'vm_http_matrix_points_total{writer="native"}': "0",
+    'vm_http_matrix_points_total{writer="python"}': "0",
+    "vm_http_matrix_parallel_points_total": "0",
+    'vm_http_matrix_metric_memo_total{result="hit"}': "0",
+    'vm_http_matrix_metric_memo_total{result="miss"}': "0"}, lines
+print("ok")
+"""
+    out = subprocess.run([sys.executable, "-c", code], cwd=REPO,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == "ok"

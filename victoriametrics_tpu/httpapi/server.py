@@ -47,7 +47,7 @@ class Request:
 class Response:
     def __init__(self, status=200, body=b"", content_type="application/json"):
         self.status = status
-        self.body = body if isinstance(body, bytes) else body.encode()
+        self.body = body.encode() if isinstance(body, str) else body
         self.content_type = content_type
         self.headers: dict[str, str] = {}
 

@@ -9,9 +9,11 @@ cgo-zstd-with-pure-Go-fallback split
 
 from __future__ import annotations
 
+import collections
 import ctypes
 import os
 import subprocess
+import sys
 
 import numpy as np
 
@@ -157,7 +159,10 @@ def _configure(lib):
     lib.vm_keymap_resolve.argtypes = [i64, p8, pi64, pi64, i64, pi64]
     lib.vm_write_matrix.restype = i64
     lib.vm_write_matrix.argtypes = [pf64, i64, pf64, i64, p8, i64, pi64,
-                                    pi64]
+                                    pi64, pi64, pi64]
+    lib.vm_write_matrix_cut.restype = i64
+    lib.vm_write_matrix_cut.argtypes = [pf64, i64, pf64, i64, p8, i64,
+                                        pi64, pi64, pi64, i64]
     pp = ctypes.POINTER(ctypes.c_void_p)
     lib.vm_pending_order.restype = i64
     lib.vm_pending_order.argtypes = [pp, pp, pp, pi64, i64, pi32, i64, i64,
@@ -756,35 +761,91 @@ def marshal_i64_many(vals: np.ndarray, offsets: np.ndarray):
 MATRIX_POINT_MAX = 56
 
 
-def write_matrix(grid_s: np.ndarray, block: np.ndarray):
+class _Spares:
+    """The buffers of the last calls of one kind, for the next to write
+    into: memory the process has not touched before costs 9 us a page on
+    the chip's host (PERF.md section 6, PR 33), 5 ms for a 2.3 MB answer's
+    text and 20 for a 9 MB one, every query that malloc hands out fresh
+    pages.  ``take`` gives a buffer to one caller alone; one that an
+    earlier answer's memoryviews still hold is left to them."""
+
+    def __init__(self, dtype):
+        self._dtype = dtype
+        self._free: collections.deque = collections.deque(maxlen=2)
+
+    def take(self, n: int) -> np.ndarray:
+        try:
+            buf = self._free.pop()
+        except IndexError:
+            return np.empty(n, dtype=self._dtype)
+        # 2: `buf` and getrefcount's own argument, so no view is left on it
+        if buf.size >= n and sys.getrefcount(buf) == 2:
+            return buf
+        return np.empty(n, dtype=self._dtype)
+
+    def give(self, buf: np.ndarray) -> None:
+        self._free.append(buf)
+
+
+_matrix_blocks = _Spares(np.float64)
+_matrix_texts = _Spares(np.uint8)
+
+
+def write_matrix(grid_s: np.ndarray, rows):
     """The ``values`` text of a ``query_range`` answer in one native
-    pass: grid_s = float64 [T] seconds, block = float64 [R, T] (NaN =
-    absent).  Returns (buf, row_ends int64 [R], n_points): row i's text
-    ``[[t, "v"], ...]`` is ``buf[row_ends[i-1]:row_ends[i]]``, empty for
-    a row with no point; byte for byte what ``json.dumps`` makes of
-    ``[[float(t), fmt_value(v)], ...]``.  None when the native library
-    is unavailable."""
+    call: grid_s = float64 [T] seconds, rows = R float64 [T] arrays (or
+    one [R, T] block; NaN = absent).  Returns (buf, row_starts int64 [R],
+    row_ends int64 [R], n_points, n_ranges): row i's text ``[[t, "v"],
+    ...]`` is ``buf[row_starts[i]:row_ends[i]]``, empty for a row with no
+    point; byte for byte what ``json.dumps`` makes of ``[[float(t),
+    fmt_value(v)], ...]``.  The call cuts the rows into n_ranges ranges,
+    written at once by as many threads, by the points it sees and the
+    machine's cores (1: a small answer, written on the calling thread);
+    the text between two ranges' rows is not the answer's.  None when the
+    native library is unavailable."""
+    return _write_matrix(grid_s, rows, None)
+
+
+def write_matrix_cut(grid_s: np.ndarray, rows, ranges: int):
+    """``write_matrix`` with the number of ranges given (held to 1..R)
+    instead of observed: what the tests, and the measurement the width
+    was set from, hold the served call to."""
+    return _write_matrix(grid_s, rows, ranges)
+
+
+def _write_matrix(grid_s, rows, ranges):
     lib = _load()
     if lib is None:
         return None
     grid_s = np.ascontiguousarray(grid_s, dtype=np.float64)
-    block = np.ascontiguousarray(block, dtype=np.float64)
-    if block.ndim != 2 or grid_s.shape != block.shape[1:]:
-        raise ValueError(
-            f"grid {grid_s.shape} does not fit a block {block.shape}")
-    r, t = block.shape
+    r, t = len(rows), grid_s.size
+    flat = _matrix_blocks.take(r * t)
+    block = flat[:r * t].reshape(r, t)
+    if r:
+        np.stack(rows, out=block)  # ValueError unless every row is [T]
     cap = r * (t * MATRIX_POINT_MAX + 2)
-    out = np.empty(cap, dtype=np.uint8)
+    out = _matrix_texts.take(cap)
+    row_starts = np.empty(r, dtype=np.int64)
     row_ends = np.empty(r, dtype=np.int64)
     n_points = ctypes.c_int64()
     pf64 = ctypes.POINTER(ctypes.c_double)
-    n = lib.vm_write_matrix(
-        grid_s.ctypes.data_as(pf64), t, block.ctypes.data_as(pf64), r,
-        out.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)), cap,
-        _as_i64_ptr(row_ends), ctypes.byref(n_points))
+    args = (grid_s.ctypes.data_as(pf64), t, block.ctypes.data_as(pf64), r,
+            out.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)), cap,
+            _as_i64_ptr(row_starts), _as_i64_ptr(row_ends),
+            ctypes.byref(n_points))
+    if ranges is None:
+        n_ranges = ctypes.c_int64()
+        n = lib.vm_write_matrix(*args, ctypes.byref(n_ranges))
+        ranges = n_ranges.value
+    else:
+        ranges = max(1, min(int(ranges), r))
+        n = lib.vm_write_matrix_cut(*args, ranges)
+    _matrix_blocks.give(flat)
     if not 0 <= n <= cap:
         raise ValueError(f"native matrix writer: {n} of {cap} bytes")
-    return memoryview(out)[:n], row_ends, n_points.value
+    buf = memoryview(out)[:n]
+    _matrix_texts.give(out)
+    return buf, row_starts, row_ends, n_points.value, ranges
 
 
 def pending_order(chunks: list, rank: np.ndarray, n_ranks: int,
