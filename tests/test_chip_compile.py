@@ -170,34 +170,45 @@ def _on_mesh(mesh):
     return make
 
 
-def test_four_chip_series_sharded_step(topo, chip_regime):
+# the four-chip cells' windows: the module's dashboard, and
+# dash32k.refresh4's (32,768 rows x 1856 columns, 1024 groups)
+MESH_SHAPES = {"dash8k": (S, N, G), "dash32k-4chip": (32768, 1856, 1024)}
+
+
+def _mesh_tile(at, s, n):
+    return (at("ts", (s, n), np.int32), at("values", (s, n), np.float32),
+            at("counts", (s,), np.int32))
+
+
+@pytest.mark.parametrize("shape", sorted(MESH_SHAPES))
+def test_four_chip_series_sharded_step(topo, chip_regime, shape):
     """The serving engine's mesh step (auto_mesh: 4x1 series mesh): each
     chip rolls up its series shard, the [G, T] group moments cross chips
     in an XLA-inserted all-reduce."""
-    import jax
-
     from victoriametrics_tpu.parallel.mesh import (make_mesh,
                                                    sharded_rollup_aggregate)
+    s, n, g = MESH_SHAPES[shape]
     mesh = make_mesh(topo.devices)
     at = _on_mesh(mesh)
-    fn = sharded_rollup_aggregate(mesh, "rate", "sum", _cfg("rate"), G)
-    c = jax.jit(fn).lower(
-        at("ts", (S, N), np.int32), at("values", (S, N), np.float32),
-        at("counts", (S,), np.int32), at("group_ids", (S,), np.int32),
+    fn = sharded_rollup_aggregate(mesh, "rate", "sum", _cfg("rate"), g)
+    c = fn.lower(
+        *_mesh_tile(at, s, n), at("group_ids", (s,), np.int32),
         at("shift", (), np.int32), at("min_ts", (), np.int32),
-        at("v0", (S,), np.float32)).compile()
-    assert "all-reduce" in c.as_text()
+        at("v0", (s,), np.float32)).compile()
+    assert "all-reduce" in c.as_text() and "all-gather" not in c.as_text()
     # the tile is split, not replicated: each chip holds a quarter
-    assert c.memory_analysis().argument_size_in_bytes < S * N * 8 // 2
+    assert c.memory_analysis().argument_size_in_bytes < s * n * 8 // 2
 
 
 def test_four_chip_decode_and_append(topo, chip_regime):
-    """The rest of the mesh engine's refresh path runs the single-device
-    jits on row-sharded arrays (GSPMD partitions them by the arguments'
-    shardings): the cold decode and the donated append stay row-local."""
+    """The rest of the mesh engine's refresh path stays row-local: the
+    cold decode runs the single-device jit on row-sharded arrays (GSPMD
+    partitions it by the arguments' shardings), the donated append its
+    twin with the staged tail's shardings declared
+    (parallel.mesh.cached_sharded_append_tile)."""
     from victoriametrics_tpu.ops.device_decode import decode_tiles
-    from victoriametrics_tpu.ops.device_rollup import append_tile
-    from victoriametrics_tpu.parallel.mesh import make_mesh
+    from victoriametrics_tpu.parallel.mesh import (
+        cached_sharded_append_tile, make_mesh)
     mesh = make_mesh(topo.devices)
     at = _on_mesh(mesh)
     v = lambda name, dt: at(name, (S,), dt)  # noqa: E731
@@ -209,12 +220,32 @@ def test_four_chip_decode_and_append(topo, chip_regime):
         rebase=True).compile()
     assert "all-reduce" not in c.as_text() and \
         "all-gather" not in c.as_text()
-    c = append_tile.lower(
-        at("ts", (S, N), np.int32), at("values", (S, N), np.float32),
-        v("counts", np.int32), at("ts", (S, 8), np.int32),
+    c = cached_sharded_append_tile(mesh).lower(
+        *_mesh_tile(at, S, N), at("ts", (S, 8), np.int32),
         at("values", (S, 8), np.float32), v("counts", np.int32)).compile()
     assert c.memory_analysis().alias_size_in_bytes >= S * N * 8 // 4
     assert "all-gather" not in c.as_text()
+
+
+@pytest.mark.parametrize("shape", sorted(MESH_SHAPES))
+def test_four_chip_compact_tile_donated(topo, chip_regime, shape):
+    """The slide of a row-sharded resident window (compact_window runs
+    the single-device jit on the sharded tile): each chip compacts its
+    own quarter in place - the donated planes are aliased, no sample
+    plane is gathered, and the tile comes back sharded as it went in."""
+    from victoriametrics_tpu.ops.device_rollup import compact_tile
+    from victoriametrics_tpu.parallel.mesh import make_mesh
+    s, n, _ = MESH_SHAPES[shape]
+    at = _on_mesh(make_mesh(topo.devices))
+    tile = _mesh_tile(at, s, n)
+    i32 = at("shift", (), np.int32)
+    c = compact_tile.lower(*tile, i32, i32).compile()
+    assert c.memory_analysis().alias_size_in_bytes >= s * n * 8 // 4, \
+        "compact_tile no longer aliases the donated, sharded tile"
+    assert "all-gather" not in c.as_text() and \
+        "all-reduce" not in c.as_text()
+    assert [o.spec for o in c.output_shardings] == \
+        [t.sharding.spec for t in tile]
 
 
 def test_four_chip_fleet_step(topo, chip_regime):

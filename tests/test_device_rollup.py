@@ -130,7 +130,7 @@ class TestMesh:
         from victoriametrics_tpu.ops.device_rollup import MIN_TS_NONE
         got = np.asarray(fn(jnp.asarray(ts), jnp.asarray(vals),
                             jnp.asarray(counts), jnp.asarray(gids),
-                            np.int32(0), MIN_TS_NONE))
+                            np.int32(0), MIN_TS_NONE, None))
         rolled = rollup_tile("rate", jnp.asarray(ts), jnp.asarray(vals),
                              jnp.asarray(counts), CFG)
         want = np.asarray(aggregate_groups(aggr, rolled, jnp.asarray(gids), 5))

@@ -75,44 +75,66 @@ def cached_fleet_rollup_aggregate(mesh: Mesh, rollup_func: str,
 def cached_sharded_rollup_aggregate(mesh: Mesh, rollup_func: str, aggr: str,
                                     cfg: RollupConfig, num_groups: int):
     """Memoized sharded_rollup_aggregate: the serving engine calls this per
-    query; without memoization every call would build a fresh closure and
-    miss jax's jit cache."""
+    query; without memoization every call would build a fresh jit and miss
+    its cache."""
     return sharded_rollup_aggregate(mesh, rollup_func, aggr, cfg, num_groups)
 
 
 def sharded_rollup_aggregate(mesh: Mesh, rollup_func: str, aggr: str,
                              cfg: RollupConfig, num_groups: int):
-    """Build a jitted aggr(rollup(...)) running series-sharded on the mesh.
+    """Build the jitted aggr(rollup(...)) that runs series-sharded on the
+    mesh: the returned jit itself (its `_cache_size` is what
+    tpu_engine.timed_kernel_call reads to book a compile), named
+    `sharded_rollup_aggregate` so the device trace tells the mesh step
+    (`jit_sharded_rollup_aggregate`) from the fleet's `jit_step`.
 
-    Declarative GSPMD partitioning: the SAME fused kernel the single-device
-    engine runs (ops.device_rollup.rollup_aggregate_tile) is jit'd with
-    in/out shardings derived from the partition-rule table — the
-    per-shard segment moments and the cross-shard reduction are one XLA
-    program, with the all-reduce inserted by the partitioner instead of a
-    hand-rolled shard_map closure + psum.
+    Declarative GSPMD partitioning: the body of the single-device fused
+    kernel (ops.device_rollup.rollup_aggregate_tile: rollup_tile, then
+    aggregate_groups) with in/out shardings derived from the
+    partition-rule table — the per-shard segment moments and the
+    cross-shard reduction are one XLA program, with the all-reduce
+    inserted by the partitioner instead of a hand-rolled shard_map
+    closure + psum.
 
     Inputs: ts [S, N] int32, values [S, N], counts [S] int32,
     group_ids [S] int32, shift int32 scalar (rolling-tile grid rebase, 0
     for freshly built tiles), min_ts int32 scalar, v0 [S] (per-series
-    rebase offsets of f32 tiles; zeros otherwise); S must be divisible by
-    the series-axis size. Output: [G, T] fully replicated.
+    rebase offsets of f32 tiles) or None; S must be divisible by the
+    series-axis size. Output: [G, T] fully replicated.
     """
-    from ..ops.device_rollup import rollup_aggregate_tile
+    from ..ops.device_rollup import aggregate_groups, rollup_tile
     in_sh = input_shardings(mesh, (("ts", 2), ("values", 2), ("counts", 1),
                                    ("group_ids", 1), ("shift", 0),
                                    ("min_ts", 0), ("v0", 1)))
 
     @functools.partial(jax.jit, in_shardings=in_sh,
                        out_shardings=replicated(mesh))
-    def step(ts, values, counts, group_ids, shift, min_ts, v0):
-        return rollup_aggregate_tile(rollup_func, aggr, ts, values, counts,
-                                     group_ids, cfg, num_groups, shift,
-                                     min_ts, v0)
+    def sharded_rollup_aggregate(ts, values, counts, group_ids, shift,
+                                 min_ts, v0):
+        with jax.named_scope("rollup"):
+            rolled = rollup_tile(rollup_func, ts - jnp.int32(shift), values,
+                                 counts, cfg, min_ts, v0)
+        with jax.named_scope("group_moments"):
+            return aggregate_groups(aggr, rolled, group_ids, num_groups)
 
-    def call(ts, values, counts, group_ids, shift, min_ts, v0=None):
-        if v0 is None:
-            v0 = jnp.zeros(ts.shape[0], values.dtype)
-        return step(ts, values, counts, group_ids, jnp.int32(shift),
-                    jnp.int32(min_ts), v0)
+    return sharded_rollup_aggregate
 
-    return call
+
+@functools.lru_cache(maxsize=8)
+def cached_sharded_append_tile(mesh: Mesh):
+    """ops.device_rollup.append_tile for a row-sharded resident tile: the
+    same body and donation, with the staged tail's shardings DECLARED from
+    the rule table, so a tick's host arrays ride this one call onto the
+    devices that hold their rows (a plain jit would leave the placement of
+    uncommitted arguments to the partitioner)."""
+    from ..ops.device_rollup import _append_tile_body
+    tile = input_shardings(mesh, (("ts", 2), ("values", 2), ("counts", 1)))
+
+    @functools.partial(jax.jit, in_shardings=tile + tile,
+                       out_shardings=tile, donate_argnums=(0, 1, 2))
+    def sharded_append_tile(ts, values, counts, new_ts, new_values,
+                            new_counts):
+        return _append_tile_body(ts, values, counts, new_ts, new_values,
+                                 new_counts)
+
+    return sharded_append_tile

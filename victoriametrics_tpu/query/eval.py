@@ -1086,7 +1086,8 @@ def _try_device_fused_aggr(ec: EvalConfig, ae: AggrFuncExpr
     from .rollup_result_cache import RingBlock
     from .tpu_engine import (FUSED_AGGRS, RollingTile, advance_rolling,
                              aux_get, aux_put, group_slots,
-                             run_fused_on_tiles, run_quantile_on_tiles,
+                             place_series_vector, run_fused_on_tiles,
+                             run_quantile_on_tiles,
                              try_aggr_rollup_tpu, try_quantile_rollup_tpu)
     if func not in rollup_np.CORE_SUPPORTED or \
             (phi is None and ae.name not in FUSED_AGGRS):
@@ -1319,13 +1320,19 @@ def _try_device_fused_aggr(ec: EvalConfig, ae: AggrFuncExpr
                 return _decline()
             qt.donef("device path, %d series -> %d groups", len(series),
                      len(group_keys))
-        import jax.numpy as jnp
+        # kept beside the resident tile, so placed here, once (on a mesh:
+        # padded as the tile's rows are; a quantile's padding rows get
+        # out-of-bounds (group, slot) indices, see run_quantile_on_tiles)
+        gids_dev = place_series_vector(
+            ec.tpu, "group_ids", gids,
+            0 if phi is None else len(group_keys))
         if phi is not None:
-            qx = (jnp.asarray(slots), max_group)
+            qx = (place_series_vector(ec.tpu, "slots", slots, max_group),
+                  max_group)
         if aux_key is not None and tile_key is not None and \
                 not ec._partial[0]:
             aux_put(ec.tpu, aux_key,
-                    (tile_key, cfg, jnp.asarray(gids), list(group_keys),
+                    (tile_key, cfg, gids_dev, list(group_keys),
                      n_fetched, qx))
         if roll_state_key is not None and adj is None and \
                 tile_key is not None and not ec._partial[0] and \
@@ -1351,7 +1358,7 @@ def _try_device_fused_aggr(ec: EvalConfig, ae: AggrFuncExpr
                         n_samples=n_fetched, adopted_key=tile_key)
                     wcache.put(roll_tile_key, rt)
                 wcache.put(roll_state_key,
-                           (rt, jnp.asarray(gids), list(group_keys), qx,
+                           (rt, gids_dev, list(group_keys), qx,
                             RingBlock(out, cfg.start, cfg.end, cfg.step,
                                       cfg.lookback)))
     return _emit(out, group_keys)

@@ -120,13 +120,19 @@ class V0Info:
     rebase guarantees nothing there: every value-dependent func would see
     ulp(|v - v0|)-sized noise, so they all fall back to the f64 host path
     for such tiles (per-series patching is possible future work).
-    Value-free funcs (counts, timestamps) still run."""
+    Value-free funcs (counts, timestamps) still run.
 
-    __slots__ = ("offsets", "wide_range")
+    `dev` is the mesh engine's f32 copy on the devices, placed by the rule
+    table at the first fused query and kept with the tile through every
+    append and slide (they hand this object on), so a warm query sends
+    nothing per-series up."""
+
+    __slots__ = ("offsets", "wide_range", "dev")
 
     def __init__(self, offsets: np.ndarray, wide_range: bool):
         self.offsets = offsets
         self.wide_range = wide_range
+        self.dev = None
 
     def __getitem__(self, i):
         return self.offsets[i]
@@ -175,6 +181,16 @@ _BACKEND_COMPILES = metricslib.REGISTRY.counter(
     "vm_device_backend_compiles_total")
 _COMPILE_CACHE_HITS = metricslib.REGISTRY.counter(
     "vm_device_fleet_compile_cache_hits_total")
+# fused aggr(rollup()) launches by the path that ran them: "mesh" is the
+# series-sharded step (parallel/mesh.py), "single" the one-device kernel.
+# On a host with several chips anything but 100 % mesh means a query fell
+# off the sharded path.
+_FUSED_LAUNCHES = {
+    path: metricslib.REGISTRY.counter(metricslib.format_name(
+        "vm_device_fused_launches_total", {"path": path}))
+    for path in ("mesh", "single")}
+# series-axis size of the newest engine's mesh (0 until an engine is built)
+_SERIES_SHARDS = metricslib.REGISTRY.gauge("vm_device_series_shards")
 
 
 def _register_compile_listeners():
@@ -266,6 +282,7 @@ class TPUEngine:
         flightrec.set_annotator(jax.profiler.TraceAnnotation)
         if self.value_dtype is None:
             self.value_dtype = auto_value_dtype()
+        _SERIES_SHARDS.set(self.series_shards())
 
     def is_f32(self) -> bool:
         return np.dtype(self.value_dtype) == np.float32
@@ -492,8 +509,6 @@ def try_aggr_rollup_tpu(engine: TPUEngine, aggr: str, func: str, series,
     span = cfg.end - cfg.start + cfg.lookback
     if span >= 2**31 - 1:
         return None
-    import jax.numpy as jnp
-
     key = cache_key or _fingerprint(series, cfg.start)
     cache = engine.cache()
     tiles = cache.get(key)
@@ -502,7 +517,8 @@ def try_aggr_rollup_tpu(engine: TPUEngine, aggr: str, func: str, series,
         cache.put_device(key, tiles)
     if _counter_unsafe(engine, func, tiles):
         return None
-    return _dispatch_fused(engine, aggr, func, tiles, jnp.asarray(gids),
+    return _dispatch_fused(engine, aggr, func, tiles,
+                           place_series_vector(engine, "group_ids", gids),
                            num_groups, cfg)
 
 
@@ -543,11 +559,19 @@ def warmup(engine: TPUEngine, funcs=("rate", "increase", "default_rollup"),
 
 def _v0_dev(engine: TPUEngine, v0):
     """Rebase offsets in tile dtype for the kernel's counter-reset
-    threshold (None for f64 engines — no rebase happened)."""
+    threshold (None for f64 engines — no rebase happened).  A mesh engine
+    places them by the rule table once and keeps them on the tile's
+    V0Info."""
     if v0 is None:
         return None
-    import jax.numpy as jnp
-    return jnp.asarray(v0.offsets.astype(np.float32))
+    if engine.series_shards() == 1:
+        import jax.numpy as jnp
+        return jnp.asarray(v0.offsets.astype(np.float32))
+    if v0.dev is None:
+        # once a tile: every later query, append and slide reuses it
+        v0.dev = place_series_vector(engine, "v0",
+                                     v0.offsets.astype(np.float32))
+    return v0.dev
 
 
 def _counter_unsafe(engine: TPUEngine, func: str, tiles) -> bool:
@@ -555,6 +579,21 @@ def _counter_unsafe(engine: TPUEngine, func: str, tiles) -> bool:
     dynamic range exceeds the f32-safe bound (see V0Info.wide_range)."""
     v0 = tiles[3]
     return v0 is not None and v0.wide_range and func not in VALUE_FREE_FUNCS
+
+
+def place_series_vector(engine: TPUEngine, name: str, a: np.ndarray,
+                        fill=0):
+    """A per-series host vector (group ids, quantile slots) as the fused
+    kernels take it, for whoever keeps it beside a resident tile.  On a
+    mesh it is padded to the tile's rows (`fill` in the padding rows) and
+    placed by the rule table HERE, once: every query then hands the placed
+    array to the mesh step as it is, and nothing per-series crosses the
+    boundary again."""
+    if engine.series_shards() > 1:
+        from ..parallel.partition import shard_put
+        return shard_put(engine.mesh, name, a, fill)
+    import jax.numpy as jnp
+    return jnp.asarray(a)
 
 
 def _pad_rows(arr, n_rows: int, fill):
@@ -585,16 +624,15 @@ def _dispatch_fused(engine: TPUEngine, aggr: str, func: str, tiles,
     gids_dev = _pad_rows(gids_dev, ts_t.shape[0], 0)
     cfg = normalized_cfg(func, cfg)
     if engine.series_shards() > 1:
-        import jax.numpy as jnp
         from ..parallel.mesh import cached_sharded_rollup_aggregate
         fn = cached_sharded_rollup_aggregate(engine.mesh, func, aggr, cfg,
                                              num_groups)
-        v0_arr = (np.zeros(int(ts_t.shape[0]), np.float32) if v0 is None
-                  else v0.offsets.astype(np.float32))
+        _FUSED_LAUNCHES["mesh"].inc()
         out = timed_kernel_call("sharded_rollup_aggregate", fn, ts_t, v_t,
                                 counts, gids_dev, np.int32(shift),
-                                np.int32(min_ts), v0_arr)
+                                np.int32(min_ts), _v0_dev(engine, v0))
     else:
+        _FUSED_LAUNCHES["single"].inc()
         out = timed_kernel_call("rollup_aggregate_tile",
                                 rollup_aggregate_tile, func, aggr, ts_t,
                                 v_t, counts, gids_dev, cfg, num_groups,
@@ -975,23 +1013,21 @@ def _append_cols(engine: TPUEngine, rt: RollingTile, cols,
         rt.adopted_key = None
     ts_t, v_t, counts_t = ts_t0, v_t0, counts_t0
     if engine.series_shards() > 1:
-        # the tile rows are already padded to the mesh multiple, so these
-        # shard_puts never re-pad — they just place per the rule table
-        from ..parallel.partition import shard_put
-        rt.tiles = append_tile(
-            ts_t, v_t, counts_t, shard_put(engine.mesh, "ts", new_ts),
-            shard_put(engine.mesh, "values", new_vals),
-            shard_put(engine.mesh, "counts", new_counts)) + (v0,)
+        # the same body with the tail's shardings declared from the rule
+        # table: each device is sent the staged rows it holds, no more
+        from ..parallel.mesh import cached_sharded_append_tile
+        append = cached_sharded_append_tile(engine.mesh)
     else:
-        # one device: the staged NumPy tail rides the jitted call's
-        # arguments, so that call IS the put (async: it returns once the
-        # transfer and the kernel are issued, it does not wait for them)
-        from ..models.tile_cache import timed_transfer
-        rt.tiles = timed_transfer(
-            "device:upload",
-            new_ts.nbytes + new_vals.nbytes + new_counts.nbytes,
-            lambda: append_tile(ts_t, v_t, counts_t, new_ts, new_vals,
-                                new_counts)) + (v0,)
+        append = append_tile
+    # the staged NumPy tail rides the jitted call's arguments, so that call
+    # IS the put (async: it returns once the transfer and the kernel are
+    # issued, it does not wait for them)
+    from ..models.tile_cache import timed_transfer
+    rt.tiles = timed_transfer(
+        "device:upload",
+        new_ts.nbytes + new_vals.nbytes + new_counts.nbytes,
+        lambda: append(ts_t, v_t, counts_t, new_ts, new_vals,
+                       new_counts)) + (v0,)
     rt.counts_host[rows_idx] = new_n
     rt.n_samples += cols.n_samples
     rt.appends += 1
@@ -1080,8 +1116,6 @@ def try_quantile_rollup_tpu(engine: TPUEngine, phi: float, func: str,
         return None
     if not quantile_dense_fits(engine, num_groups, max_group, cfg):
         return None  # skewed grouping: dense tensor too big, host wins
-    import jax.numpy as jnp
-
     key = cache_key or _fingerprint(series, cfg.start)
     cache = engine.cache()
     tiles = cache.get(key)
@@ -1090,9 +1124,11 @@ def try_quantile_rollup_tpu(engine: TPUEngine, phi: float, func: str,
         cache.put_device(key, tiles)
     if _counter_unsafe(engine, func, tiles):
         return None
-    return run_quantile_on_tiles(engine, phi, func, tiles,
-                                 jnp.asarray(gids), jnp.asarray(slots),
-                                 num_groups, max_group, cfg)
+    return run_quantile_on_tiles(
+        engine, phi, func, tiles,
+        place_series_vector(engine, "group_ids", gids, num_groups),
+        place_series_vector(engine, "slots", slots, max_group),
+        num_groups, max_group, cfg)
 
 
 def run_quantile_on_tiles(engine: TPUEngine, phi: float, func: str, tiles,
