@@ -9,13 +9,15 @@ Storage.add_rows_columnar for the bulk load (set-up, not the timed path).
 
 from __future__ import annotations
 
+import calendar
+import http.client
 import importlib.util
 import json
 import os
+import socket
 import sys
-import urllib.error
+import time
 import urllib.parse
-import urllib.request
 
 import numpy as np
 
@@ -47,6 +49,30 @@ def load_module(kind: str, name: str):
     return mod
 
 
+def quick_ack(sock) -> None:
+    """Acknowledge what comes next at once (Linux's TCP_QUICKACK; armed
+    anew for every answer, the kernel drops it again).  The program's
+    server writes an answer's headers and its body in two writes and
+    leaves Nagle's algorithm on, so on a KEPT connection a body under one
+    segment (64 KB on loopback: histo8k.refresh's one row) waits for the
+    client's delayed acknowledgement of the headers, 40 ms by the
+    kernel's timer.  A connection a call never met it: a new connection
+    acknowledges at once.  The stall is the pair of kernels' doing, not
+    the query's, and a latency that holds 40 ms of it reads nothing of
+    the program (PERF.md, Open questions: the server should set
+    TCP_NODELAY, as the reference's does)."""
+    if sock is not None and hasattr(socket, "TCP_QUICKACK"):
+        sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_QUICKACK, 1)
+
+
+class HTTPStatus(Exception):
+    """The server answered with a status of 400 or above."""
+
+    def __init__(self, status: int, body: bytes):
+        super().__init__(f"HTTP {status}: {body[:200]!r}")
+        self.status = status
+
+
 class Server:
     """vmsingle in this process: what apps/vmsingle.main() builds with
     -search.tpuBackend, served from a thread on a loopback port."""
@@ -63,24 +89,59 @@ class Server:
         if self.api.tpu is None:
             raise RuntimeError("device engine not attached")
         self.srv.start()
-        self.url = f"http://127.0.0.1:{self.srv.port}"
+        self.connect("127.0.0.1", self.srv.port)
+
+    def connect(self, host: str, port: int) -> None:
+        """The client's side: one connection, opened at the first call
+        and kept, as a dashboard keeps its own."""
+        self.srv_addr = (host, port)
+        self.conn = None
+        self.connects = 0
 
     def stop(self):
+        self.hang_up()
         self.srv.stop()
         self.storage.close()
 
+    def call(self, method: str, path: str, body=None) -> bytes:
+        """One request over the one kept connection (HTTP/1.1,
+        keep-alive), the body read whole; reopened once where the server
+        had closed it."""
+        for again in (True, False):
+            if self.conn is None:
+                host, port = self.srv_addr
+                self.conn = http.client.HTTPConnection(host, port,
+                                                       timeout=600)
+                self.connects += 1
+            try:
+                self.conn.request(method, path, body=body)
+                quick_ack(self.conn.sock)
+                resp = self.conn.getresponse()
+                data = resp.read()
+                break
+            except (http.client.RemoteDisconnected, BrokenPipeError,
+                    ConnectionResetError):
+                self.hang_up()
+                if not again:
+                    raise
+        if resp.will_close:     # a streamed answer's Connection: close
+            self.hang_up()
+        if resp.status >= 400:
+            raise HTTPStatus(resp.status, data)
+        return data
+
+    def hang_up(self) -> None:
+        if self.conn is not None:
+            self.conn.close()
+            self.conn = None
+
     def get(self, path: str, **params) -> bytes:
-        url = self.url + path
         if params:
-            url += "?" + urllib.parse.urlencode(params)
-        with urllib.request.urlopen(url, timeout=600) as r:
-            return r.read()
+            path += "?" + urllib.parse.urlencode(params)
+        return self.call("GET", path)
 
     def post(self, path: str, body: bytes) -> None:
-        req = urllib.request.Request(self.url + path, data=body,
-                                     method="POST")
-        with urllib.request.urlopen(req, timeout=600) as r:
-            r.read()
+        self.call("POST", path, body)
 
     def metrics(self) -> dict:
         """/metrics as {series: value}."""
@@ -100,43 +161,91 @@ class Server:
             params["nocache"] = "1"
         try:
             return self.get("/api/v1/query_range", **params)
-        except urllib.error.HTTPError:
+        except HTTPStatus:
             return b""
 
 
-# The bulk ends this long before the wall clock.  A refresh mix moves
-# simulated time one query step a tick, some hundred times faster than
-# the wall clock: anchored here, every tick of a window stays in the
-# past (a 50 s window at 5 ticks a second and the warm-up's pre-roll move
-# it 6 h), as a replay of half a day ago, and nothing is future-dated.
-BULK_AGE_MS = 12 * 3_600_000
+# A refresh mix moves simulated time one query step a tick, some hundred
+# times faster than the wall clock, so the bulk ends far enough behind
+# the wall clock that every tick a run can make stays in the past: a
+# replay of two days ago, nothing future-dated.  The reckoning, in query
+# steps: a window is run_seconds = 50 s (BENCHMARK.json; 51 is the most
+# the contract allows) and the fastest tick allowed for is 20 ms (the
+# fastest cell's tick is 66-74 ms with the client's text off its clock,
+# PR 35: a quarter of it), so WINDOW_TICKS = 50 s / 20 ms = 2500; the
+# warm-up's pre-roll (110 steps) and its WARM_TICKS (3) go before the
+# window, with room for a longer pre-roll: SETUP_STEPS = 370.  At a 60 s
+# step, with WALL_MARGIN_MS: (2500 + 370) x 60 s + 10 min = 48 h.
+WINDOW_TICKS = 2500
+SETUP_STEPS = 370
 # ... and a window that would come within this of the wall clock fails
 # the run, loudly, before it measures a server answering for the future
 WALL_MARGIN_MS = 10 * 60_000
 
 
+def month_start_ms(ms: int) -> int:
+    """The first millisecond of the UTC month that holds `ms`."""
+    t = time.gmtime(ms // 1000)
+    return calendar.timegm((t.tm_year, t.tm_mon, 1, 0, 0, 0)) * 1000
+
+
+def anchor(now_ms: int, step: int, reach_ms: int) -> tuple:
+    """-> (newest, latest): where the bulk's newest sample is aimed and
+    the ceiling no window may pass.  `newest` lies (WINDOW_TICKS +
+    SETUP_STEPS) steps under `latest`, and `latest` WALL_MARGIN_MS behind
+    the wall clock; `reach_ms` is how far under `newest` the bulk's first
+    sample can lie.  Everything a run can touch lies in ONE calendar
+    month (UTC), the storage's partition: where that span would hold a
+    month's end it moves back as a whole, `latest` to the month's end less
+    a step, so no run loads two partitions for one and no tick opens a
+    new one inside a window."""
+    room = (WINDOW_TICKS + SETUP_STEPS) * step
+    latest = now_ms - WALL_MARGIN_MS
+    first_of_month = month_start_ms(latest)
+    if latest - room - reach_ms < first_of_month:
+        latest = first_of_month - step
+        if latest - room - reach_ms < month_start_ms(latest):
+            raise ValueError("the run's span does not fit a calendar month")
+    return latest - room, latest
+
+
+def exposition(keys: list, ts2: np.ndarray, vals2: np.ndarray) -> bytes:
+    """Prometheus text exposition with timestamps, one line a sample."""
+    rows = []
+    for key, vs, tss in zip(keys, vals2.astype(np.int64).tolist(),
+                            ts2.tolist()):
+        k = key.decode()
+        rows.extend(f"{k} {v} {t}" for v, t in zip(vs, tss))
+    return ("\n".join(rows) + "\n").encode()
+
+
 class Dataset:
-    """The deployment's samples, anchored BULK_AGE_MS behind the wall
-    clock (a literal timestamp would sooner or later fall out of
+    """The deployment's samples, anchored behind the wall clock by
+    `anchor` (a literal timestamp would sooner or later fall out of
     retention), and the grid the queries walk.  Keeps every sample it
     handed out: the reference reads them, never the program's storage."""
 
     def __init__(self, cfg: dict, seed: int, now_ms: int):
-        self.latest = now_ms - WALL_MARGIN_MS
-        now_ms -= BULK_AGE_MS
         self.cfg = cfg
         self.step = int(cfg["query_step_s"] * 1000)
         self.window = int(cfg["window_s"] * 1000)
         self.scrape = int(cfg["scrape_interval_s"] * 1000)
         n_samples = int(cfg["range_h"] * 3_600_000) // self.scrape
         jitter = int(cfg["jitter_s"] * 1000)
+        if jitter >= self.step:
+            raise ValueError("jitter_s has to lie under query_step_s")
+        span = (n_samples - 1) * self.scrape
+        # the first sample lies up to a step (the grid's rounding) and the
+        # jitter under newest - span; the newest tail's up to the jitter
+        # over `latest`, which `anchor` keeps a step inside the month
+        now_ms, self.latest = anchor(now_ms, self.step,
+                                     span + self.step + jitter)
         self.rng = np.random.default_rng(seed)
         self.gen = load_module("deployments", cfg["deployment"]).Deployment(cfg)
         self.labels = self.gen.labels()
         self.keys = [(l["__name__"] + "{" + ",".join(
             f'{k}="{v}"' for k, v in sorted(l.items()) if k != "__name__")
             + "}").encode() for l in self.labels]
-        span = (n_samples - 1) * self.scrape
         self.t_start = (now_ms - span) // self.step * self.step
         # the first window ends BEYOND every bulk sample, jitter included,
         # so fresh tails never interleave with the bulk
@@ -150,26 +259,46 @@ class Dataset:
     def start(self) -> int:
         return self.end - self.duration
 
+    def room(self) -> int:
+        """The ticks of one query step left under the ceiling."""
+        return (self.latest - self.end) // self.step
+
+    def _move(self, span: int) -> None:
+        if self.end + span > self.latest:
+            raise RuntimeError(
+                "the window's simulated time has caught up with the "
+                "ceiling: raise harness.WINDOW_TICKS")
+        self.end += span
+
     def advance(self, steps: int = 1):
         """Move the window `steps` query steps on and make their scrapes."""
+        if self.rng is None:
+            raise RuntimeError("the stream of tails was handed to a "
+                               "producer: take its ticks")
         span = steps * self.step
-        if self.end + span > self.latest:
-            raise RuntimeError("the window's simulated time has caught up "
-                               "with the wall clock: raise BULK_AGE_MS")
-        self.end += span
+        self._move(span)
         tail = self.gen.scrapes(self.rng, self.end - span,
                                 span // self.scrape)
         self.tails.append(tail)
         return tail
 
-    def text(self, ts2: np.ndarray, vals2: np.ndarray) -> bytes:
-        """Prometheus text exposition with timestamps, one line a sample."""
-        rows = []
-        for key, vs, tss in zip(self.keys, vals2.astype(np.int64).tolist(),
-                                ts2.tolist()):
-            k = key.decode()
-            rows.extend(f"{k} {v} {t}" for v, t in zip(vs, tss))
-        return ("\n".join(rows) + "\n").encode()
+    def hand_over(self) -> dict:
+        """Everything the stream of tails depends on from here on, for
+        the one producer that goes on drawing it (traffic/producer.py);
+        this object then draws no more and takes the producer's ticks."""
+        state = dict(cfg=self.cfg, keys=self.keys, end=self.end,
+                     step=self.step, scrape=self.scrape,
+                     rng=self.rng.bit_generator.state,
+                     gen={k: v for k, v in vars(self.gen).items()
+                          if k != "cfg"})
+        self.rng = None
+        return state
+
+    def take(self, tail) -> None:
+        """Move the window one query step on, onto the scrapes `tail`
+        that the producer made for it."""
+        self._move(self.step)
+        self.tails.append(tail)
 
     def snapshot(self, n_tails: int):
         """Every sample handed out up to the n_tails-th tail."""

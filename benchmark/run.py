@@ -84,10 +84,33 @@ def set_up(cfg: dict, mix: dict, seed: int, data_dir: str, annotate=None):
     t = time.perf_counter()
     ticker = harness.load_module("traffic", mix["generator"]).Generator(
         server, data, cfg, mix, seed, annotate)
-    warmed = ticker.warm_up()
+    try:
+        warmed = ticker.warm_up()
+    except BaseException:
+        ticker.close()
+        raise
     split["warm_up"] = time.perf_counter() - t
     log(f"warm-up: {warmed} queries")
     return server, data, ticker, split
+
+
+SLIDES = "vm_device_window_compactions_total"
+
+
+def window_line(win: dict, ticks: int, room: int, m0: dict, m1: dict) -> str:
+    """What a reader of a run's log needs of its window beside the
+    metrics: how near the ticks came to the anchor's ceiling, whether the
+    client ever waited for its producer, the slowest queries by index
+    (the resident window's slide among them, or not), the slides."""
+    lat = win["latencies"]
+    slowest = sorted(range(len(lat)), key=lambda i: -lat[i])[:3]
+    slides = f"{m1[SLIDES] - m0.get(SLIDES, 0):.0f}" if SLIDES in m1 \
+        else "not exported"
+    return (f"{ticks} ticks of ingest of the {room} the anchor allowed; "
+            f"producer_wait_s {win['producer_wait_s']:.4f}; slowest "
+            + ", ".join(f"{1e3 * lat[i]:.1f} ms at query {i}"
+                        for i in slowest)
+            + f"; slides of the resident window {slides}")
 
 
 def layer_metrics(bench: dict, cell: str, ctx: dict) -> dict:
@@ -129,7 +152,7 @@ def measure(bench: dict, cell: dict, cfg: dict, mix: dict, seed: int,
     annotate = jax.profiler.TraceAnnotation if trace_on else None
     tmp = tempfile.mkdtemp(prefix="bench-")
     try:
-        server = None
+        server = ticker = None
         try:
             server, data, ticker, split = set_up(
                 cfg, mix, seed, os.path.join(tmp, "data"), annotate)
@@ -144,6 +167,7 @@ def measure(bench: dict, cell: dict, cfg: dict, mix: dict, seed: int,
                 f"load {split['load']:.2f} + warm-up {split['warm_up']:.2f} "
                 "+ imports and device init")
 
+            room = data.room()
             win = ticker.window(seconds)
 
             m1 = server.metrics()
@@ -156,15 +180,20 @@ def measure(bench: dict, cell: dict, cfg: dict, mix: dict, seed: int,
                 for d in devs)
         finally:
             # the program's state is freed before the reference runs
+            if ticker is not None:
+                ticker.close()
             if server is not None:
                 server.stop()
 
         n = len(win["latencies"])
         log(f"window: {win['window_s']:.2f} s, {n} queries (n of both "
-            f"percentiles), slowest {1e3 * max(win['latencies']):.1f} ms at "
-            f"query {win['latencies'].index(max(win['latencies']))}, "
-            f"{win['failed']} failed, {len(data.tails)} ticks of ingest "
-            "since the load")
+            f"percentiles), {win['failed']} failed; "
+            + window_line(win, room - data.room(), room, m0, m1))
+        if win["producer_wait_s"] > 0.01 * win["window_s"]:
+            print(f"run.py: THE CLIENT WAITED {win['producer_wait_s']:.2f} s "
+                  f"of a window of {win['window_s']:.2f} s FOR TICKS ITS "
+                  "PRODUCER HAD NOT READY: queries_per_s reads the "
+                  "harness, not the server", file=sys.stderr)
         by_template = ticker.by_template(win)
         for tmpl, lats in by_template.items():
             log(f"  {len(lats)} x {tmpl}: p50 "

@@ -68,3 +68,47 @@ def test_a_misspelt_series_reads_nothing():
     reader = harness.load_module("readers", "counter_ratio")
     ctx = {"m0": {"a_total": 1.0}, "m1": {"a_total": 2.0}, "queries": 1}
     assert reader.read({"num": ["a_totl"], "den": "queries"}, ctx) is None
+
+
+BENCHMARK = harness.load_json(os.path.dirname(BENCH), "BENCHMARK.json")
+CELLS = [w["name"] for w in BENCHMARK["workloads"]]
+
+
+def _reported(metrics: list, cell: str) -> list:
+    return [m for m in metrics if cell in m.get("workloads", CELLS)]
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_a_cell_reports_what_its_layer_metrics_move(cell):
+    """setup_s and one more end to end, a per-layer metric, and no
+    per-layer metric that moves an end-to-end metric the cell lacks."""
+    end = {m["name"] for m in _reported(BENCHMARK["end_to_end"], cell)}
+    assert "setup_s" in end and len(end) >= 2
+    layers = _reported(BENCHMARK["per_layer"], cell)
+    assert layers
+    for m in layers:
+        assert m["moves"] in end, (m["name"], m["moves"])
+
+
+def test_the_tail_is_end_to_end_only_where_it_is_steady():
+    """query_p90_ms is the tail of some 160 queries in the 32k cells, of
+    which the ticks that meet background work are a tenth to a sixth: the
+    90th percentile lies on the edge of the slow mode and swings with it
+    (PERF.md section 2).  There the same number is read per layer."""
+    (p90,) = [m for m in BENCHMARK["end_to_end"]
+              if m["name"] == "query_p90_ms"]
+    (tail,) = [m for m in BENCHMARK["per_layer"]
+               if m["name"] == "query_tail_p90_ms"]
+    big = [w["name"] for w in BENCHMARK["workloads"]
+           if w["config"].startswith("dash32k")]
+    assert sorted(tail["workloads"]) == sorted(big)
+    assert sorted(p90["workloads"]) == sorted(set(CELLS) - set(big))
+    assert tail["source"] == "host_clock" and tail["unit"] == p90["unit"]
+    # the same arithmetic over ALL the window's latencies
+    import stats
+    lats = {"a": [0.1 * i for i in range(1, 8)], "b": [0.05, 2.0]}
+    spec = harness.load_json(BENCH, "layers", "query_tail_p90_ms.json")
+    got = harness.load_module("readers", spec["reader"]).read(
+        spec["args"], {"by_template": lats})
+    assert got == pytest.approx(
+        1e3 * stats.percentile([x for ls in lats.values() for x in ls], 90))

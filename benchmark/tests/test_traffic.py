@@ -44,3 +44,18 @@ def test_client_percentile_reads_one_shape_or_nothing():
     assert reader.read({"pattern": r"^topk\(", "percentile": 50}, ctx) == 50.0
     assert reader.read({"pattern": "^max_over_time", "percentile": 50},
                        ctx) is None
+
+
+def test_the_window_line_names_three_slowest_and_the_slides():
+    import run
+    win = dict(latencies=[0.03, 0.4, 0.02, 2.5, 0.05, 0.6],
+               producer_wait_s=0.0123)
+    m0 = {run.SLIDES: 1.0}
+    line = run.window_line(win, 6, 2757, m0, {run.SLIDES: 3.0})
+    assert "6 ticks of ingest of the 2757 the anchor allowed" in line
+    assert "producer_wait_s 0.0123" in line
+    assert "2500.0 ms at query 3, 600.0 ms at query 5, 400.0 ms at query 1" \
+        in line
+    assert line.endswith("slides of the resident window 2")
+    # a program that does not export the counter says so, not 0
+    assert run.window_line(win, 6, 2757, {}, {}).endswith("not exported")

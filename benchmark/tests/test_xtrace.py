@@ -85,12 +85,20 @@ def test_top_ops_and_idle_gaps(trace):
     top = xtrace.top_ops(trace, 3)
     assert [n for n, _ in top] == ["while.1", "while.23", "fusion.7"]
     assert top[0][1] == pytest.approx(0.004728944)
-    gaps = xtrace.idle_gaps(trace)
-    assert gaps[0] == ["bench:query_range", pytest.approx(0.814770949)]
-    assert gaps[1] == ["unmarked", pytest.approx(0.314477469)]
+    gaps = dict(xtrace.idle_gaps(trace))
+    # a gap goes to the INNERMOST mark covering it: the server's phases
+    # inside the client's call; what stays with bench:query_range is the
+    # client's own (0.8148 s before the vm: marks were read)
+    assert gaps["vm:serve:rows"] == pytest.approx(0.3)
+    assert gaps["vm:fetch:wait"] == pytest.approx(0.038)
+    assert gaps["vm:serve:send"] == pytest.approx(0.004)
+    assert gaps["bench:query_range"] == pytest.approx(0.028924822)
+    assert gaps["unmarked"] == pytest.approx(0.314477469)
+    assert sum(v for k, v in gaps.items() if k != "unmarked") == \
+        pytest.approx(0.814770949)
     # the shares add up to the device's idle time between its ops
     busy = xtrace.union(xtrace.device_lines(trace, xtrace.OPS_LINE)[0])
-    assert sum(s for _, s in gaps) == pytest.approx(
+    assert sum(gaps.values()) == pytest.approx(
         (busy[-1][0] - busy[0][1] - sum(e - s for s, e in busy[1:-1])) / 1e9)
     assert xtrace.op_name("%fusion.2 = s32[8]{0} fusion(...)") == "fusion.2"
 
@@ -107,3 +115,44 @@ def test_counter_ratio():
     assert read({"num": ["hits"]}, ctx) == 4.0
     assert read({"num": ["absent_total"], "den": "queries"}, ctx) is None
     assert read({"num": ["hits"], "den": ["absent"]}, ctx) is None
+
+
+def host_marks(trace):
+    return [e for p in trace["planes"] if not p["name"].startswith("/device")
+            for line in p["lines"] for e in line["events"]]
+
+
+def test_idle_gaps_equal_a_brute_force_over_every_edge(trace):
+    """Between two neighbouring edges (of a mark or of a gap) nothing
+    changes: charge each such stretch to the covering mark that started
+    last, and the sums are idle_gaps'."""
+    marks = host_marks(trace)
+    assert {m[0].split(":")[0] for m in marks} == {"bench", "vm"}
+    busy = xtrace.union(xtrace.device_lines(trace, xtrace.OPS_LINE)[0])
+    gaps = [(a[1], b[0]) for a, b in zip(busy, busy[1:])]
+    edges = sorted({t for m in marks for t in (m[1], m[1] + m[2])} |
+                   {t for g in gaps for t in g})
+    want = {}
+    for t0, t1 in zip(edges, edges[1:]):
+        if not any(g0 <= t0 and t1 <= g1 for g0, g1 in gaps):
+            continue
+        cover = [m for m in marks if m[1] <= t0 and t1 <= m[1] + m[2]]
+        name = max(cover, key=lambda m: (m[1], -m[2]))[0] if cover \
+            else "unmarked"
+        want[name] = want.get(name, 0) + (t1 - t0) / 1e9
+    got = dict(xtrace.idle_gaps(trace, 99))
+    assert got.keys() == want.keys()
+    for name in want:
+        assert got[name] == pytest.approx(want[name], abs=1e-12)
+
+
+def test_innermost_is_the_mark_that_started_last():
+    spans = xtrace.innermost([["a", 0, 100], ["b", 10, 30], ["c", 20, 10],
+                              ["d", 60, 50], ["e", 200, 5]])
+    assert spans == [[0, 10, "a"], [10, 20, "b"], [20, 30, "c"],
+                     [30, 40, "b"], [40, 60, "a"], [60, 110, "d"],
+                     [200, 205, "e"]]
+    # two that start together: the shorter is inside the longer
+    assert xtrace.innermost([["out", 0, 10], ["in", 0, 4]]) == \
+        [[0, 4, "in"], [4, 10, "out"]]
+    assert xtrace.innermost([]) == []

@@ -11,7 +11,12 @@ parameters; this one reads
                  size of that name ("jobs")
   nocache        send nocache=1 (the reference's -search.disableCache)
   ingest         true: each tick first posts one query step of fresh
-                 scrapes of every series and moves the window a step on
+                 scrapes of every series and moves the window a step on.
+                 The scrapes and their text are made off the window's
+                 clock, by one helper process a few ticks ahead
+                 (traffic/producer.py): a tick TAKES its scrapes (a pipe
+                 read), appends them to the data set the reference reads,
+                 and posts the text.  A mix without ingest starts no helper
   preroll_steps  with ingest: the panel has been open this many steps
                  when the window opens.  Warm-up loads their scrapes in
                  one bulk and asks once, which slides the resident window
@@ -21,7 +26,11 @@ parameters; this one reads
                  kept for the comparison; the last one is always kept
 
 A tick is [ingest] + one query_range.  The latency is the query_range
-call alone; the window is all of the ticks.  Queries come in balanced
+call alone; the window is all of the ticks.  The time the client waited
+for a tick the helper had not ready is summed (`producer_wait_s`): the
+helper makes a tick in a fraction of the time the server takes to
+ingest and answer one, so it should be nil, and run.py says so loudly
+where it passes 1 % of the window.  Queries come in balanced
 rounds: a round asks one query of every template, in an order drawn from
 the seed, and each template's queries are walked in an order drawn from
 the seed too - every seed asks the same set, and any stretch of the
@@ -29,7 +38,8 @@ window holds the templates in equal shares.
 
 The interface run.py asks of a generator: Generator(server, data, cfg,
 mix, seed, annotate) with warm_up() -> queries asked, window(seconds) ->
-{latencies, asked, failed, kept, window_s} and by_template(win).
+{latencies, asked, failed, kept, window_s, producer_wait_s},
+by_template(win) and close(), which stops what the generator started.
 """
 
 from __future__ import annotations
@@ -73,6 +83,7 @@ class Generator:
         self.round = []
         self.walks = [[] for _ in self.queries]
         self.annotate = annotate or (lambda name: contextlib.nullcontext())
+        self.producer = None
 
     def _next_query(self):
         """-> (template index, query text)."""
@@ -96,24 +107,38 @@ class Generator:
 
     def ingest(self) -> None:
         with self.annotate("bench:build_scrapes"):
-            body = self.data.text(*self.data.advance())
+            ts, vals, body = self.producer.take()
+            self.data.take((ts, vals))
         with self.annotate("bench:import"):
             self.server.post(IMPORT, body)
+
+    def close(self) -> None:
+        if self.producer is not None:
+            self.producer.close()
+            self.producer = None
 
     def warm_up(self) -> int:
         """Every distinct query of the mix once (a compiled shape can hang
         on the data: a topk gathers as many rows as it chose); where the
         mix ingests, the pre-roll and WARM_TICKS full ticks."""
         n = 0
+        if self.mix["ingest"]:
+            # the helper imports while the first answers are asked
+            self.producer = harness.load_module("traffic",
+                                                "producer").Producer()
         for _, texts in self.queries:
             for q in texts:
                 self.ask(q)
                 n += 1
         if not self.mix["ingest"]:
             return n
-        if self.mix.get("preroll_steps"):
-            harness.load_columnar(self.server, self.data,
-                                  *self.data.advance(self.mix["preroll_steps"]))
+        preroll = self.data.advance(self.mix["preroll_steps"]) \
+            if self.mix.get("preroll_steps") else None
+        # from here on the stream of tails is the helper's: it makes the
+        # first ticks while the pre-roll loads
+        self.producer.start(self.data.hand_over())
+        if preroll is not None:
+            harness.load_columnar(self.server, self.data, *preroll)
             self.server.get("/internal/force_flush")
             self.ask(self._next_query()[1])
             n += 1
@@ -137,6 +162,7 @@ class Generator:
         keep = self.mix["check_sample"]
         lat, asked, kept, failed = [], [], [], 0
         last = None
+        waited = self.producer.wait_s if self.producer else 0.0
         t_open = time.perf_counter()
         while time.perf_counter() - t_open < seconds:
             if self.mix["ingest"]:
@@ -159,8 +185,10 @@ class Generator:
         window_s = time.perf_counter() - t_open
         if last is not None and all(r is not last for r in kept):
             kept.append(last)
+        if self.producer:
+            waited = self.producer.wait_s - waited
         return dict(latencies=lat, asked=asked, failed=failed, kept=kept,
-                    window_s=window_s)
+                    window_s=window_s, producer_wait_s=waited)
 
 
 def answered(body: bytes) -> bool:

@@ -7,20 +7,23 @@ A trace here is {"planes": [{"name", "lines": [{"name", "events":
 named "/device:TPU:<n>"; their line "XLA Ops" holds one event per
 executed HLO op and "XLA Modules" one per launched program, named
 "<jit name>(<fingerprint>)".  Host planes hold the threads, and on them
-the harness's own "bench:*" annotations.
+the marks: the harness's own "bench:*" annotations on the client's
+thread, and the program's phase seam's "vm:*" (one a served phase, nested
+as the phases nest) on the threads that serve.
 """
 
 from __future__ import annotations
 
 import bisect
 import glob
+import heapq
 import os
 import re
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
-MARK = "bench:"
+MARKS = ("bench:", "vm:")
 
 
 def read(logdir: str) -> dict:
@@ -40,7 +43,7 @@ def read(logdir: str) -> dict:
                 continue
             events = [[e.name, int(e.start_ns), int(e.duration_ns)]
                       for e in line.events
-                      if device or e.name.startswith(MARK)]
+                      if device or e.name.startswith(MARKS)]
             if events:
                 lines.append({"name": line.name, "events": events})
         if lines:
@@ -104,29 +107,54 @@ def top_ops(trace: dict, n: int = 10) -> list:
     return [[name, dur / 1e9] for name, dur in top]
 
 
+def innermost(marks: list) -> list:
+    """[(start, end, name)], sorted and disjoint: each stretch of time
+    that some mark covers, under the INNERMOST mark covering it - of
+    those open, the one that started last (the shorter of two that
+    started together).  On one thread that is the mark's self time; over
+    threads, the server's phase inside the client's call."""
+    marks = sorted(marks, key=lambda e: (e[1], -e[2]))
+    edges = sorted({e[1] for e in marks} | {e[1] + e[2] for e in marks})
+    out, open_, i = [], [], 0
+    for t0, t1 in zip(edges, edges[1:]):
+        while i < len(marks) and marks[i][1] <= t0:
+            name, start, dur = marks[i]
+            # a heap by the latest start, then the nearest end
+            heapq.heappush(open_, (-start, start + dur, i, name))
+            i += 1
+        while open_ and open_[0][1] <= t0:
+            heapq.heappop(open_)
+        if open_:
+            name = open_[0][3]
+            if out and out[-1][2] == name and out[-1][1] == t0:
+                out[-1][1] = t1
+            else:
+                out.append([t0, t1, name])
+    return out
+
+
 def idle_gaps(trace: dict, n: int = 10) -> list:
     """[[what the host was doing, seconds]]: the time the first device sat
-    idle between ops, shared out over the harness annotations that cover
-    it ("unmarked" where none does), longest first."""
+    idle between ops, each stretch of it charged to the innermost mark
+    that covers it ("unmarked" where none does), longest first.  What is
+    left under "bench:query_range" is the client's own: the request on
+    its way and the body read."""
     lines = device_lines(trace, OPS_LINE)
     if not lines or not lines[0]:
         return []
-    marks = sorted((e for plane in trace["planes"]
-                    if not DEVICE_PLANE.match(plane["name"])
-                    for line in plane["lines"] for e in line["events"]
-                    if e[0].startswith(MARK)), key=lambda e: e[1])
-    starts = [e[1] for e in marks]
+    spans = innermost([e for plane in trace["planes"]
+                       if not DEVICE_PLANE.match(plane["name"])
+                       for line in plane["lines"] for e in line["events"]
+                       if e[0].startswith(MARKS)])
+    ends = [t1 for _, t1, _ in spans]
     busy = union(lines[0])
     total = {}
     for (_, g0), (g1, _) in zip(busy, busy[1:]):
         left = g1 - g0
-        # one client: its marks do not overlap, so only the mark that
-        # holds g0 and those that start inside the gap can cover it
-        for name, start, dur in marks[max(bisect.bisect_right(starts, g0)
-                                          - 1, 0):]:
-            if start >= g1:
+        for t0, t1, name in spans[bisect.bisect_right(ends, g0):]:
+            if t0 >= g1:
                 break
-            cover = max(min(g1, start + dur) - max(g0, start), 0)
+            cover = min(g1, t1) - max(g0, t0)
             total[name] = total.get(name, 0) + cover
             left -= cover
         total["unmarked"] = total.get("unmarked", 0) + left
