@@ -206,6 +206,55 @@ def test_oracle_disables_resident_reuse(tmp_path, monkeypatch):
         "vm_device_window_cache_hits_total").get() == hits0
 
 
+def test_refresh_finds_its_series_plan(tmp_path):
+    """The host side of the same loop: a refresh's tail fetch asks for
+    the series set the refresh before it asked for, so from the second
+    refresh on it finds its series plan
+    (vm_fetch_plan_total{result="hit"} ticks) and does not derive the
+    panel's names again; the served answer still equals a cold eval."""
+    from victoriametrics_tpu.query.tpu_engine import TPUEngine
+    s, end, vals0, rng = _mk_store(tmp_path / "s", n_samples=240)
+    if (end - 20 * STEP) // 86_400_000 != (end + 3 * STEP) // 86_400_000:
+        s.close()
+        pytest.skip("the loop would cross midnight UTC: a new day's "
+                    "index is a new series list, rightly a miss")
+    try:
+        rrc.GLOBAL.reset()
+        engine = TPUEngine(min_series=4)
+        api = PrometheusAPI(s, engine)
+        dur = 239 * SCRAPE // STEP * STEP - 10 * STEP
+        kw = dict(step=STEP, storage=s, tpu=engine)
+        api._exec_range_cached(EvalConfig(start=end - dur, end=end, **kw),
+                               Q, end)
+        plan = {r: metricslib.REGISTRY.counter(
+            f'vm_fetch_plan_total{{result="{r}"}}') for r in ("hit", "miss")}
+        win0 = metricslib.REGISTRY.counter(
+            "vm_device_window_cache_hits_total").get()
+        for r in range(3):
+            end += STEP
+            _ingest(s, rng, vals0, end)
+            hit0, miss0 = plan["hit"].get(), plan["miss"].get()
+            served = api._exec_range_cached(
+                EvalConfig(start=end - dur, end=end, **kw), Q, end)
+            if r:
+                assert plan["hit"].get() > hit0, f"refresh {r + 1}: no hit"
+                assert plan["miss"].get() == miss0, f"refresh {r + 1}"
+            cold = exec_query(EvalConfig(start=end - dur, end=end, **kw,
+                                         disable_cache=True), Q)
+            gm, cm = _as_map(served), _as_map(cold)
+            assert set(gm) == set(cm) and len(gm) == 4
+            for k in gm:
+                fa = np.isnan(gm[k])
+                np.testing.assert_array_equal(fa, np.isnan(cm[k]))
+                np.testing.assert_allclose(gm[k][~fa], cm[k][~fa],
+                                           rtol=1e-12, err_msg=str(r))
+        assert metricslib.REGISTRY.counter(
+            "vm_device_window_cache_hits_total").get() >= win0 + 3, \
+            "the loop left the device path"
+    finally:
+        s.close()
+
+
 def test_window_slide_compaction_keeps_rolling(tmp_path):
     """Column-headroom exhaustion triggers on-device compaction (samples
     older than the fetch bound dropped, origin rebased) instead of a

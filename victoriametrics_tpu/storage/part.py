@@ -65,17 +65,18 @@ def _dec_budget_release(cost: int) -> None:
 # numpy mirror of BlockHeader's struct layout (">32sqqIhBBBqqQIQI"); the
 # TSID's trailing 8 bytes are the metric_id (tsid.py _FMT ">IIQIIQ"), split
 # out so header selection is pure array masking
-def sorted_member_mask(mids_sorted, mids: np.ndarray) -> np.ndarray:
-    """Membership mask of each metric id in the SORTED wanted-id array
-    (None = everything matches). Shared by the file-part and in-memory
-    columnar block selectors so their semantics cannot diverge."""
-    if mids_sorted is None:
-        return np.ones(mids.shape, bool)
+def sorted_member_mask(mids_sorted: np.ndarray, mids: np.ndarray):
+    """(mask, pos): whether each metric id is in the SORTED wanted-id
+    array, and where (``mids_sorted[pos] == mids`` under the mask). Shared
+    by the file-part and in-memory columnar block selectors so their
+    semantics cannot diverge; the positions label the selected blocks of
+    a piece, so the fetch never looks an id up a second time."""
     if len(mids_sorted) == 0:
-        return np.zeros(mids.shape, bool)
+        return np.zeros(mids.shape, bool), np.zeros(mids.shape, np.intp)
     pos = np.searchsorted(mids_sorted, mids)
-    pos_c = np.minimum(pos, len(mids_sorted) - 1)
-    return (mids_sorted[pos_c] == mids) & (pos < len(mids_sorted))
+    # an id past the last wanted one clips onto it and compares unequal
+    np.minimum(pos, len(mids_sorted) - 1, out=pos)
+    return mids_sorted[pos] == mids, pos
 
 
 def _clip_gather(mids, scales, ts_src, m_src, bstart, bend, min_ts, max_ts,
@@ -430,11 +431,12 @@ class Part:
         # kernel; a memo only short-circuits the mode that can use it
         self._dec = None
         self._dec_cost = 0
-        # memoized block-membership masks keyed by the wanted-id set:
-        # a rolling refresh selects the SAME series every step, so the
-        # O(#blocks) membership scan runs once per id set and only the
-        # (cheap, vectorized) time clip reruns per refresh
-        self._member_memo: "OrderedDict[tuple, np.ndarray]" = OrderedDict()
+        # memoized block membership (mask, positions in the wanted ids)
+        # keyed by the wanted-id set: a rolling refresh selects the SAME
+        # series every step, so the O(#blocks) membership scan runs once
+        # per id set and only the (cheap, vectorized) time clip reruns
+        # per refresh
+        self._member_memo: "OrderedDict[tuple, tuple]" = OrderedDict()
 
     def close(self):
         self._release_dec()
@@ -592,8 +594,9 @@ class Part:
 
     def collect_columns(self, mids_sorted, min_ts, max_ts):
         """Vectorized header selection + ONE native decode pass over every
-        matched block, row-clipped to [min_ts, max_ts]. Returns (mids,
-        cnts, scales, ts_concat, mant_concat); None when the native path is
+        matched block, row-clipped to [min_ts, max_ts]. Returns (pos,
+        cnts, scales, ts_concat, mant_concat), a block labeled by its
+        id's position in `mids_sorted`; None when the native path is
         unavailable (caller falls back to the per-header object path);
         False when the vectorized path RAN and nothing matched (caller
         skips this part — do not collapse the two sentinels,
@@ -612,15 +615,15 @@ class Part:
             # suffix-aware early-out: a part wholly outside the tail
             # window never builds header columns or scans membership
             return False
-        hc, lo, hi, idx = self._select_blocks(mids_sorted, min_ts, max_ts)
+        hc, lo, hi, idx, pos = self._select_blocks(mids_sorted, min_ts,
+                                                   max_ts)
         if idx.size == 0:
             return False
         dec = self._dec
         if dec is not None and dec[0] == "mant":
             _, ts_full, m_full, goff_full = dec
             piece = _clip_gather(
-                np.ascontiguousarray(hc["mid"][idx]),
-                np.ascontiguousarray(hc["scale"][idx]),
+                pos, np.ascontiguousarray(hc["scale"][idx]),
                 ts_full, m_full, goff_full[idx], goff_full[idx + 1],
                 min_ts, max_ts)
             return piece if piece[3].size else False
@@ -644,41 +647,40 @@ class Part:
             validate_ts=False)
         if idx.size == hc["mid"].size:
             self._maybe_memoize("mant", ts_out, m_out, cnt, idx.size, total)
-        return clip_piece(np.ascontiguousarray(hc["mid"][idx]), cnt,
-                          np.ascontiguousarray(hc["scale"][idx]),
+        return clip_piece(pos, cnt, np.ascontiguousarray(hc["scale"][idx]),
                           ts_out, m_out, min_ts, max_ts)
 
     def _select_blocks(self, mids_sorted, min_ts, max_ts):
         """Shared header selection of the batched read paths: returns
-        (hc, lo, hi, idx) where idx lists the blocks overlapping
-        [min_ts, max_ts] for the wanted metric ids.  The membership mask
-        is memoized per id set (suffix-aware fetch: a rolling refresh's
-        repeated identical series set pays only the time clip)."""
+        (hc, lo, hi, idx, pos) where idx lists the blocks overlapping
+        [min_ts, max_ts] for the wanted metric ids and pos each one's
+        position in `mids_sorted`.  The membership is memoized per id
+        set (suffix-aware fetch: a rolling refresh's repeated identical
+        series set pays only the time clip)."""
         hc = self.header_columns()
         lo = -(1 << 62) if min_ts is None else min_ts
         hi = (1 << 62) if max_ts is None else max_ts
-        mm = self._member_mask(mids_sorted, hc)
-        mask = (hc["max_ts"] >= lo) & (hc["min_ts"] <= hi) & mm
-        return hc, lo, hi, np.flatnonzero(mask)
+        mm, pos = self._member_mask(mids_sorted, hc)
+        idx = np.flatnonzero((hc["max_ts"] >= lo) & (hc["min_ts"] <= hi) & mm)
+        return hc, lo, hi, idx, pos[idx]
 
-    def _member_mask(self, mids_sorted, hc) -> np.ndarray:
-        if mids_sorted is None:
-            return sorted_member_mask(mids_sorted, hc["mid"])
+    def _member_mask(self, mids_sorted, hc):
         import xxhash
         key = (xxhash.xxh64_intdigest(np.ascontiguousarray(
             mids_sorted).tobytes()), int(mids_sorted.size))
         with self._lock:
-            mm = self._member_memo.get(key)
-            if mm is not None:
+            got = self._member_memo.get(key)
+            if got is not None:
                 self._member_memo.move_to_end(key)
-                return mm
-        mm = sorted_member_mask(mids_sorted, hc["mid"])
-        mm.setflags(write=False)
+                return got
+        got = sorted_member_mask(mids_sorted, hc["mid"])
+        for a in got:
+            a.setflags(write=False)
         with self._lock:
-            self._member_memo[key] = mm
+            self._member_memo[key] = got
             while len(self._member_memo) > 4:
                 self._member_memo.popitem(last=False)
-        return mm
+        return got
 
     def _maybe_memoize(self, kind, ts_arr, data_arr, cnt, n_blocks,
                        total) -> None:
@@ -750,8 +752,9 @@ class Part:
         mantissas straight to float64 with the block exponents and
         compacts into freshly allocated columns — no per-block Python, no
         intermediate mantissa arrays, fully-clipped blocks never decode
-        their value stream. Returns a FLOAT piece (mids, cnts, ts,
-        vals_f64); None when the native fused path is unavailable (caller
+        their value stream. Returns a FLOAT piece (pos, cnts, ts,
+        vals_f64), a block labeled by its id's position in `mids_sorted`;
+        None when the native fused path is unavailable (caller
         falls back to the split path and converts); False when it RAN and
         nothing matched.
 
@@ -766,30 +769,29 @@ class Part:
             # suffix-aware early-out: a part wholly outside the tail
             # window never builds header columns or scans membership
             return False
-        hc, lo, hi, idx = self._select_blocks(mids_sorted, min_ts, max_ts)
+        hc, lo, hi, idx, pos = self._select_blocks(mids_sorted, min_ts,
+                                                   max_ts)
         if idx.size == 0:
             return False
         dec = self._dec
         if dec is not None:
             kind, ts_full, data_full, goff_full = dec
-            mids, cnts, scales, ts_k, d_k = _clip_gather(
-                np.ascontiguousarray(hc["mid"][idx]),
-                np.ascontiguousarray(hc["scale"][idx]),
+            pos, cnts, scales, ts_k, d_k = _clip_gather(
+                pos, np.ascontiguousarray(hc["scale"][idx]),
                 ts_full,
                 data_full.view(np.int64) if kind == "float" else data_full,
                 goff_full[idx], goff_full[idx + 1], min_ts, max_ts)
             if not ts_k.size:
                 return False
             if kind == "float":
-                return mids, cnts, ts_k, d_k.view(np.float64)
-            return _piece_to_float((mids, cnts, scales, ts_k, d_k))
+                return pos, cnts, ts_k, d_k.view(np.float64)
+            return _piece_to_float((pos, cnts, scales, ts_k, d_k))
         ts_mt = np.ascontiguousarray(hc["ts_mt"][idx])
         val_mt = np.ascontiguousarray(hc["val_mt"][idx])
         if not self._compressed_decodable(idx, ts_mt, val_mt):
             return None
         cnt = np.ascontiguousarray(hc["rows"][idx])
         total = int(cnt.sum())
-        mids = np.ascontiguousarray(hc["mid"][idx])
         scales = np.ascontiguousarray(hc["scale"][idx])
         # when the query touches every block of the part, decode UNCLIPPED
         # so the whole-part float memo can build even though this query
@@ -812,20 +814,20 @@ class Part:
             goff = np.empty(idx.size + 1, np.int64)
             goff[0] = 0
             np.cumsum(cnt, out=goff[1:])
-            mids, cnts, _, ts_c, d_c = _clip_gather(
-                mids, scales, ts_k, vals_k.view(np.int64), goff[:-1],
+            pos, cnts, _, ts_c, d_c = _clip_gather(
+                pos, scales, ts_k, vals_k.view(np.int64), goff[:-1],
                 goff[1:], min_ts, max_ts,
-                unchanged=(mids, cnt, scales, ts_k,
+                unchanged=(pos, cnt, scales, ts_k,
                            vals_k.view(np.int64)))
             if not ts_c.size:
                 return False
-            return mids, cnts, ts_c, d_c.view(np.float64)
+            return pos, cnts, ts_c, d_c.view(np.float64)
         if ts_k.size == 0:
             return False
         nz = kept > 0
         if not nz.all():
-            return mids[nz], kept[nz], ts_k, vals_k
-        return mids, kept, ts_k, vals_k
+            return pos[nz], kept[nz], ts_k, vals_k
+        return pos, kept, ts_k, vals_k
 
     def read_blocks_columns(self, hdrs: list[BlockHeader]):
         """Batched decode of many blocks in ONE native call per stream

@@ -204,24 +204,24 @@ class InmemoryPart:
         return c
 
     def collect_columns(self, mids_sorted, min_ts, max_ts):
-        """Vectorized block selection -> (mids, cnts, scales, ts, mants)
+        """Vectorized block selection -> (pos, cnts, scales, ts, mants)
         or None when nothing matches. `mids_sorted` is a sorted int64 array
-        of wanted metric ids (None = all)."""
+        of wanted metric ids; pos is each selected block's position in
+        it."""
         from .part import sorted_member_mask
         mids, cnts, scales, bmin, bmax, offs, ts_all, m_all = self.columns()
         lo = -(1 << 62) if min_ts is None else min_ts
         hi = (1 << 62) if max_ts is None else max_ts
-        mask = (bmax >= lo) & (bmin <= hi) & \
-            sorted_member_mask(mids_sorted, mids)
-        idx = np.flatnonzero(mask)
+        member, pos = sorted_member_mask(mids_sorted, mids)
+        idx = np.flatnonzero((bmax >= lo) & (bmin <= hi) & member)
         if idx.size == 0:
             return None
         sel_cnts = cnts[idx]
         tot = int(sel_cnts.sum())
         excl = np.cumsum(sel_cnts) - sel_cnts
-        pos = np.repeat(offs[idx] - excl, sel_cnts) + \
+        rows = np.repeat(offs[idx] - excl, sel_cnts) + \
             np.arange(tot, dtype=np.int64)
-        return (mids[idx], sel_cnts, scales[idx], ts_all[pos], m_all[pos])
+        return (pos[idx], sel_cnts, scales[idx], ts_all[rows], m_all[rows])
 
 
 class PendingChunk:
@@ -1102,19 +1102,25 @@ class Partition:
             yield from p.iter_blocks(tsid_set, min_ts, max_ts,
                                      tsid_lo, tsid_hi)
 
-    def collect_units(self, tsid_set=None, min_ts=None, max_ts=None,
-                      tsid_lo=None, tsid_hi=None, mids_sorted=None,
-                      as_float=False, ds=None, note=None):
+    def collect_units(self, series, min_ts, max_ts, as_float=False,
+                      ds=None, note=None):
         """Batched block collection, split into independent work units
         for the shared fetch pool (utils/workpool): returns a list of
-        zero-arg callables, each yielding a list of (mids, cnts, scales,
-        ts_concat, mant_concat) pieces.  Executing the units in ORDER and
-        concatenating their outputs is bit-identical to the sequential
-        collection — the pool preserves submit order, so parallel and
-        sequential fetches return the same bytes.
+        zero-arg callables, each yielding a list of (pos, cnts, scales,
+        ts_concat, mant_concat) pieces.  ``series`` is the fetch's series
+        plan (storage.py _SeriesPlan), read here for the wanted ids as a
+        sorted int64 array, ``mids_sorted`` (and, for the per-header
+        fallback, as a set, ``tsid_set``, with their TSID bounds
+        ``tsid_lo``/``tsid_hi``); a piece labels each block with its id's
+        POSITION in that array, the one lookup the membership test makes
+        anyway.
+        Executing the units in ORDER and concatenating their outputs is
+        bit-identical to the sequential collection — the pool preserves
+        submit order, so parallel and sequential fetches return the same
+        bytes.
 
         With ``as_float=True`` (the VM_NATIVE_ASSEMBLE fused read path)
-        every unit instead yields FLOAT pieces (mids, cnts, ts_concat,
+        every unit instead yields FLOAT pieces (pos, cnts, ts_concat,
         vals_f64): file parts run the one-call native fetch→decode→clip→
         float kernel (Part.assemble_columns), and the in-memory /
         fallback sub-paths convert their mantissa pieces per block so the
@@ -1156,12 +1162,10 @@ class Partition:
                                  if st.has_parts]
                     break
         mems = mems + pend
-        if mids_sorted is None and tsid_set is not None:
-            mids_sorted = np.fromiter(tsid_set, np.int64, len(tsid_set))
-            mids_sorted.sort()
+        mids_sorted = series.mids_sorted
         lo = -(1 << 62) if min_ts is None else min_ts
         hi = (1 << 62) if max_ts is None else max_ts
-        from .part import _piece_to_float, clip_piece
+        from .part import _piece_to_float, clip_piece, sorted_member_mask
         units = []
 
         # -- tier selection (see docstring) --------------------------------
@@ -1250,15 +1254,17 @@ class Partition:
                     return [dslib.count_tail_piece(piece, as_float)
                             if ones else piece]
                 # fallback: native decode unavailable — per-header path
-                hdrs = list(p.iter_headers(tsid_set, u_lo, u_hi,
-                                           tsid_lo, tsid_hi))
+                hdrs = list(p.iter_headers(series.tsid_set, u_lo, u_hi,
+                                           series.tsid_lo, series.tsid_hi))
                 if not hdrs:
                     return []
                 K = len(hdrs)
                 ts_c, m_c = p.read_blocks_columns(hdrs)
                 piece = clip_piece(
-                    np.fromiter((h.tsid.metric_id for h in hdrs),
-                                np.int64, K),
+                    sorted_member_mask(
+                        mids_sorted,
+                        np.fromiter((h.tsid.metric_id for h in hdrs),
+                                    np.int64, K))[1],
                     np.fromiter((h.rows for h in hdrs), np.int64, K),
                     np.fromiter((h.scale for h in hdrs), np.int64, K),
                     ts_c, m_c, u_lo, u_hi)
@@ -1267,23 +1273,6 @@ class Partition:
                         if ones else piece]
             units.append(file_unit)
         return units
-
-    def collect_columns(self, tsid_set=None, min_ts=None, max_ts=None,
-                        tsid_lo=None, tsid_hi=None, mids_sorted=None,
-                        as_float=False, ds=None, note=None):
-        """Batched block collection: returns (mids, cnts, scales, ts_concat,
-        mant_concat) numpy arrays over every matching block in this
-        partition (float pieces under ``as_float`` — see collect_units).
-        File parts decode ALL their matched blocks in one native
-        call (part.read_blocks_columns); in-memory parts are masked
-        columnar views with zero per-block Python.  (Sequential execution
-        of collect_units; Table.collect_columns fans the same units across
-        the shared work pool.)"""
-        return [piece
-                for unit in self.collect_units(tsid_set, min_ts, max_ts,
-                                               tsid_lo, tsid_hi, mids_sorted,
-                                               as_float, ds, note)
-                for piece in unit()]
 
     @property
     def rows(self) -> int:

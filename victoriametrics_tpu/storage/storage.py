@@ -77,6 +77,13 @@ _FANOUT_MIN_REGS = 64
 _DEADLINE_ABORTS = metricslib.REGISTRY.counter(
     "vm_storage_deadline_aborts_total")
 
+# one inc a fetch: whether the fetch found its series plan (see
+# _SeriesPlan) or had to build it
+_PLAN = {
+    res: metricslib.REGISTRY.counter(
+        f'vm_fetch_plan_total{{result="{res}"}}')
+    for res in ("hit", "miss")}
+
 _storage_tokens = itertools.count(1)
 
 
@@ -297,6 +304,21 @@ def _ingest_lap(ph: flightrec.phase, phase: str) -> None:
     ph.lap("ingest:" + phase, _ING_PHASE[phase])
 
 
+class _SeriesPlan:
+    """What a fetch derives from its series set ALONE, kept per (the
+    index's tsid list, structural version) so a rolling refresh pays for
+    its new samples and not for the panel's names.  Nothing here is about
+    samples, parts or time ranges.
+
+    ``row[i]`` is the answer's row of ``mids_sorted[i]`` when every named
+    series has samples (rows run in raw-name order), -1 where the index
+    has no name for the id; ``ordered_mids`` / ``raws`` / ``names`` are
+    per row, ``groups`` the names' metric groups."""
+
+    __slots__ = ("tsids", "tsid_set", "mids_sorted", "tsid_lo", "tsid_hi",
+                 "row", "ordered_mids", "raws", "names", "groups")
+
+
 class SeriesData:
     """Decoded query result for one series."""
 
@@ -403,11 +425,12 @@ class Storage:
         self._append_log: deque = deque(maxlen=4096)
         self._append_log_floor = 0  # appends at versions <= floor may be
         #                             missing from the bounded log
-        # memoized name-resolution/row-order products per fetched id set
-        # (suffix-aware fetch; see _resolve_ordered_names)
+        # series plans, LRU: (id of the index's tsid list, structural
+        # version) -> _SeriesPlan, which keeps that list alive (see
+        # _series_plan)
         from collections import OrderedDict
-        self._name_memo: OrderedDict = OrderedDict()
-        self._name_memo_lock = make_lock("storage.Storage._name_memo")
+        self._plan_memo: OrderedDict = OrderedDict()
+        self._plan_memo_lock = make_lock("storage.Storage._plan_memo")
         self.slow_row_inserts = 0
         self.new_series_created = 0
         # metric-name usage stats + TYPE/HELP metadata (storage-resident
@@ -1290,25 +1313,38 @@ class Storage:
                     filters, min_ts, max_ts, interval, max_series, tenant,
                     _tsids, ColumnarSeries, assemble, budget, ds, ph)
 
-    def _resolve_ordered_names(self, uniq: np.ndarray):
-        """Raw-name resolution + canonical (raw-sorted) row order for a
-        fetched metric-id set: (have, kept, rank, ordered_mids,
-        raws_in_row_order, names_in_row_order).  Memoized on the id set +
-        structural version (metric id -> name is immutable; deletes and
-        retention bump structural_version), LRU-bounded — the
-        suffix-aware fetch's answer to per-refresh O(S) resolution."""
-        import xxhash
-        key = (xxhash.xxh64_intdigest(np.ascontiguousarray(uniq).tobytes()),
-               int(uniq.size), self.structural_version)
-        with self._name_memo_lock:
-            got = self._name_memo.get(key)
-            if got is not None:
-                self._name_memo.move_to_end(key)
-                return got
-        names = self.idb.get_metric_names_by_ids([int(m) for m in uniq])
-        have = np.array([int(m) in names for m in uniq], bool)
-        kept = uniq[have]
-        raws = [names[int(m)][1] for m in kept]
+    def _series_plan(self, tsids: list, keep: bool) -> _SeriesPlan:
+        """The plan of this tsid list, found or built (and counted so).
+        The key is the list ITSELF: `idb.search_tsids` hands the same
+        object out refresh after refresh until a series is registered, a
+        day's index begins or the filter changes, and the plan holds the
+        list, so its id cannot come back as another's.  Deletes and
+        retention bump structural_version (metric id -> name is
+        immutable otherwise).  `keep` is False for a list no later fetch
+        can bring again (a caller's own slice)."""
+        key = (id(tsids), self.structural_version)
+        with self._plan_memo_lock:
+            plan = self._plan_memo.get(key)
+            if plan is not None and plan.tsids is tsids:
+                self._plan_memo.move_to_end(key)
+            else:
+                plan = None
+        _PLAN["miss" if plan is None else "hit"].inc()
+        if plan is not None:
+            return plan
+        plan = _SeriesPlan()
+        plan.tsids = tsids
+        plan.tsid_set = frozenset(t.metric_id for t in tsids)
+        plan.tsid_lo = tsids[0].sort_key()
+        plan.tsid_hi = tsids[-1].sort_key()
+        mids = np.fromiter(plan.tsid_set, np.int64, len(plan.tsid_set))
+        mids.sort()
+        plan.mids_sorted = mids
+        ids = mids.tolist()
+        names = self.idb.get_metric_names_by_ids(ids)
+        have = np.fromiter((m in names for m in ids), bool, len(ids))
+        kept = mids[have]
+        raws = [names[m][1] for m in kept.tolist()]
         if len(raws) > 1:
             # fixed-width bytes argsort (C memcmp) instead of a Python-object
             # compare per element; numpy's S dtype strips trailing NULs, so
@@ -1321,18 +1357,21 @@ class Storage:
             perm = np.argsort(arr, kind="stable")
         else:
             perm = np.arange(len(raws), dtype=np.int64)
-        ordered_mids = kept[perm]
-        # rank[j] = final row of kept[j]
+        plan.ordered_mids = kept[perm]
+        plan.row = np.full(mids.size, -1, np.int64)
+        # the final row of kept[j] is where perm puts it
         rank = np.empty(perm.size, np.int64)
         rank[perm] = np.arange(perm.size)
-        raws_final = [raws[i] for i in perm]
-        names_final = [names[int(m)][0] for m in ordered_mids]
-        val = (have, kept, rank, ordered_mids, raws_final, names_final)
-        with self._name_memo_lock:
-            self._name_memo[key] = val
-            while len(self._name_memo) > 64:
-                self._name_memo.popitem(last=False)
-        return val
+        plan.row[have] = rank
+        plan.raws = [raws[i] for i in perm]
+        plan.names = [names[m][0] for m in plan.ordered_mids.tolist()]
+        plan.groups = frozenset(mn.metric_group for mn in plan.names)
+        if keep:
+            with self._plan_memo_lock:
+                self._plan_memo[key] = plan
+                while len(self._plan_memo) > 64:
+                    self._plan_memo.popitem(last=False)
+        return plan
 
     def _search_columns_gated(self, filters, min_ts, max_ts, interval,
                               max_series, tenant, _tsids, ColumnarSeries,
@@ -1350,7 +1389,7 @@ class Storage:
         empty = ColumnarSeries.empty()
         if not tsids:
             return empty
-        tsid_set = {t.metric_id for t in tsids}
+        plan = self._series_plan(tsids, keep=_tsids is None)
         # downsampled-tier serving: a note dict both ENABLES per-
         # partition tier selection and reports back what was chosen;
         # VM_DOWNSAMPLE_READ=0 (the raw-oracle escape hatch) keeps every
@@ -1373,9 +1412,7 @@ class Storage:
         fused = _native.assemble_enabled()
         _phase_lap(ph, "assemble_native" if fused else "collect")
         pieces = self.table.collect_columns(
-            tsid_set, min_ts, max_ts,
-            tsid_lo=tsids[0].sort_key(), tsid_hi=tsids[-1].sort_key(),
-            as_float=fused,
+            plan, min_ts, max_ts, as_float=fused,
             check=budget.check if budget is not None else None,
             ds=ds, note=note)
         _phase_lap(ph, "assemble" if fused else "decode")
@@ -1395,10 +1432,10 @@ class Storage:
             return empty
         if fused:
             if len(pieces) == 1:
-                mids, cnts, ts_all, vals_f = pieces[0]
+                pos, cnts, ts_all, vals_f = pieces[0]
                 piece_ids = None  # one piece: every block shares provenance
             else:
-                mids = np.concatenate([p[0] for p in pieces])
+                pos = np.concatenate([p[0] for p in pieces])
                 cnts = np.concatenate([p[1] for p in pieces])
                 ts_all = np.concatenate([p[2] for p in pieces])
                 vals_f = np.concatenate([p[3] for p in pieces])
@@ -1406,10 +1443,10 @@ class Storage:
                                       [p[0].size for p in pieces])
         else:
             if len(pieces) == 1:
-                mids, cnts, scales, ts_all, mant_all = pieces[0]
+                pos, cnts, scales, ts_all, mant_all = pieces[0]
                 piece_ids = None  # one piece: every block shares provenance
             else:
-                mids = np.concatenate([p[0] for p in pieces])
+                pos = np.concatenate([p[0] for p in pieces])
                 cnts = np.concatenate([p[1] for p in pieces])
                 scales = np.concatenate([p[2] for p in pieces])
                 ts_all = np.concatenate([p[3] for p in pieces])
@@ -1435,31 +1472,38 @@ class Storage:
         # parts (timestamps + decoded values) — the "bytesRead" column
         # of top_queries/usage
         costacc.add_part_bytes(int(ts_all.nbytes) + int(vals_f.nbytes))
-        # resolve names FIRST and bake the canonical raw-name row order into
-        # the assembly scatter (no post-assembly reorder pass); memoized
-        # on the fetched id set — a rolling refresh's per-step cost stays
-        # O(new samples), not O(S) name lookups + argsort
-        uniq = np.unique(mids)
-        if max_series is not None and uniq.size > max_series:
-            raise ResourceWarning(
-                f"query matches {uniq.size} series, limit {max_series}")
-        have, kept, rank, ordered_mids, raws_final, names_final = \
-            self._resolve_ordered_names(uniq)
-        # per-block target row; blocks of name-less series are dropped
-        pos_in_uniq = np.searchsorted(uniq, mids)
-        if not have.all():
-            bkeep = have[pos_in_uniq]
+        # a block's row comes off the plan by the position the membership
+        # test already found (the canonical raw-name row order is baked
+        # into the assembly scatter: no post-assembly reorder pass).  The
+        # limit counts the series that HAVE blocks in range.
+        if max_series is not None:
+            seen = np.zeros(plan.mids_sorted.size, bool)
+            seen[pos] = True
+            n_seen = int(np.count_nonzero(seen))
+            if n_seen > max_series:
+                raise ResourceWarning(
+                    f"query matches {n_seen} series, limit {max_series}")
+        block_rows = plan.row[pos]
+        ordered_mids = plan.ordered_mids
+        if ordered_mids.size < plan.mids_sorted.size:
+            # blocks of name-less series are dropped
+            bkeep = block_rows >= 0
             if not bkeep.all():
                 sample_keep = np.repeat(bkeep, cnts)
-                mids, cnts = mids[bkeep], cnts[bkeep]
+                block_rows, cnts = block_rows[bkeep], cnts[bkeep]
                 ts_all = ts_all[sample_keep]
                 vals_f = vals_f[sample_keep]
                 if piece_ids is not None:
                     piece_ids = piece_ids[bkeep]
-            pos_in_kept = np.searchsorted(kept, mids)
-        else:
-            pos_in_kept = pos_in_uniq
-        block_rows = rank[pos_in_kept]
+        # `live`: the plan's rows this answer has, in order (None = all);
+        # a series without a block in range gets no row and no name
+        live = None
+        present = np.zeros(ordered_mids.size, bool)
+        present[block_rows] = True
+        if not present.all():
+            live = np.flatnonzero(present)
+            block_rows = (np.cumsum(present) - 1)[block_rows]
+            ordered_mids = ordered_mids[live]
         # coalesce adjacent same-series blocks within one piece: a part's
         # blocks are (tsid, min_ts)-sorted, so a series' span-capped blocks
         # concatenate in time order — assemble then sees one block per
@@ -1490,23 +1534,27 @@ class Storage:
                 seg = np.cumsum(starts_blk) - 1
                 cnts = np.bincount(seg, weights=cnts).astype(np.int64)
                 block_rows = block_rows[starts_blk]
-        cols = assemble(block_rows, int(kept.size), cnts, ts_all, vals_f,
-                        min_ts, max_ts, interval, metric_ids=ordered_mids)
+        cols = assemble(block_rows, int(ordered_mids.size), cnts, ts_all,
+                        vals_f, min_ts, max_ts, interval,
+                        metric_ids=ordered_mids)
         if cols.dropped_rows is not None:
-            live = np.delete(np.arange(ordered_mids.size),
+            left = np.delete(np.arange(ordered_mids.size),
                              cols.dropped_rows)
-            cols.raw_names = [raws_final[i] for i in live]
-            cols.metric_names = [names_final[i] for i in live]
+            live = left if live is None else live[left]
+        # fresh list objects either way: the plan's must never alias a
+        # caller-mutable ColumnarSeries field
+        if live is None:
+            cols.raw_names = list(plan.raws)
+            cols.metric_names = list(plan.names)
+            groups = plan.groups
         else:
-            # fresh list objects: the memoized products must never alias
-            # a caller-mutable ColumnarSeries field
-            cols.raw_names = list(raws_final)
-            cols.metric_names = list(names_final)
+            cols.raw_names = [plan.raws[i] for i in live]
+            cols.metric_names = [plan.names[i] for i in live]
+            groups = {mn.metric_group for mn in cols.metric_names}
         cols.compute_stale_rows()
         self._note_to_cols(cols, note)
-        if cols.metric_names:
-            self.track_name_usage(
-                {mn.metric_group for mn in cols.metric_names})
+        if groups:
+            self.track_name_usage(groups)
         return cols
 
     @staticmethod

@@ -119,13 +119,13 @@ class Table:
             yield from p.iter_blocks(tsid_set, min_ts, max_ts,
                                      tsid_lo, tsid_hi)
 
-    def collect_columns(self, tsid_set=None, min_ts=None, max_ts=None,
-                        tsid_lo=None, tsid_hi=None, mids_sorted=None,
-                        as_float=False, check=None, ds=None, note=None):
+    def collect_columns(self, series, min_ts, max_ts, as_float=False,
+                        check=None, ds=None, note=None):
         """Batched per-partition block collection (see
-        Partition.collect_units); returns a flat list of pieces —
-        mantissa 5-tuples, or float 4-tuples under ``as_float`` (the
-        VM_NATIVE_ASSEMBLE fused kernel).
+        Partition.collect_units, for ``series`` too); returns a flat list
+        of pieces — mantissa 5-tuples, or float 4-tuples under
+        ``as_float`` (the VM_NATIVE_ASSEMBLE fused kernel) — whose
+        blocks are labeled by position in ``series.mids_sorted``.
 
         ``check`` (optional zero-arg callable, the storage-side deadline
         budget) runs before each fetch unit: an expired query aborts
@@ -142,14 +142,10 @@ class Table:
         parts = self.partitions_for_range(
             min_ts if min_ts is not None else -(1 << 62),
             max_ts if max_ts is not None else 1 << 62)
-        if mids_sorted is None and tsid_set is not None:
-            mids_sorted = np.fromiter(tsid_set, np.int64, len(tsid_set))
-            mids_sorted.sort()
         units = []
         for p in parts:
-            units.extend(p.collect_units(tsid_set, min_ts, max_ts,
-                                         tsid_lo, tsid_hi, mids_sorted,
-                                         as_float, ds, note))
+            units.extend(p.collect_units(series, min_ts, max_ts, as_float,
+                                         ds, note))
         if check is not None:
             units = [(lambda u=u: (check(), u())[1]) for u in units]
         from ..utils import workpool
