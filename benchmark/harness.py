@@ -189,17 +189,21 @@ def month_start_ms(ms: int) -> int:
     return calendar.timegm((t.tm_year, t.tm_mon, 1, 0, 0, 0)) * 1000
 
 
-def anchor(now_ms: int, step: int, reach_ms: int) -> tuple:
+def anchor(now_ms: int, step: int, reach_ms: int,
+           ingests: bool = True) -> tuple:
     """-> (newest, latest): where the bulk's newest sample is aimed and
     the ceiling no window may pass.  `newest` lies (WINDOW_TICKS +
     SETUP_STEPS) steps under `latest`, and `latest` WALL_MARGIN_MS behind
     the wall clock; `reach_ms` is how far under `newest` the bulk's first
-    sample can lie.  Everything a run can touch lies in ONE calendar
+    sample can lie.  A configuration none of whose mixes ingests
+    (`"ingests": false` in its file) needs no step free above its bulk:
+    its `newest` IS `latest` (at an hour's step the ticks' room would be
+    119 days).  Everything a run can touch lies in ONE calendar
     month (UTC), the storage's partition: where that span would hold a
     month's end it moves back as a whole, `latest` to the month's end less
     a step, so no run loads two partitions for one and no tick opens a
     new one inside a window."""
-    room = (WINDOW_TICKS + SETUP_STEPS) * step
+    room = (WINDOW_TICKS + SETUP_STEPS) * step if ingests else 0
     latest = now_ms - WALL_MARGIN_MS
     first_of_month = month_start_ms(latest)
     if latest - room - reach_ms < first_of_month:
@@ -239,7 +243,8 @@ class Dataset:
         # jitter under newest - span; the newest tail's up to the jitter
         # over `latest`, which `anchor` keeps a step inside the month
         now_ms, self.latest = anchor(now_ms, self.step,
-                                     span + self.step + jitter)
+                                     span + self.step + jitter,
+                                     cfg.get("ingests", True))
         self.rng = np.random.default_rng(seed)
         self.gen = load_module("deployments", cfg["deployment"]).Deployment(cfg)
         self.labels = self.gen.labels()
@@ -254,14 +259,17 @@ class Dataset:
         self.ts, self.vals = self.gen.scrapes(
             self.rng, self.t_start - self.scrape, n_samples)
         self.tails = []
+        self.work_memo = {}     # query_work's
+        self._flat = None       # samples_between's
 
     @property
     def start(self) -> int:
         return self.end - self.duration
 
     def room(self) -> int:
-        """The ticks of one query step left under the ceiling."""
-        return (self.latest - self.end) // self.step
+        """The ticks of one query step left under the ceiling (none
+        where the configuration never ingests)."""
+        return max(0, (self.latest - self.end) // self.step)
 
     def _move(self, span: int) -> None:
         if self.end + span > self.latest:
@@ -300,9 +308,31 @@ class Dataset:
         self._move(self.step)
         self.tails.append(tail)
 
+    def samples_between(self, idx: np.ndarray, lo: int, hi: int,
+                        n_tails: int) -> int:
+        """How many samples of the rows `idx`, of the bulk and the first
+        n_tails tails, lie in (lo, hi].  The bulk's rows are sorted, so
+        its share is two binary searches a row in ONE flat array made
+        once (row r's times shifted by r << 42, as reference._counts_le
+        shifts them): a refresh cell's every tick is another range, and a
+        pass over 47 M timestamps a tick cost a traced run of 190 ticks
+        four minutes."""
+        if self._flat is None:
+            rows = np.arange(self.ts.shape[0], dtype=np.int64)[:, None]
+            self._flat = (self.ts + (rows << 42)).ravel()
+        off = idx.astype(np.int64) << 42
+        n = int((np.searchsorted(self._flat, hi + off, side="right") -
+                 np.searchsorted(self._flat, lo + off, side="right")).sum())
+        for ts, _ in self.tails[:n_tails]:
+            sub = ts[idx]
+            n += int(((sub > lo) & (sub <= hi)).sum())
+        return n
+
     def snapshot(self, n_tails: int):
         """Every sample handed out up to the n_tails-th tail."""
         tails = self.tails[:n_tails]
+        if not tails:       # the bulk alone: no copy of 47 M samples
+            return self.ts, self.vals
         return (np.concatenate([self.ts] + [t for t, _ in tails], axis=1),
                 np.concatenate([self.vals] + [v for _, v in tails], axis=1))
 
@@ -335,7 +365,10 @@ def check_answers(data: Dataset, records: list, round_rollup=None) -> dict:
     unreadable = 0
     for r in records:
         ts, vals = data.snapshot(r["n_tails"])
-        grid = np.arange(r["start"], r["end"] + 1, data.step, dtype=np.int64)
+        # a query's own step where its record holds one (traffic/
+        # intervals.py), else the configuration's
+        step = r.get("step", data.step)
+        grid = np.arange(r["start"], r["end"] + 1, step, dtype=np.int64)
         ast = reference.parse(r["query"])
         kind, labels, ref = reference.evaluate(ast, data.labels, ts, vals, grid)
         if round_rollup is not None:
@@ -343,7 +376,7 @@ def check_answers(data: Dataset, records: list, round_rollup=None) -> dict:
                 ast, data.labels, ts, vals, grid, round_rollup))
         else:
             ok, got = compare.parse_answer(r["body"], r["start"], r["end"],
-                                           data.step)
+                                           step)
             unreadable += not ok
         results.append(compare.compare(kind, got, labels, ref))
     out = compare.worst(results)
@@ -367,21 +400,24 @@ def control_answer(kind: str, labels: list, values: np.ndarray) -> dict:
 
 
 def query_work(data: Dataset, asked: dict) -> dict:
-    """What one asked query needs moved, for the roofline: the real
-    samples of the matched series in the fetched range (start - window,
-    end], and the values of its answer."""
-    ast = reference.parse(asked["query"])
-    idx = reference.select(data.labels, *reference.selector(ast))
-    lo, hi = asked["start"] - data.window, asked["end"]
-    samples = sum(int(((ts[idx] > lo) & (ts[idx] <= hi)).sum())
-                  for ts in [data.ts] + [t for t, _ in
-                                         data.tails[:asked["n_tails"]]])
-    rows = len(idx)
-    if ast[0] == "sum":
-        rows = len({tuple(data.labels[i].get(k) for k in ast[1]) for i in idx})
-    elif ast[0] == "topk":
-        rows = min(ast[1], rows)
-    elif ast[0] == "hq":
-        rows = 1
-    steps = (asked["end"] - asked["start"]) // data.step + 1
-    return {"samples": samples, "out_values": rows * steps}
+    """What one asked query needs moved, for the roofline and the scan
+    rate: the real samples of the matched series in the fetched range
+    (start - window, end], the window being the query's own rollup's, and
+    the values of its answer (a row for every group the query leaves, of
+    a topk its k).  Kept on the data set by the query's text and range:
+    a window asks some of them a hundred times."""
+    memo = data.work_memo
+    step = asked.get("step", data.step)
+    key = (asked["query"], asked["start"], asked["end"], asked["n_tails"],
+           step)
+    if key not in memo:
+        ast = reference.parse(asked["query"])
+        idx = reference.select(data.labels, *reference.selector(ast))
+        lo, hi = asked["start"] - reference.window_of(ast), asked["end"]
+        samples = data.samples_between(idx, lo, hi, asked["n_tails"])
+        rows = len(reference.row_labels(ast, data.labels))
+        if ast[0] == "topk":
+            rows = min(ast[1], rows)
+        steps = (asked["end"] - asked["start"]) // step + 1
+        memo[key] = {"samples": samples, "out_values": rows * steps}
+    return memo[key]

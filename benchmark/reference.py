@@ -3,15 +3,23 @@ float64 NumPy straight from the samples the benchmark generated.  It
 imports nothing of the program and takes nothing the program made: its
 input is the deployment's own arrays (labels, timestamps, values).
 
-Semantics follow upstream VictoriaMetrics (app/vmselect/promql/rollup.go
-and transform.go):
+Semantics follow upstream VictoriaMetrics (app/vmselect/promql/rollup.go,
+aggr.go and transform.go):
 
+* a selector may leave the metric name out, and `=~` on any label,
+  `__name__` among them, matches the WHOLE value, as Prometheus anchors
+  it (a label the series lacks is the empty string);
 * a rollup at grid time t sees the window (t - w, t];
 * `rate` is (last - prev) / dt, prev being the last sample at or before
   the window start when it lies within maxPrevInterval of it, else the
   window's first sample (two samples needed); counter resets are removed;
-* `max_over_time` keeps the metric name, `rate` drops it;
-* `sum by (l)` ignores NaN and is NaN where every member is;
+* `avg_over_time` is the mean of the window's samples, NaN on an empty
+  window;
+* `max_over_time` and `avg_over_time` keep the metric name (upstream's
+  keep-name list), `rate` drops it;
+* `sum`, `max` and `avg by (l)` ignore NaN and are NaN where every member
+  is; the `by (...)` clause stands before the argument or after it, and
+  `__name__` is a grouping label where the rollup kept the name;
 * `topk(k, x)` keeps, at each t, the k largest x;
 * `histogram_quantile(phi, buckets)` interpolates linearly inside the
   bucket that holds rank phi * total, the lowest bucket starting at 0 and
@@ -28,16 +36,20 @@ import re
 
 import numpy as np
 
-_TOKEN = re.compile(r'\s*([A-Za-z_:][A-Za-z0-9_:]*|[0-9.]+[smhd]?|"[^"]*"|[(){}\[\],=])')
+_TOKEN = re.compile(
+    r'\s*([A-Za-z_:][A-Za-z0-9_:]*|[0-9.]+[smhd]?|"[^"]*"|=~|[(){}\[\],=])')
 _DUR = {"s": 1000, "m": 60_000, "h": 3_600_000, "d": 86_400_000}
-ROLLUPS = ("rate", "max_over_time")
-KEEP_NAME = ("max_over_time",)
+ROLLUPS = ("rate", "max_over_time", "avg_over_time")
+KEEP_NAME = ("max_over_time", "avg_over_time")
+AGGREGATES = ("sum", "max", "avg")
 
 
 def parse(expr: str):
     """Query text -> nested tuples:
-    ("hq", phi, e) | ("sum", by, e) | ("topk", k, e) |
-    ("rollup", func, name, {label: value}, window_ms)."""
+    ("hq", phi, e) | ("sum" | "max" | "avg", by, e) | ("topk", k, e) |
+    ("rollup", func, name, {label: matcher}, window_ms), where name is
+    None for a selector without one and a matcher is the value itself
+    for `=`, ("=~", pattern) for `=~`."""
     toks, pos = [], 0
     while pos < len(expr):
         m = _TOKEN.match(expr, pos)
@@ -59,20 +71,49 @@ def _eat(toks, want):
     return toks[1:]
 
 
+def _by(toks):
+    """An optional `by (a, b)` at the head of toks -> (labels, rest)."""
+    if toks[:1] != ["by"]:
+        return None, toks
+    by, toks = [], _eat(toks[1:], "(")
+    while toks[0] != ")":
+        if toks[0] != ",":
+            by.append(toks[0])
+        toks = toks[1:]
+    return tuple(by), toks[1:]
+
+
+def _selector(toks):
+    """`name`, `name{...}` or `{...}` -> (name, matchers, rest)."""
+    name = None
+    if toks[0] != "{":
+        name, toks = toks[0], toks[1:]
+    matchers = {}
+    if toks[0] == "{":
+        toks = toks[1:]
+        while toks[0] != "}":
+            if toks[0] == ",":
+                toks = toks[1:]
+                continue
+            label, op, value = toks[0], toks[1], toks[2].strip('"')
+            if op not in ("=", "=~"):
+                raise ValueError(f"expected '=' or '=~', found {op!r}")
+            matchers[label] = value if op == "=" else (op, value)
+            toks = toks[3:]
+        toks = toks[1:]
+    return name, matchers, toks
+
+
 def _expr(toks):
     head, toks = toks[0], toks[1:]
-    if head == "sum":
-        by = []
-        if toks[0] == "by":
-            toks = _eat(toks[1:], "(")
-            while toks[0] != ")":
-                if toks[0] != ",":
-                    by.append(toks[0])
-                toks = toks[1:]
-            toks = toks[1:]
+    if head in AGGREGATES:
+        by, toks = _by(toks)
         toks = _eat(toks, "(")
         sub, toks = _expr(toks)
-        return ("sum", tuple(by), sub), _eat(toks, ")")
+        toks = _eat(toks, ")")
+        if by is None:
+            by, toks = _by(toks)
+        return (head, by or (), sub), toks
     if head in ("topk", "histogram_quantile"):
         toks = _eat(toks, "(")
         num, toks = float(toks[0]), _eat(toks[1:], ",")
@@ -80,18 +121,7 @@ def _expr(toks):
         kind = ("topk", int(num)) if head == "topk" else ("hq", num)
         return kind + (sub,), _eat(toks, ")")
     if head in ROLLUPS:
-        toks = _eat(toks, "(")
-        name, toks = toks[0], toks[1:]
-        matchers = {}
-        if toks[0] == "{":
-            toks = toks[1:]
-            while toks[0] != "}":
-                if toks[0] == ",":
-                    toks = toks[1:]
-                    continue
-                label, toks = toks[0], _eat(toks[1:], "=")
-                matchers[label], toks = toks[0].strip('"'), toks[1:]
-            toks = toks[1:]
+        name, matchers, toks = _selector(_eat(toks, "("))
         toks = _eat(toks, "[")
         window = int(float(toks[0][:-1]) * _DUR[toks[0][-1]])
         toks = _eat(_eat(toks[1:], "]"), ")")
@@ -99,19 +129,81 @@ def _expr(toks):
     raise ValueError(f"the reference does not know {head!r}")
 
 
-def selector(ast):
-    """The (name, matchers) of the one selector under `ast`."""
+def _rollup_of(ast):
+    """The one rollup node under `ast`."""
     while ast[0] != "rollup":
         ast = ast[-1]
-    return ast[2], ast[3]
+    return ast
 
 
-def select(labels: list, name: str, matchers: dict) -> np.ndarray:
-    """Row indices of the series the selector matches."""
-    return np.array([i for i, l in enumerate(labels)
-                     if l["__name__"] == name and
-                     all(l.get(k) == v for k, v in matchers.items())],
-                    dtype=np.int64)
+def selector(ast):
+    """The (name, matchers) of the one selector under `ast`."""
+    return _rollup_of(ast)[2:4]
+
+
+def window_of(ast) -> int:
+    """The lookbehind window, in ms, of the one rollup under `ast`."""
+    return _rollup_of(ast)[4]
+
+
+def select(labels: list, name, matchers: dict) -> np.ndarray:
+    """Row indices of the series the selector matches.  A matcher is
+    decided once for each distinct value of its label, not once a
+    series."""
+    if name is not None:
+        matchers = dict(matchers, __name__=name)
+    keep = np.ones(len(labels), dtype=bool)
+    for label, want in matchers.items():
+        values = [l.get(label, "") for l in labels]
+        if isinstance(want, tuple):
+            rx = re.compile(want[1])
+            ok = {v for v in set(values) if rx.fullmatch(v)}
+        else:
+            ok = {want}
+        keep &= np.fromiter((v in ok for v in values), bool, len(labels))
+    return np.flatnonzero(keep).astype(np.int64)
+
+
+def by_groups(by: tuple, labels: list) -> dict:
+    """{group's labels as ((label, value), ...): [rows]} of `... by (by)`,
+    in order of first appearance."""
+    groups = {}
+    for i, l in enumerate(labels):
+        groups.setdefault(tuple((k, l[k]) for k in by if k in l),
+                          []).append(i)
+    return groups
+
+
+def le_groups(labels: list) -> dict:
+    """{what is left of a bucket row's labels without `le` and the name:
+    [(le, row)]}: the groups histogram_quantile answers one row for."""
+    groups = {}
+    for i, l in enumerate(labels):
+        rest = tuple(sorted((k, v) for k, v in l.items()
+                            if k not in ("le", "__name__")))
+        groups.setdefault(rest, []).append((float(l["le"]), i))
+    return groups
+
+
+def _rollup_labels(func: str, labels: list, idx) -> list:
+    keep = func in KEEP_NAME
+    return [{k: v for k, v in labels[i].items() if keep or k != "__name__"}
+            for i in idx]
+
+
+def row_labels(ast, labels: list) -> list:
+    """The label set of every row `ast` can answer (all the candidates
+    of a topk), without a sample read: what `evaluate` returns as its
+    labels."""
+    op = ast[0]
+    if op == "rollup":
+        return _rollup_labels(ast[1], labels, select(labels, ast[2], ast[3]))
+    sub = row_labels(ast[-1], labels)
+    if op in AGGREGATES:
+        return [dict(k) for k in by_groups(ast[1], sub)]
+    if op == "hq":
+        return [dict(k) for k in le_groups(sub)]
+    return sub
 
 
 def _counts_le(ts: np.ndarray, marks: np.ndarray) -> np.ndarray:
@@ -151,13 +243,18 @@ def rollup(func: str, ts: np.ndarray, vals: np.ndarray, grid: np.ndarray,
     have = hi > lo
     rows = np.arange(ts.shape[0])[:, None]
     last = np.maximum(hi - 1, 0)
-    if func == "max_over_time":
-        out = np.full(hi.shape, -np.inf)
+    if func in ("max_over_time", "avg_over_time"):
+        # the window's samples one at a time, oldest first: a plain loop's
+        # own order of summation
+        fold, out = (np.maximum, np.full(hi.shape, -np.inf)) \
+            if func == "max_over_time" else (np.add, np.zeros(hi.shape))
         for k in range(int((hi - lo).max(initial=0))):
             at = lo + k
             ok = at < hi
-            out = np.where(ok, np.maximum(
+            out = np.where(ok, fold(
                 out, vals[rows, np.minimum(at, ts.shape[1] - 1)]), out)
+        if func == "avg_over_time":
+            out = out / np.maximum(hi - lo, 1)
         return np.where(have, out, np.nan)
     if func == "rate":
         v = _remove_resets(vals)
@@ -173,9 +270,17 @@ def rollup(func: str, ts: np.ndarray, vals: np.ndarray, grid: np.ndarray,
     raise ValueError(f"the reference does not know {func!r}")
 
 
-def _nansum(x: np.ndarray) -> np.ndarray:
-    some = ~np.isnan(x).all(axis=0)
-    return np.where(some, np.nansum(x, axis=0), np.nan)
+def _aggregate(op: str, x: np.ndarray) -> np.ndarray:
+    """[M, T] -> [T]: members that are NaN at a step do not count there,
+    and a step at which every member is NaN answers NaN."""
+    n = (~np.isnan(x)).sum(axis=0)
+    if op == "max":
+        out = np.where(np.isnan(x), -np.inf, x).max(axis=0)
+    else:
+        out = np.nansum(x, axis=0)
+        if op == "avg":
+            out = out / np.maximum(n, 1)
+    return np.where(n > 0, out, np.nan)
 
 
 def _histogram_quantile(phi: float, les: np.ndarray, m: np.ndarray
@@ -212,29 +317,19 @@ def evaluate(ast, labels: list, ts: np.ndarray, vals: np.ndarray,
         out = rollup(func, ts[idx], vals[idx], grid, window)
         if round_rollup is not None:
             out = round_rollup(out)
-        keep = func in KEEP_NAME
-        return "rows", [{k: v for k, v in labels[i].items()
-                         if keep or k != "__name__"} for i in idx], out
+        return "rows", _rollup_labels(func, labels, idx), out
     kind, sub_labels, sub = evaluate(ast[-1], labels, ts, vals, grid,
                                      round_rollup)
     if kind != "rows":
         raise ValueError("the reference nests nothing over topk")
-    if op == "sum":
-        groups = {}
-        for i, l in enumerate(sub_labels):
-            groups.setdefault(tuple((k, l[k]) for k in ast[1] if k in l),
-                              []).append(i)
-        keys = list(groups)
-        return "rows", [dict(k) for k in keys], \
-            np.stack([_nansum(sub[groups[k]]) for k in keys])
+    if op in AGGREGATES:
+        groups = by_groups(ast[1], sub_labels)
+        return "rows", [dict(k) for k in groups], \
+            np.stack([_aggregate(op, sub[rows]) for rows in groups.values()])
     if op == "topk":
         return f"topk:{ast[1]}", sub_labels, sub
     if op == "hq":
-        groups = {}
-        for i, l in enumerate(sub_labels):
-            rest = tuple(sorted((k, v) for k, v in l.items()
-                                if k not in ("le", "__name__")))
-            groups.setdefault(rest, []).append((float(l["le"]), i))
+        groups = le_groups(sub_labels)
         keys = list(groups)
         rows = []
         for k in keys:

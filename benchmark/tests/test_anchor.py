@@ -114,3 +114,50 @@ def test_a_seed_gives_the_same_values_at_any_anchor():
     np.testing.assert_array_equal(a.ts - a.t_start, b.ts - b.t_start)
     ta, tb = a.advance(), b.advance()
     np.testing.assert_array_equal(ta[1], tb[1])
+
+
+# ---- PR 37: a configuration that never ingests, and the four that do
+
+def test_a_configuration_that_never_ingests_keeps_no_room():
+    step, reach = 3_600_000, 27 * H
+    now = at(2026, 11, 15, 9, 0)
+    newest, latest = harness.anchor(now, step, reach, ingests=False)
+    assert newest == latest == now - harness.WALL_MARGIN_MS
+    # with the ticks' room an hour's step does not fit a month
+    with pytest.raises(ValueError, match="calendar month"):
+        harness.anchor(now, step, reach)
+    # a month's end still moves the whole span back, never forward
+    for hours in range(0, 24 * 40):
+        now = at(2026, 10, 20, 0, 0) + hours * H + 7 * 60_000
+        newest, latest = harness.anchor(now, step, reach, ingests=False)
+        assert newest == latest <= now - harness.WALL_MARGIN_MS
+        assert month(newest - reach) == month(latest)
+
+
+# now -> `latest` as PR 35's anchor gave it: ten minutes back, or a
+# month's end less a step; `newest` 2870 steps under it
+PARENT_ANCHORS = {
+    at(2026, 11, 15, 9, 0): at(2026, 11, 15, 8, 50),
+    at(2026, 11, 1, 3, 0): at(2026, 10, 31, 23, 59),
+    at(2027, 1, 2, 23, 59): at(2026, 12, 31, 23, 59),
+}
+
+
+@pytest.mark.parametrize("config", ["dash32k", "dash8k", "histo8k",
+                                    "dash32k-4chip"])
+@pytest.mark.parametrize("now", sorted(PARENT_ANCHORS))
+def test_the_four_ingesting_configurations_are_anchored_as_before(config,
+                                                                  now):
+    cfg = harness.load_json(BENCH, "configs", config + ".json")
+    assert "ingests" not in cfg
+    n_samples = int(cfg["range_h"] * H) // int(cfg["scrape_interval_s"] * 1000)
+    span = (n_samples - 1) * int(cfg["scrape_interval_s"] * 1000)
+    reach = span + 60_000 + int(cfg["jitter_s"] * 1000)
+    room = (harness.WINDOW_TICKS + harness.SETUP_STEPS) * 60_000
+    newest, latest = harness.anchor(now, 60_000, reach)
+    assert (newest, latest) == harness.anchor(now, 60_000, reach, True)
+    assert latest == PARENT_ANCHORS[now] and newest == latest - room
+    small_cfg = small(config if config != "dash32k-4chip" else "dash32k")
+    data = harness.Dataset(small_cfg, 11, now)
+    assert data.latest == latest
+    assert data.t_start == (newest - span) // 60_000 * 60_000

@@ -42,7 +42,7 @@ def drive(config: str, mix_name: str, seed: int = 3_000_000_019):
 
 
 CELLS = [("dash8k", "refresh"), ("dash8k", "explore"),
-         ("dash32k", "refresh")]
+         ("dash32k", "refresh"), ("dash8k", "explore_live")]
 
 
 @pytest.mark.parametrize("config,mix", CELLS)
@@ -84,8 +84,9 @@ def test_an_altered_answer_is_not_correct(monkeypatch):
     assert result["checks"]["rel_err"]["value"] > 5e-4
 
 
-@pytest.mark.parametrize("config", ["dash8k", "dash32k"])
-def test_a_dropped_import_is_not_correct(monkeypatch, config):
+@pytest.mark.parametrize("config,mix", [
+    ("dash8k", "refresh"), ("dash32k", "refresh"), ("dash8k", "explore_live")])
+def test_a_dropped_import_is_not_correct(monkeypatch, config, mix):
     """The server acknowledges the window's imports and stores none: its
     state stays as the warm-up left it."""
     sound = harness.Server.post
@@ -97,7 +98,7 @@ def test_a_dropped_import_is_not_correct(monkeypatch, config):
             return None
         return sound(self, path, body)
     monkeypatch.setattr(harness.Server, "post", dropping)
-    result = drive(config, "refresh")
+    result = drive(config, mix)
     assert len(posts) > 4
     assert not result["correct"], result["checks"]
 
