@@ -73,35 +73,15 @@ class HTTPStatus(Exception):
         self.status = status
 
 
-class Server:
-    """vmsingle in this process: what apps/vmsingle.main() builds with
-    -search.tpuBackend, served from a thread on a loopback port."""
+class Client:
+    """The client's side: one connection to the server, opened at the
+    first call and kept, as a dashboard keeps its own.  `ticker` asks
+    through the one `Server` is; a storm's helper makes one a client."""
 
-    def __init__(self, data_dir: str):
-        from victoriametrics_tpu.apps import vmsingle
-        from victoriametrics_tpu.utils import logger
-        args = vmsingle.parse_flags([
-            f"-storageDataPath={data_dir}", "-httpListenAddr=127.0.0.1:0",
-            "-search.tpuBackend", "-search.maxQueryDuration=300s"])
-        logger.set_level(args.loggerLevel)
-        # build() attaches the device engine before it returns, or raises
-        self.storage, self.srv, self.api = vmsingle.build(args)
-        if self.api.tpu is None:
-            raise RuntimeError("device engine not attached")
-        self.srv.start()
-        self.connect("127.0.0.1", self.srv.port)
-
-    def connect(self, host: str, port: int) -> None:
-        """The client's side: one connection, opened at the first call
-        and kept, as a dashboard keeps its own."""
+    def __init__(self, host: str, port: int):
         self.srv_addr = (host, port)
         self.conn = None
         self.connects = 0
-
-    def stop(self):
-        self.hang_up()
-        self.srv.stop()
-        self.storage.close()
 
     def call(self, method: str, path: str, body=None) -> bytes:
         """One request over the one kept connection (HTTP/1.1,
@@ -163,6 +143,34 @@ class Server:
             return self.get("/api/v1/query_range", **params)
         except HTTPStatus:
             return b""
+
+
+class Server(Client):
+    """vmsingle in this process: what apps/vmsingle.main() builds with
+    -search.tpuBackend, served from a thread on a loopback port, and the
+    harness's own client of it.  `flags` are the configuration's own
+    (`server_flags` in its file: what the deployment it stands for starts
+    vmsingle with), after the four every run passes."""
+
+    def __init__(self, data_dir: str, flags=()):
+        from victoriametrics_tpu.apps import vmsingle
+        from victoriametrics_tpu.utils import logger
+        args = vmsingle.parse_flags([
+            f"-storageDataPath={data_dir}", "-httpListenAddr=127.0.0.1:0",
+            "-search.tpuBackend", "-search.maxQueryDuration=300s",
+            *flags])
+        logger.set_level(args.loggerLevel)
+        # build() attaches the device engine before it returns, or raises
+        self.storage, self.srv, self.api = vmsingle.build(args)
+        if self.api.tpu is None:
+            raise RuntimeError("device engine not attached")
+        self.srv.start()
+        super().__init__("127.0.0.1", self.srv.port)
+
+    def stop(self):
+        self.hang_up()
+        self.srv.stop()
+        self.storage.close()
 
 
 # A refresh mix moves simulated time one query step a tick, some hundred
@@ -227,7 +235,10 @@ class Dataset:
     """The deployment's samples, anchored behind the wall clock by
     `anchor` (a literal timestamp would sooner or later fall out of
     retention), and the grid the queries walk.  Keeps every sample it
-    handed out: the reference reads them, never the program's storage."""
+    handed out: the reference reads them, never the program's storage.
+    Where the configuration states a dedup interval (`dedup_interval_s`:
+    the server is started with -dedup.minScrapeInterval), a query sees
+    the survivors of what was handed out (`visible`), and only there."""
 
     def __init__(self, cfg: dict, seed: int, now_ms: int):
         self.cfg = cfg
@@ -235,6 +246,7 @@ class Dataset:
         self.window = int(cfg["window_s"] * 1000)
         self.scrape = int(cfg["scrape_interval_s"] * 1000)
         n_samples = int(cfg["range_h"] * 3_600_000) // self.scrape
+        self.dedup = int(cfg.get("dedup_interval_s", 0) * 1000)
         jitter = int(cfg["jitter_s"] * 1000)
         if jitter >= self.step:
             raise ValueError("jitter_s has to lie under query_step_s")
@@ -316,17 +328,29 @@ class Dataset:
         once (row r's times shifted by r << 42, as reference._counts_le
         shifts them): a refresh cell's every tick is another range, and a
         pass over 47 M timestamps a tick cost a traced run of 190 ticks
-        four minutes."""
+        four minutes.  Under a dedup interval the SURVIVORS are counted:
+        what the query needs read, not what the replicas wrote.  The flat
+        array then holds the bulk's survivors but each row's newest,
+        whose window the first tail may go on filling: that one is
+        counted with the tails, of which each window's newest counts (no
+        sample is older than one handed out before it)."""
         if self._flat is None:
             rows = np.arange(self.ts.shape[0], dtype=np.int64)[:, None]
-            self._flat = (self.ts + (rows << 42)).ravel()
+            flat = self.ts + (rows << 42)
+            if self.dedup:
+                closed = reference.newest_of_window(self.ts, self.dedup)
+                closed[:, -1] = False
+                flat = flat[closed]
+            self._flat = flat.ravel()
         off = idx.astype(np.int64) << 42
         n = int((np.searchsorted(self._flat, hi + off, side="right") -
                  np.searchsorted(self._flat, lo + off, side="right")).sum())
-        for ts, _ in self.tails[:n_tails]:
-            sub = ts[idx]
-            n += int(((sub > lo) & (sub <= hi)).sum())
-        return n
+        tails = [ts[idx] for ts, _ in self.tails[:n_tails]]
+        if self.dedup:
+            sub = np.concatenate([self.ts[idx, -1:]] + tails, axis=1)
+            keep = reference.newest_of_window(sub, self.dedup)
+            return n + int((keep & (sub > lo) & (sub <= hi)).sum())
+        return n + sum(int(((sub > lo) & (sub <= hi)).sum()) for sub in tails)
 
     def snapshot(self, n_tails: int):
         """Every sample handed out up to the n_tails-th tail."""
@@ -335,6 +359,14 @@ class Dataset:
             return self.ts, self.vals
         return (np.concatenate([self.ts] + [t for t, _ in tails], axis=1),
                 np.concatenate([self.vals] + [v for _, v in tails], axis=1))
+
+    def visible(self, n_tails: int):
+        """What a query may see of `snapshot(n_tails)`: all of it, or
+        under a dedup interval each row's survivors (reference.dedup)."""
+        ts, vals = self.snapshot(n_tails)
+        if self.dedup:
+            ts, vals = reference.dedup_rows(ts, vals, self.dedup)
+        return ts, vals
 
 
 def load_columnar(server: Server, data: Dataset, ts: np.ndarray,
@@ -364,7 +396,7 @@ def check_answers(data: Dataset, records: list, round_rollup=None) -> dict:
     results = []
     unreadable = 0
     for r in records:
-        ts, vals = data.snapshot(r["n_tails"])
+        ts, vals = data.visible(r["n_tails"])
         # a query's own step where its record holds one (traffic/
         # intervals.py), else the configuration's
         step = r.get("step", data.step)

@@ -25,6 +25,12 @@ aggr.go and transform.go):
   bucket that holds rank phi * total, the lowest bucket starting at 0 and
   the +Inf bucket answering the highest finite bound.
 
+`dedup` is upstream's deduplication (lib/storage/dedup.go, recalled), for a
+configuration that states `-dedup.minScrapeInterval`: of one series' sorted
+samples, one survivor a window of the interval, windows right-inclusive at
+exact multiples of it; `dedup_rows` makes the dense rows `evaluate` reads
+from the survivors.
+
 `round_rollup` is the CONTROL's hook: a function applied to every rollup
 output before anything else sees it (the control rounds it to bfloat16,
 what the MXU's default precision does to the operands of the group sum).
@@ -339,6 +345,58 @@ def evaluate(ast, labels: list, ts: np.ndarray, vals: np.ndarray,
                 sub[[i for _, i in members]]))
         return "rows", [dict(k) for k in keys], np.stack(rows)
     raise ValueError(f"the reference does not know {op!r}")
+
+
+def newest_of_window(ts: np.ndarray, interval_ms: int) -> np.ndarray:
+    """Sorted rows [..., N] -> bool [..., N]: the places that hold their
+    dedup window's newest sample (of equal timestamps the last place).  A
+    sample belongs to window ceil(ts / interval)."""
+    window = -(-ts // interval_ms)
+    out = np.ones(ts.shape, dtype=bool)
+    out[..., :-1] = window[..., 1:] != window[..., :-1]
+    return out
+
+
+def dedup(ts: np.ndarray, vals: np.ndarray, interval_ms: int):
+    """One row's sorted samples -> its survivors (ts, vals).  A sample
+    belongs to window ceil(ts / interval): the window (m - 1, m] x
+    interval, so a sample exactly on a multiple closes its own.  Of a
+    window the sample with the highest timestamp stays; of several at
+    that timestamp, the largest value.  (No staleness marker occurs in
+    the benchmark's data; upstream prefers any value over one.)"""
+    ts = np.asarray(ts, dtype=np.int64)
+    vals = np.asarray(vals, dtype=np.float64)
+    if ts.size == 0:
+        return ts, vals
+    last = np.flatnonzero(newest_of_window(ts, interval_ms))
+    out_ts, out_vals = ts[last], vals[last].copy()
+    first = np.append(0, last[:-1] + 1)
+    # a tie at a window's newest timestamp: rare, so one at a time
+    for i in np.flatnonzero((last > first) & (ts[last] == ts[last - 1])):
+        a, b = first[i], last[i] + 1
+        out_vals[i] = vals[a:b][ts[a:b] == ts[b - 1]].max()
+    return out_ts, out_vals
+
+
+PAD_TS = 0
+
+
+def dedup_rows(ts: np.ndarray, vals: np.ndarray, interval_ms: int):
+    """[S, N] sorted rows -> ([S, M], [S, M]): every row's survivors,
+    right-aligned, M the most any row keeps.  A shorter row is filled
+    from the left with PAD_TS (older than any window a query reaches, and
+    more than any maxPrevInterval under the first real sample) and the
+    row's own oldest value (no reset, no increase): rows stay sorted and
+    no rollup here ever reads a filled place."""
+    rows = [dedup(t, v, interval_ms) for t, v in zip(ts, vals)]
+    width = max(t.size for t, _ in rows)
+    out_ts = np.full((len(rows), width), PAD_TS, dtype=np.int64)
+    out_vals = np.empty((len(rows), width), dtype=np.float64)
+    for i, (t, v) in enumerate(rows):
+        out_ts[i, width - t.size:] = t
+        out_vals[i, width - v.size:] = v
+        out_vals[i, :width - v.size] = v[0] if v.size else 0.0
+    return out_ts, out_vals
 
 
 def to_bfloat16(x: np.ndarray) -> np.ndarray:

@@ -67,7 +67,7 @@ def set_up(cfg: dict, mix: dict, seed: int, data_dir: str, annotate=None):
                          "served fetch path would be another program")
     split = {}
     t = time.perf_counter()
-    server = harness.Server(data_dir)
+    server = harness.Server(data_dir, cfg.get("server_flags", []))
     split["server"] = time.perf_counter() - t
     t = time.perf_counter()
     data = harness.Dataset(cfg, seed, int(time.time() * 1000))

@@ -41,6 +41,7 @@ def small(config: str) -> dict:
     cpu-max-all-8 query draws eight)."""
     cfg = harness.load_json(harness.ROOT, CONFIGS[config]["file"])
     sizes = {"counters": dict(series=64, instances=8, jobs=4),
+             "counters_ha": dict(series=64, instances=8, jobs=4),
              "histogram": dict(series=96, instances=8, jobs=4),
              "tsbs_cpu": dict(hosts=8)}
     cfg.update(sizes[cfg["deployment"]])

@@ -1,4 +1,4 @@
-"""The client's one kept connection (harness.Server's client side),
+"""The client's one kept connection (harness.Client),
 against a stub HTTP/1.1 server that counts what it accepts."""
 
 import threading
@@ -59,8 +59,7 @@ def stub():
     srv = Stub()
     thread = threading.Thread(target=srv.serve_forever, daemon=True)
     thread.start()
-    client = harness.Server.__new__(harness.Server)     # no vmsingle
-    client.connect(*srv.server_address)
+    client = harness.Client(*srv.server_address)
     try:
         yield srv, client
     finally:
@@ -103,7 +102,7 @@ def test_a_refusal_is_an_empty_answer_and_keeps_the_connection(stub):
     with pytest.raises(harness.HTTPStatus) as e:
         client.get("/refuse")
     assert e.value.status == 422
-    client.get = lambda path, **params: harness.Server.get(
+    client.get = lambda path, **params: harness.Client.get(
         client, "/refuse", **params)
     assert client.query_range("up", 0, 60_000, 60_000, True) == b""
     assert srv.accepts == 1

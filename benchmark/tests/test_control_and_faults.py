@@ -8,7 +8,12 @@ size a test run can hold, against the real server:
 * an answer altered where it is produced reads not correct;
 * an acknowledged import that the server then drops (its state left
   unchanged under a tick) reads not correct: the newest step is missing
-  or stale.
+  or stale;
+* the HA pair's server started WITHOUT its dedup flag, or the reference's
+  rule for equal timestamps flipped, reads not correct;
+* an answer of the mix `flood` (generator `storm`, eight clients; no
+  cell runs it yet) altered where the harness takes it from its helper
+  reads not correct.
 """
 
 import glob
@@ -42,7 +47,8 @@ def drive(config: str, mix_name: str, seed: int = 3_000_000_019):
 
 
 CELLS = [("dash8k", "refresh"), ("dash8k", "explore"),
-         ("dash32k", "refresh"), ("dash8k", "explore_live")]
+         ("dash32k", "refresh"), ("dash8k", "explore_live"),
+         ("dash8k-ha2", "refresh"), ("dash8k", "flood")]
 
 
 @pytest.mark.parametrize("config,mix", CELLS)
@@ -85,7 +91,8 @@ def test_an_altered_answer_is_not_correct(monkeypatch):
 
 
 @pytest.mark.parametrize("config,mix", [
-    ("dash8k", "refresh"), ("dash32k", "refresh"), ("dash8k", "explore_live")])
+    ("dash8k", "refresh"), ("dash32k", "refresh"), ("dash8k", "explore_live"),
+    ("dash8k-ha2", "refresh")])
 def test_a_dropped_import_is_not_correct(monkeypatch, config, mix):
     """The server acknowledges the window's imports and stores none: its
     state stays as the warm-up left it."""
@@ -101,6 +108,83 @@ def test_a_dropped_import_is_not_correct(monkeypatch, config, mix):
     result = drive(config, mix)
     assert len(posts) > 4
     assert not result["correct"], result["checks"]
+
+
+def test_the_ha_pair_without_its_dedup_flag_is_not_correct(monkeypatch):
+    """The reference dedups, the server keeps both replicas' samples: the
+    rate's first and last sample are others, and the window's newest
+    step may hold a sample the reference dropped."""
+    sound = harness.Server.__init__
+    started = []
+
+    def without(self, data_dir, flags=()):
+        started.append(list(flags))
+        sound(self, data_dir)
+    monkeypatch.setattr(harness.Server, "__init__", without)
+    result = drive("dash8k-ha2", "refresh")
+    assert started == [["-dedup.minScrapeInterval=15s"]]
+    assert not result["correct"], result["checks"]
+    assert result["checks"]["rel_err"]["value"] > 10 * 5e-5
+
+
+def test_the_tie_rule_flipped_is_not_correct(monkeypatch):
+    """Of two samples at a window's newest timestamp the reference keeps
+    the SMALLER value: the server, which keeps the larger as upstream
+    does, then reads not correct, so the comparison holds the rule."""
+    sound = reference.dedup
+
+    def flipped(ts, vals, interval_ms):
+        out_ts, out_vals = sound(ts, vals, interval_ms)
+        lo_ts, lo_vals = sound(ts, -vals, interval_ms)
+        assert (out_ts == lo_ts).all()
+        return out_ts, -lo_vals
+    monkeypatch.setattr(reference, "dedup", flipped)
+    result = drive("dash8k-ha2", "refresh")
+    assert not result["correct"], result["checks"]
+
+
+def test_a_floods_altered_answer_is_not_correct(monkeypatch):
+    storm = harness.load_module("traffic", "storm")
+    sound = storm.recv
+
+    def altered(pipe):
+        got = sound(pipe)
+        if isinstance(got, dict) and got.get("kept"):
+            i, body = got["kept"][0]
+            doc = json.loads(body)
+            row = doc["data"]["result"][0]["values"]
+            row[len(row) // 2][1] = repr(float(row[len(row) // 2][1]) * 1.001)
+            got["kept"][0] = (i, json.dumps(doc, separators=(",", ":")).encode())
+        return got
+    monkeypatch.setattr(storm, "recv", altered)
+    monkeypatch.setattr(harness, "load_module",
+                        lambda kind, name, _load=harness.load_module:
+                        storm if (kind, name) == ("traffic", "storm")
+                        else _load(kind, name))
+    result = drive("dash8k", "flood")
+    assert not result["correct"]
+    assert result["checks"]["rel_err"]["value"] > 5e-4
+
+
+@pytest.mark.parametrize("cell", ["dash8k-ha2.refresh"])
+def test_a_traced_run_reports_every_metric_the_cell_lists(cell):
+    """A per-layer metric without a `workloads` key is due in every cell,
+    and a reader with nothing to read leaves its metric out of the line,
+    which the driver then refuses.  The device trace's metrics read
+    nothing on the CPU; every other one the cell lists has to be in a
+    traced run's line (`samples_scanned_per_s` among them: the survivors
+    counted)."""
+    import jax
+    bench = harness.load_json(os.path.dirname(BENCH), "BENCHMARK.json")
+    entry = next(w for w in bench["workloads"] if w["name"] == cell)
+    mix = harness.load_json(BENCH, "traffic", entry["traffic"] + ".json")
+    result, _ = run.measure(bench, entry, small(entry["config"]), mix,
+                            3_800_000_029, 2.0, True, jax.devices()[:1], {})
+    due = {m["name"] for m in bench["per_layer"]
+           if cell in m.get("workloads", [cell])
+           and m["source"] != "device_trace"}
+    assert due - set(result["metrics"]) == set()
+    assert result["correct"], result["checks"]
 
 
 def test_run_refuses_a_machine_without_a_tpu(capsys):

@@ -119,8 +119,11 @@ def test_a_sound_rehearsal_is_correct(mix_name):
                             False, jax.devices()[:1], {})
     assert result["correct"], result["checks"]
     assert result["attempted"] >= 2 and result["failed"] == 0
-    assert set(result["metrics"]) == {"query_p50_ms", "query_p90_ms",
-                                      "queries_per_s", "setup_s"}
+    # the percentiles are end to end in `refresh` alone (PERF.md section 2)
+    percentiles = {"query_p50_ms", "query_p90_ms"} \
+        if mix_name == "refresh" else set()
+    assert set(result["metrics"]) == \
+        percentiles | {"queries_per_s", "setup_s"}
     # 1 row, or 4 jobs' rows, of the range's steps in each checked answer
     rows = 1 if mix_name == "refresh" else 4
     assert result["checks"]["values"]["value"] >= rows * 40

@@ -103,6 +103,22 @@ def test_the_tail_is_end_to_end_only_where_it_is_steady():
                if m["name"] == "query_tail_p90_ms"]
     big = [w["name"] for w in BENCHMARK["workloads"]
            if w["config"].startswith("dash32k")] + ["dash8k.explore_live"]
+    # ... and in dash8k-ha2.refresh, whose window holds six queries of
+    # 9.5 s (PR 38), which reports no percentile but its median
+    big.append("dash8k-ha2.refresh")
+    # ... and in histo8k.services, whose percentiles the check of PR 38
+    # read 11-23 % apart from run to run on its machines, where one call
+    # on one machine reads them within 1-3 %: its median stands per layer
+    # beside the tail, as query_mid_p50_ms
+    big.append("histo8k.services")
+    (mid,) = [m for m in BENCHMARK["per_layer"]
+              if m["name"] == "query_mid_p50_ms"]
+    (p50,) = [m for m in BENCHMARK["end_to_end"]
+              if m["name"] == "query_p50_ms"]
+    assert mid["workloads"] == ["histo8k.services"]
+    assert "histo8k.services" not in p50["workloads"]
+    assert (mid["source"], mid["unit"], mid["moves"]) == \
+        (tail["source"], p50["unit"], tail["moves"])
     assert sorted(tail["workloads"]) == sorted(big)
     assert sorted(p90["workloads"]) == sorted(set(CELLS) - set(big))
     assert tail["source"] == "host_clock" and tail["unit"] == p90["unit"]
@@ -114,6 +130,11 @@ def test_the_tail_is_end_to_end_only_where_it_is_steady():
         spec["args"], {"by_template": lats})
     assert got == pytest.approx(
         1e3 * stats.percentile([x for ls in lats.values() for x in ls], 90))
+    spec = harness.load_json(BENCH, "layers", "query_mid_p50_ms.json")
+    got = harness.load_module("readers", spec["reader"]).read(
+        spec["args"], {"by_template": lats})
+    assert got == pytest.approx(
+        1e3 * stats.percentile([x for ls in lats.values() for x in ls], 50))
 
 
 def test_query_work_counts_what_a_mask_over_every_sample_counts():

@@ -32,11 +32,12 @@ def producer():
 
 
 @pytest.mark.parametrize("seed", [5, 3_500_000_029])
-@pytest.mark.parametrize("config", ["dash8k", "histo8k"])
+@pytest.mark.parametrize("config", ["dash8k", "histo8k", "dash8k-ha2"])
 def test_the_helpers_ticks_equal_the_in_process_twins(config, seed):
     cfg = small(config)
     assert cfg["deployment"] == {"dash8k": "counters",
-                                 "histo8k": "histogram"}[config]
+                                 "histo8k": "histogram",
+                                 "dash8k-ha2": "counters_ha"}[config]
     twin = harness.Dataset(cfg, seed, NOW)
     data = harness.Dataset(cfg, seed, NOW)
     twin.advance(PREROLL)

@@ -313,6 +313,6 @@ def test_every_text_of_the_cells_parses_to_its_present_tuples():
             for text in texts:
                 assert reference.parse(text) == want[text], text
                 seen += 1
-    # four refresh cells and services of one text, explore's 4 x 17,
+    # five refresh cells and services of one text, explore's 4 x 17,
     # explore_live's 3 x 17
-    assert seen == 5 + 4 * 17 + 3 * 17
+    assert seen == 6 + 4 * 17 + 3 * 17
