@@ -45,6 +45,20 @@ def _random_starts(rng, n):
     return np.unique(starts).astype(np.int64)
 
 
+def _pow10_neighbours(rng):
+    """10^k and the floats an ulp, 1e-10 and 2e-9 either side (where
+    floor(log10) turns, and the native code hands the call back to log10),
+    and the integers 10^k - 1, 10^k + 1 (where a mantissa's headroom
+    turns), of both signs."""
+    p = 10.0 ** np.arange(-300, 301)
+    near = [p, np.nextafter(p, 0), np.nextafter(p, np.inf)]
+    near += [p * (1 + f) for f in (1e-10, -1e-10, 2e-9, -2e-9)]
+    ints = 10 ** np.arange(1, 18, dtype=np.int64)
+    near += [(ints - 1).astype(np.float64), (ints + 1).astype(np.float64)]
+    v = np.concatenate(near)
+    return v * np.where(rng.random(v.size) < .5, -1, 1)
+
+
 CASES = {
     "counters": lambda rng: np.cumsum(
         rng.integers(0, 50, 4000)).astype(np.float64),
@@ -55,6 +69,7 @@ CASES = {
     * np.where(rng.random(2000) < .5, -1, 1),
     "large_base_counters": lambda rng: 1e15 + np.cumsum(
         rng.integers(0, 3, 3000)).astype(np.float64),
+    "powers_of_ten": _pow10_neighbours,
 }
 
 

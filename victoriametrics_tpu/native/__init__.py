@@ -135,6 +135,12 @@ def _configure(lib):
     lib.vm_counter_resets_2d.argtypes = [pf64, i64, i64, pf64]
     lib.vm_f2d_grouped.restype = None
     lib.vm_f2d_grouped.argtypes = [pf64, pi64, i64, i64, pi64, pi64]
+    lib.vm_pack_delta_planes.restype = i64
+    lib.vm_pack_delta_planes.argtypes = [pi64, pi64, pf64, pi64, i64, i64,
+                                         i64, ctypes.c_int32, i64,
+                                         ctypes.c_double, pi32, pi32, pi32,
+                                         pi32, pi32, pi32, pi64, pi64, pf64,
+                                         pi32]
     lib.vm_rollup_counter_2d.restype = None
     lib.vm_rollup_counter_2d.argtypes = [pi64, pf64, pi64, i64, i64, i64,
                                          i64, i64, i64, pi64,
@@ -459,6 +465,51 @@ def f2d_grouped(values: np.ndarray, starts: np.ndarray):
         _as_i64_ptr(st), st.size, v.size, _as_i64_ptr(m_out),
         _as_i64_ptr(exps))
     return m_out, exps
+
+
+def pack_delta_planes(ts: np.ndarray, m: np.ndarray, starts: np.ndarray,
+                      start_ms: int, rebase: bool, width: int,
+                      vals: np.ndarray | None = None, gate: float = 0.0):
+    """The cold tile build's delta planes for S rows in one GIL-released
+    pass (vm_pack_delta_planes; ops/device_decode._pack_py is its NumPy
+    twin): row i is ts/m[starts[i]:starts[i+1]] (int64, contiguous).
+    Returns None when the library is missing, else (planes, v0, risky):
+    planes = (ts_first, ts_fdelta, ts_d2 [S, width], val_first,
+    val_fdelta, val_d2, ts_max, val_max), all int32 but the int64 row
+    maxima of |d2|, or None where a row is empty or needs more than int32;
+    with `vals` (f32 tiles) v0 [S] and risky are the rebase gates at
+    `gate`, else None and False."""
+    lib = _load()
+    if lib is None:
+        return None
+    ts = np.ascontiguousarray(ts, dtype=np.int64)
+    m = np.ascontiguousarray(m, dtype=np.int64)
+    starts = np.ascontiguousarray(starts, dtype=np.int64)
+    if vals is not None:
+        vals = np.ascontiguousarray(vals, dtype=np.float64)
+    if m.size != ts.size or (vals is not None and vals.size != ts.size):
+        raise ValueError("pack_delta_planes: ts, m and vals differ in size")
+    S = int(starts.size)
+    pi32 = ctypes.POINTER(ctypes.c_int32)
+    vec = [np.empty(S, np.int32) for _ in range(4)]
+    d2 = [np.empty((S, width), np.int32) for _ in range(2)]
+    mx = [np.empty(S, np.int64) for _ in range(2)]
+    v0 = np.empty(S, np.float64) if vals is not None else None
+    risky = ctypes.c_int32(0)
+    r = lib.vm_pack_delta_planes(
+        _as_i64_ptr(ts), _as_i64_ptr(m),
+        None if vals is None else
+        vals.ctypes.data_as(ctypes.POINTER(ctypes.c_double)),
+        _as_i64_ptr(starts), S, int(ts.size), int(start_ms),
+        1 if rebase else 0, int(width), float(gate),
+        *(a.ctypes.data_as(pi32) for a in vec + d2),
+        *(_as_i64_ptr(a) for a in mx),
+        None if v0 is None else
+        v0.ctypes.data_as(ctypes.POINTER(ctypes.c_double)),
+        ctypes.byref(risky))
+    planes = None if r else (vec[0], vec[1], d2[0], vec[2], vec[3], d2[1],
+                             mx[0], mx[1])
+    return planes, v0, bool(risky.value)
 
 
 def clip_blocks(ts: np.ndarray, bstart: np.ndarray, bend: np.ndarray,

@@ -841,6 +841,22 @@ static inline double vm_scale_up(double x, int64_t e) {
     return x * vm_pow10d(e1) * vm_pow10d(e - e1);
 }
 
+// floor(log10(x)) to the bit of the libm call, without it where it cannot
+// matter: x is placed between two entries of the table, and only where it
+// lies within a relative 1e-9 of one (far beyond log10's few-ulp error
+// and the recurrence-built table's own) does log10 itself decide. Outside
+// [1e-300, 1e300] it always does.
+static inline int64_t vm_floor_log10(double x) {
+    if (!(x >= 1e-300 && x <= 1e300)) return (int64_t)floor(log10(x));
+    const double* t = vm_pow10_table() + VM_POW10_MAX;
+    int64_t k = ((int64_t)ilogb(x) * 1233) >> 12;  // ~ log10(2) x log2(x)
+    while (x < t[k]) k--;
+    while (x >= t[k + 1]) k++;
+    if (x < t[k] * (1.0 + 1e-9) || x > t[k + 1] * (1.0 - 1e-9))
+        return (int64_t)floor(log10(x));
+    return k;
+}
+
 static void vm_f2d_decompose(double v, int64_t exp10, int digits,
                              int64_t* mo, int64_t* eo) {
     int64_t ei = exp10 - (digits - 1);
@@ -874,11 +890,11 @@ static inline void vm_f2d_elem(double x, int64_t* m, int64_t* e,
     if (x == 0.0) { *kind = VM_K_ZERO; return; }
     *kind = VM_K_NORM;
     double ax = fabs(x);
-    int64_t exp10 = (int64_t)floor(log10(ax));
     if (x == floor(x) && ax <= (double)VM_F2D_MAX_MANTISSA) {
         *m = (int64_t)x;
         *e = 0;
     } else {
+        int64_t exp10 = vm_floor_log10(ax);
         int64_t m15, e15;
         vm_f2d_decompose(x, exp10, 15, &m15, &e15);
         double recon = (e15 < 0) ? (double)m15 / vm_pow10d(-e15)
@@ -890,10 +906,16 @@ static inline void vm_f2d_elem(double x, int64_t* m, int64_t* e,
             vm_f2d_decompose(x, exp10, 17, m, e);
         }
     }
-    while (*m != 0 && *m % 10 == 0) {
-        *m /= 10;
-        *e += 1;
+    // strip trailing zeros, eight at a time first (a 15-digit decimal
+    // carries up to 14)
+    if (*m == 0) return;
+    while (*m % 100000000 == 0) {
+        *m /= 100000000;
+        *e += 8;
     }
+    if (*m % 10000 == 0) { *m /= 10000; *e += 4; }
+    if (*m % 100 == 0) { *m /= 100; *e += 2; }
+    if (*m % 10 == 0) { *m /= 10; *e += 1; }
 }
 
 // v[n] float64 -> m_out[n] int64 mantissas + exps_out[n_groups]; group g
@@ -919,8 +941,8 @@ void vm_f2d_grouped(const double* v, const int64_t* starts,
             if (es[i] < emin) emin = es[i];
             double absm = (double)(m_out[i] < 0 ? -m_out[i] : m_out[i]);
             if (absm < 1.0) absm = 1.0;
-            int64_t allowed_up = (int64_t)floor(
-                log10((double)VM_F2D_MAX_MANTISSA / absm));
+            int64_t allowed_up = vm_floor_log10(
+                (double)VM_F2D_MAX_MANTISSA / absm);
             int64_t fl = es[i] - allowed_up;
             if (fl > efloor) efloor = fl;
         }
@@ -950,6 +972,113 @@ void vm_f2d_grouped(const double* v, const int64_t* starts,
             }
         }
     }
+}
+
+// ---------------------------------------------------------------------------
+// cold tile staging: S rows -> the device's delta planes in one pass
+// ---------------------------------------------------------------------------
+// The native twin of ops/device_decode._pack_py (bit-identical,
+// differentially tested): row i covers ts/m[starts[i]..starts[i+1])
+// (starts[S] == n implied). Writes the int32 first and first-delta
+// vectors, the [S, w] second-difference planes (zero past a row's n - 2)
+// and each row's largest |d2| per plane. Returns 1 (planes undefined) when
+// a row is empty or any |rel| (ts - start_ms), |m|, |d1| or |d2| -- and
+// under `rebase` any |m - m[0]| -- reaches 2^31, else 0. With `vals`
+// (f32 tiles) it also computes the rebase gates over EVERY row, refused or
+// not: v0[i] = the row's first value where finite, else 0; *risky = 1 when
+// a finite |v - v0| reaches `gate`, or over the row's int32-range ("sane")
+// mantissas |m - the first sane m| does.
+
+static inline bool vm_fits_i32(int64_t x) {
+    return x > -(INT64_C(1) << 31) && x < (INT64_C(1) << 31);
+}
+
+int64_t vm_pack_delta_planes(
+    const int64_t* ts, const int64_t* m, const double* vals,
+    const int64_t* starts, int64_t S, int64_t n, int64_t start_ms,
+    int32_t rebase, int64_t w, double gate,
+    int32_t* ts_first, int32_t* ts_fd, int32_t* val_first, int32_t* val_fd,
+    int32_t* ts_d2, int32_t* val_d2, int64_t* ts_max, int64_t* val_max,
+    double* v0, int32_t* risky) {
+    bool refused = false;
+    if (vals) *risky = 0;
+    for (int64_t i = 0; i < S; i++) {
+        const int64_t a = starts[i];
+        const int64_t c = ((i + 1 < S) ? starts[i + 1] : n) - a;
+        if (vals) {
+            const double f = (c > 0 && std::isfinite(vals[a])) ? vals[a]
+                                                               : 0.0;
+            v0[i] = f;
+            for (int64_t j = a; j < a + c && !*risky; j++)
+                if (std::isfinite(vals[j]) && fabs(vals[j] - f) >= gate)
+                    *risky = 1;
+            bool have = false;
+            int64_t base = 0;
+            for (int64_t j = a; j < a + c && !*risky; j++) {
+                if (!vm_fits_i32(m[j])) continue;
+                if (!have) { base = m[j]; have = true; }
+                int64_t d = m[j] - base;
+                if ((double)(d < 0 ? -d : d) >= gate) *risky = 1;
+            }
+        }
+        if (refused) continue;
+        if (c < 1) { refused = true; continue; }
+        const int64_t* t = ts + a;
+        const int64_t* mm = m + a;
+        // ts - start_ms wraps as numpy's int64 subtraction does
+        int64_t prel = (int64_t)((uint64_t)t[0] - (uint64_t)start_ms);
+        if (!vm_fits_i32(prel) || !vm_fits_i32(mm[0])) {
+            refused = true;
+            continue;
+        }
+        ts_first[i] = (int32_t)prel;
+        val_first[i] = (int32_t)mm[0];
+        ts_fd[i] = 0;
+        val_fd[i] = 0;
+        int32_t* tr = ts_d2 + i * w;
+        int32_t* vr = val_d2 + i * w;
+        int64_t tmax = 0, vmax = 0, ptd = 0, pvd = 0;
+        for (int64_t j = 1; j < c; j++) {
+            int64_t rel = (int64_t)((uint64_t)t[j] - (uint64_t)start_ms);
+            if (!vm_fits_i32(rel) || !vm_fits_i32(mm[j]) ||
+                (rebase && !vm_fits_i32(mm[j] - mm[0]))) {
+                refused = true;
+                break;
+            }
+            int64_t td = rel - prel, vd = mm[j] - mm[j - 1];
+            if (!vm_fits_i32(td) || !vm_fits_i32(vd)) {
+                refused = true;
+                break;
+            }
+            if (j == 1) {
+                ts_fd[i] = (int32_t)td;
+                val_fd[i] = (int32_t)vd;
+            } else {
+                int64_t t2 = td - ptd, v2 = vd - pvd;
+                if (!vm_fits_i32(t2) || !vm_fits_i32(v2)) {
+                    refused = true;
+                    break;
+                }
+                tr[j - 2] = (int32_t)t2;
+                vr[j - 2] = (int32_t)v2;
+                if (t2 < 0) t2 = -t2;
+                if (v2 < 0) v2 = -v2;
+                if (t2 > tmax) tmax = t2;
+                if (v2 > vmax) vmax = v2;
+            }
+            prel = rel;
+            ptd = td;
+            pvd = vd;
+        }
+        if (refused) continue;
+        for (int64_t j = c > 2 ? c - 2 : 0; j < w; j++) {
+            tr[j] = 0;
+            vr[j] = 0;
+        }
+        ts_max[i] = tmax;
+        val_max[i] = vmax;
+    }
+    return refused ? 1 : 0;
 }
 
 // ---------------------------------------------------------------------------

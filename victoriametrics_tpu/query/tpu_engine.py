@@ -189,6 +189,13 @@ _FUSED_LAUNCHES = {
     path: metricslib.REGISTRY.counter(metricslib.format_name(
         "vm_device_fused_launches_total", {"path": path}))
     for path in ("mesh", "single")}
+# rows staged by a cold tile build, by the code that staged them: the
+# native pass, its NumPy twin where the library is missing, or the dense
+# tile where a row needs more than int32 (0 from import)
+_TILE_BUILD_ROWS = {
+    path: metricslib.REGISTRY.counter(metricslib.format_name(
+        "vm_device_tile_build_rows_total", {"path": path}))
+    for path in ("native", "python", "dense")}
 # series-axis size of the newest engine's mesh (0 until an engine is built)
 _SERIES_SHARDS = metricslib.REGISTRY.gauge("vm_device_series_shards")
 
@@ -643,7 +650,7 @@ def _dispatch_fused(engine: TPUEngine, aggr: str, func: str, tiles,
 
 def _upload_tiles(engine: TPUEngine, series, cfg: RollupConfig):
     """Cold tile build + upload.  device:tile_build is the host staging
-    (float_to_decimal, delta-plane pack, padding); the puts nest inside
+    (device_decode.stage_rows, padding); the puts nest inside
     it as device:upload phases and are charged there, not here."""
     with flightrec.phase("device:tile_build"):
         return _build_tiles(engine, series, cfg)
@@ -661,10 +668,8 @@ def _build_tiles(engine: TPUEngine, series, cfg: RollupConfig):
     leaves its device (the scatter half of the reference's
     scatter-gather)."""
     import dataclasses
+    import operator
 
-    import jax.numpy as jnp
-
-    from ..ops import decimal as dec
     from ..ops import device_decode as dd
     from ..ops.device_rollup import TS_PAD, pack_series
     from ..models.tile_cache import chunked_device_put
@@ -678,47 +683,21 @@ def _build_tiles(engine: TPUEngine, series, cfg: RollupConfig):
         return chunked_device_put(np.asarray(a))
 
     f32 = engine.is_f32()
-    v0 = risky = None
-    if f32:
-        # per-series rebase offsets, float64, HOST-resident: the affine
-        # addback and append-slice rebasing must not round through f32
-        v0 = np.array([sd.values[0] if sd.values.size and
-                       np.isfinite(sd.values[0]) else 0.0
-                       for sd in series], dtype=np.float64)
-        risky = any(
-            sd.values.size and np.isfinite(sd.values).any() and
-            float(np.nanmax(np.abs(np.where(np.isfinite(sd.values),
-                                            sd.values, v0[i]) - v0[i])))
-            >= F32_SAFE_RANGE
-            for i, sd in enumerate(series))
-    triples = []
-    for sd in series:
-        m, e = dec.float_to_decimal(sd.values)
-        triples.append((sd.timestamps, m, e))
-    if f32 and not risky:
-        # The one f32 rounding happens on the REBASED MANTISSA (the delta
-        # planes reconstruct m - m[0], then scale): with fractional scales
-        # (10^-k) the mantissa range can exceed 2^24 while the value-space
-        # gate above passes, silently costing integer exactness that
-        # equality-sensitive funcs (changes, reset classification) need.
-        # Specials (NaN/Inf sentinels ~ 2^63) can't reach here: the int32
-        # plane check below rejects them first, but mask to |m|<2^31
-        # anyway so the gate never trips on a sentinel-only artifact.
-        for _, m, _ in triples:
-            if not m.size:
-                continue
-            # range test, NOT np.abs: abs(INT64_MIN) overflows back to
-            # INT64_MIN (the V_NAN sentinel) and would pass an abs-< gate
-            sane = (m > -(2 ** 31)) & (m < 2 ** 31)
-            if not sane.any():
-                continue
-            base = m[0] if sane[0] else m[sane][0]
-            if float(np.abs(m[sane] - base).max()) >= F32_SAFE_RANGE:
-                risky = True
-                break
-    planes = dd.pack_delta_planes(triples, cfg.start,
-                                  value_dtype=engine.value_dtype,
-                                  rebase=f32)
+    ts_rows = list(map(operator.attrgetter("timestamps"), series))
+    val_rows = list(map(operator.attrgetter("values"), series))
+    # f32 tiles: per-series rebase offsets, float64, HOST-resident (the
+    # affine addback and append-slice rebasing must not round through
+    # f32), and the wide-range flag: the value-space gate, and the one on
+    # the REBASED MANTISSA (the delta planes reconstruct m - m[0], then
+    # scale: with fractional scales (10^-k) the mantissa range can exceed
+    # 2^24 while the value-space gate passes, silently costing integer
+    # exactness that equality-sensitive funcs (changes, reset
+    # classification) need)
+    staged = dd.stage_rows(ts_rows, val_rows, cfg.start, engine.value_dtype,
+                           rebase=f32, gate=F32_SAFE_RANGE)
+    planes, v0, risky = staged.planes, staged.v0, staged.risky
+    _TILE_BUILD_ROWS[staged.path if planes is not None else "dense"].inc(
+        len(series))
     if planes is not None:
         n = int(planes.counts.max())
         n_cap = tile_capacity(n)
@@ -730,14 +709,6 @@ def _build_tiles(engine: TPUEngine, series, cfg: RollupConfig):
                 planes,
                 ts_d2=np.pad(planes.ts_d2, ((0, 0), (0, pad))),
                 val_d2=np.pad(planes.val_d2, ((0, 0), (0, pad))))
-        if f32:
-            # v0 must match the DECODED first value exactly (mant * scale),
-            # not the pre-codec float, so addback + decode compose to the
-            # device's own absolute values
-            v0 = np.array([float(m[0]) if m.size else 0.0
-                           for _, m, _ in triples], dtype=np.float64) * \
-                np.array([10.0 ** e for _, _, e in triples])
-            v0[~np.isfinite(v0)] = 0.0
         # padded rows get count=0 and scale=1: decode masks them to TS_PAD
         pad_vals = {"scale": 1}
         dev = [_put(getattr(planes, f.name), pad_vals.get(f.name, 0),
@@ -746,16 +717,12 @@ def _build_tiles(engine: TPUEngine, series, cfg: RollupConfig):
         ts_t, v_t = dd.decode_tiles(*dev[:6], dev[6], dev[7], n_cap,
                                     engine.value_dtype, rebase=f32)
         return ts_t, v_t, dev[7], _pad_v0(v0, int(ts_t.shape[0]), risky)
-    pairs = []
-    for i, sd in enumerate(series):
-        vals_i = sd.values
-        if f32:
-            vals_i = vals_i - v0[i]
-        pairs.append((sd.timestamps, vals_i))
+    if f32:  # the dense tile holds v - v0 (v0: the first finite value)
+        val_rows = np.split(staged.vals - np.repeat(v0, staged.counts),
+                            np.cumsum(staged.counts)[:-1])
     ts, vals, counts = pack_series(
-        pairs, cfg.start,
-        n_pad=tile_capacity(
-            max((sd.timestamps.size for sd in series), default=1)),
+        list(zip(ts_rows, val_rows)), cfg.start,
+        n_pad=tile_capacity(int(staged.counts.max(initial=1))),
         dtype=engine.value_dtype)
     ts_d = _put(ts, TS_PAD, name="ts")
     return (ts_d, _put(vals, name="values"), _put(counts, name="counts"),
