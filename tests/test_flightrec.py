@@ -322,6 +322,66 @@ class TestSummary:
             evs, focus_ctx=99)["slow_refresh"]["ctx"] == 1
 
 
+    def test_the_capture_and_the_counters_name_the_same_kinds(self):
+        """One classifier for background work: a recorded ring's
+        slow-refresh overlap and vm_query_background_overlap_seconds_total
+        read the same kinds, to a millisecond, for one request that met a
+        flush, two merges, a spill conversion and a collection; the merge
+        yield stays a wait, apart from the kinds."""
+        over = {k: metricslib.REGISTRY.float_counter(
+            f'vm_query_background_overlap_seconds_total{{kind="{k}"}}')
+            for k in flightrec.BACKGROUND_KINDS}
+
+        def region(name, delay, hold):
+            time.sleep(delay)
+            if name == "gc":
+                # the ring records a collection only on a thread that
+                # owns a ring already; the clock counts every one
+                flightrec.instant("t:ring")
+                gc.collect()
+                return
+            if name == "merge:yield":   # a wait, recorded after the fact
+                t0 = time.perf_counter()
+                time.sleep(hold)
+                flightrec.rec(name, t0, time.perf_counter() - t0)
+                return
+            with flightrec.phase(name):
+                time.sleep(hold)
+
+        work = [("flush:part", 0.0, 0.05), ("merge:part", 0.01, 0.03),
+                ("merge:index", 0.02, 0.04), ("ingest:convert", 0.03, 0.02),
+                ("gc", 0.02, 0.0), ("merge:yield", 0.0, 0.08)]
+        gc.disable()
+        try:
+            v0 = {k: c.get() for k, c in over.items()}
+            with flightrec.phase("serve:other", root=True) as root:
+                ctx = flightrec.get_ctx()
+                ts = [threading.Thread(target=region, args=w) for w in work]
+                for t in ts:
+                    t.start()
+                for t in ts:
+                    t.join(10)
+                gc.collect()    # on the serving thread itself
+            got = {k: c.get() - v0[k] for k, c in over.items()}
+        finally:
+            gc.enable()
+        prev = flightrec.set_ctx(ctx)
+        flightrec.rec("serve:refresh", root.t0, root.dur)
+        flightrec.set_ctx(prev)
+        cap = flightrec.FlightRecorder(max_captures=2).capture(
+            "test", window_s=5.0, meta={"ctx": ctx})
+        slow = cap["summary"]["slow_refresh"]
+        assert slow["ctx"] == ctx
+        by_kind = slow["overlap_ms_by_category"]
+        for kind in flightrec.BACKGROUND_KINDS:
+            assert got[kind] > 0, kind
+            assert by_kind[kind] == pytest.approx(got[kind] * 1e3, abs=1.0), \
+                (kind, by_kind, got)
+        assert "merge:yield" in slow["waiting_ms_by_name"]
+        assert flightrec.background_kind("merge:yield") is None
+        assert flightrec.background_kind("gc:gen2") == "gc"
+
+
 class TestCrossThreadPropagation:
     def test_pool_worker_inherits_ctx_and_tracer(self, monkeypatch):
         """A task submitted to the shared pool runs under the SUBMITTING
@@ -462,6 +522,32 @@ class TestGcVisibility:
             "test", window_s=5.0)
         assert any(e["name"].startswith("gc:gen")
                    for e in cap["trace"]["traceEvents"])
+
+
+    def test_a_collection_on_another_thread_overlaps_the_request(self):
+        """gc.collect() on another thread inside a request root lands in
+        vm_query_background_overlap_seconds_total{kind="gc"}: a
+        collection holds the GIL, so it stops the serving thread
+        wherever it runs."""
+        over = metricslib.REGISTRY.float_counter(
+            'vm_query_background_overlap_seconds_total{kind="gc"}')
+        pause = metricslib.REGISTRY.float_counter(
+            "vm_gc_pause_seconds_total")
+        seen = {}
+
+        def collect():
+            p0 = pause.get()
+            gc.collect()
+            seen["paused"] = pause.get() - p0
+
+        v0 = over.get()
+        with flightrec.phase("serve:other", root=True) as root:
+            t = threading.Thread(target=collect)
+            t.start()
+            t.join(30)
+        got = over.get() - v0
+        assert seen["paused"] > 0
+        assert seen["paused"] - 1e-6 <= got <= root.dur
 
 
 class TestSlowQueryLog:

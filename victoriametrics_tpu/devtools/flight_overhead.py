@@ -19,6 +19,12 @@ This microbench enforces that contract two ways:
    noise-robust statistic for timing), with a few full retries before
    declaring failure — CI boxes are noisy, regressions are not.
 
+It also reports, without gating on it, what one phase of the seam
+costs (``per_phase_ns``: entry + exit of a query phase or a fetch stage
+inside a request root, with its counters, CPU twin and ring event) with
+the recorder on and off, and what a request root adds around its phases
+(``per_root_ns``: the wall counter and the background-overlap clocks).
+
 Run directly: ``python -m victoriametrics_tpu.devtools.flight_overhead``
 (prints one JSON line; exit 0 = within budget, 1 = overhead regression).
 """
@@ -33,6 +39,7 @@ import time
 import numpy as np
 
 from ..utils import flightrec
+from ..utils import metrics as metricslib
 
 
 def _per_event_ns(n: int = 50_000) -> float:
@@ -43,6 +50,55 @@ def _per_event_ns(n: int = 50_000) -> float:
     for _ in range(n):
         rec("smoke:event", t, 1e-6)
     return (time.perf_counter() - t0) / n * 1e9
+
+
+def _per_phase_ns(n: int = 20_000) -> float:
+    """Amortized cost of one phase inside a request root: a query phase
+    and a fetch stage (a family of its own) in turn."""
+    phase = flightrec.phase
+    own = metricslib.fetch_phase("decode")
+    t0 = time.perf_counter()
+    with phase("serve:other", root=True):
+        for _ in range(n // 2):
+            with phase("eval:other"):
+                pass
+            with phase("fetch:decode", counter=own):
+                pass
+    return (time.perf_counter() - t0) / n * 1e9
+
+
+def _per_root_ns(n: int = 20_000) -> float:
+    """Amortized cost of one request root with nothing inside it."""
+    phase = flightrec.phase
+    t0 = time.perf_counter()
+    for _ in range(n):
+        with phase("serve:other", root=True):
+            pass
+    return (time.perf_counter() - t0) / n * 1e9
+
+
+def _seam_ns(retries: int = 3) -> dict:
+    """Best of `retries` of each seam cost, recorder on and off."""
+    out = {}
+    prev_env = os.environ.get("VM_FLIGHTREC")
+    try:
+        for side, env in (("on", None), ("off", "0")):
+            if env is None:
+                os.environ.pop("VM_FLIGHTREC", None)
+            else:
+                os.environ["VM_FLIGHTREC"] = env
+            flightrec.reconfigure()
+            out[f"per_phase_ns_{side}"] = round(
+                min(_per_phase_ns() for _ in range(retries)), 1)
+            out[f"per_root_ns_{side}"] = round(
+                min(_per_root_ns() for _ in range(retries)), 1)
+    finally:
+        if prev_env is None:
+            os.environ.pop("VM_FLIGHTREC", None)
+        else:
+            os.environ["VM_FLIGHTREC"] = prev_env
+        flightrec.reconfigure()
+    return out
 
 
 def _workload(arr: np.ndarray, spans: int) -> None:
@@ -123,6 +179,7 @@ def main() -> int:
     except ValueError:
         max_delta_pct = 2.0
     res = run_smoke(max_event_ns, max_delta_pct)
+    res.update(_seam_ns())
     res["check"] = "flightrec_overhead"
     print(json.dumps(res))
     return 0 if res["ok"] else 1

@@ -32,8 +32,7 @@ nan = np.nan
 # host-rollup share of vm_fetch_phase_seconds_total (storage/storage.py
 # owns the fetch-side phases; the benchmark reads the whole family as
 # fetch_ms_per_query)
-_ROLLUP_PHASE = metricslib.REGISTRY.float_counter(
-    'vm_fetch_phase_seconds_total{phase="rollup"}')
+_ROLLUP_PHASE = metricslib.fetch_phase("rollup")
 
 
 def _rollup_phase() -> flightrec.phase:

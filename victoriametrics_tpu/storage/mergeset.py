@@ -21,7 +21,6 @@ import heapq
 import itertools
 import os
 import struct
-import time
 import zlib
 from collections import OrderedDict
 
@@ -366,24 +365,21 @@ class Table:
                 mems = list(self._mem_parts)
             if len(mems) < min_parts:
                 return
-            with workpool.MERGE_GATE:
+            with workpool.MERGE_GATE, \
+                    flightrec.phase("flush:index", counter=_ING_FLUSH) as ph:
                 # timed inside the gate: pure write time (queue wait is
                 # visible as vm_merge_pending)
-                t0 = time.perf_counter()
                 merged = _dedup_sorted(heapq.merge(*mems))
                 name = f"part_{next(self._part_seq):016d}"
                 p = os.path.join(self.path, name)
                 _FilePart.write(p, merged)
-                dt = time.perf_counter() - t0
             with self._lock:
                 flushed = {id(m) for m in mems}
                 self._mem_parts = [m for m in self._mem_parts
                                    if id(m) not in flushed]
                 self._file_parts.append(_FilePart(p, trusted=True))
                 merge_files = len(self._file_parts) > MAX_INMEMORY_PARTS
-            _FLUSH_DURATION.update(dt)
-            _ING_FLUSH.inc(dt)
-            flightrec.rec("flush:index", t0, dt)
+            _FLUSH_DURATION.update(ph.dur)
         if merge_files:
             self._merge_file_parts()
 
@@ -398,23 +394,22 @@ class Table:
                 return
             _ACTIVE_MERGES.inc()
             try:
-                with workpool.MERGE_GATE:
-                    t0 = time.perf_counter()
+                with workpool.MERGE_GATE, \
+                        flightrec.phase("merge:index",
+                                        counter=_ING_MERGE) as ph:
                     merged = _dedup_sorted(
                         heapq.merge(*[p.iter_all() for p in olds]))
                     name = f"part_{next(self._part_seq):016d}"
                     p = os.path.join(self.path, name)
                     _FilePart.write(p, merged)
-                    dt = time.perf_counter() - t0
                 new_part = _FilePart(p, trusted=True)
                 with self._lock:
                     keep = [q for q in self._file_parts if q not in olds]
                     self._file_parts = [new_part] + keep
                 # success only: aborted merges must not count as progress
-                _MERGE_DURATION.update(dt)
-                _ING_MERGE.inc(dt)
+                # (their seconds still reach the ingest family)
+                _MERGE_DURATION.update(ph.dur)
                 _MERGES_TOTAL.inc()
-                flightrec.rec("merge:index", t0, dt)
             finally:
                 _ACTIVE_MERGES.dec()
             for old in olds:

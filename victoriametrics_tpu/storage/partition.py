@@ -47,6 +47,8 @@ _ACTIVE_MERGES = metricslib.REGISTRY.gauge(
     'vm_active_merges{type="storage/file"}')
 _ING_FLUSH = metricslib.ingest_phase("flush")
 _ING_MERGE = metricslib.ingest_phase("merge")
+# the pool's conversion of a spilled pending batch (_convert_spill)
+_ING_CONVERT = metricslib.ingest_phase("convert")
 _SPILL_ERRORS = metricslib.REGISTRY.counter("vm_ingest_spill_errors_total")
 # rows turned from pending rows into an InmemoryPart, by the code that
 # ordered them (one inc a conversion, on whichever thread ran it): the
@@ -57,8 +59,7 @@ _CONVERT_ROWS = {
     for path in ("native", "lexsort")}
 # the calling thread's wait for fresh rows to become readable parts
 # (storage/storage.py _PHASE lists the family)
-_PENDING_CONVERT = metricslib.REGISTRY.float_counter(
-    'vm_fetch_phase_seconds_total{phase="pending_convert"}')
+_PENDING_CONVERT = metricslib.fetch_phase("pending_convert")
 # torn/corrupt parts moved aside at open instead of being served or
 # silently dropped (one series per store kind; mergeset ticks its own)
 _PARTS_QUARANTINED = metricslib.REGISTRY.counter(
@@ -747,7 +748,8 @@ class Partition:
         to whoever waits on the Future (the flusher logs it)."""
         part = err = None
         try:
-            part = _rows_to_inmemory_part(rows)
+            with flightrec.phase("ingest:convert", counter=_ING_CONVERT):
+                part = _rows_to_inmemory_part(rows)
         except BaseException as e:  # noqa: BLE001 — re-raised below
             err = e
             _SPILL_ERRORS.inc()
@@ -877,12 +879,10 @@ class Partition:
             with workpool.MERGE_GATE:
                 # timed inside the gate: the histograms mean pure write
                 # time; queue wait is visible as vm_merge_pending
-                t0 = time.perf_counter()
-                p = self._write_part([m.iter_blocks() for m in mems])
-                dt = time.perf_counter() - t0
-            _FLUSH_DURATION.update(dt)
-            _ING_FLUSH.inc(dt)
-            flightrec.rec("flush:part", t0, dt, arg=self.name)
+                with flightrec.phase("flush:part", arg=self.name,
+                                     counter=_ING_FLUSH) as ph:
+                    p = self._write_part([m.iter_blocks() for m in mems])
+            _FLUSH_DURATION.update(ph.dur)
             with self._lock:
                 if p is not None:
                     self._file_parts.append(p)
@@ -937,18 +937,17 @@ class Partition:
                 return
             _ACTIVE_MERGES.inc()
             try:
-                with workpool.MERGE_GATE:
-                    t0 = time.perf_counter()
+                with workpool.MERGE_GATE, \
+                        flightrec.phase("merge:part", arg=self.name,
+                                        counter=_ING_MERGE) as ph:
                     merged = self._write_part(
                         [p.iter_blocks() for p in olds],
                         deleted_ids, min_valid_ts)
-                    dt = time.perf_counter() - t0
                 # counted only on success: an aborted merge (ENOSPC)
-                # must not look like the compactor making progress
-                _MERGE_DURATION.update(dt)
-                _ING_MERGE.inc(dt)
+                # must not look like the compactor making progress (its
+                # seconds still reach the ingest family: they were spent)
+                _MERGE_DURATION.update(ph.dur)
                 _MERGES_TOTAL.inc()
-                flightrec.rec("merge:part", t0, dt, arg=self.name)
             finally:
                 _ACTIVE_MERGES.dec()
             # the merged part dir is renamed into place but NOT yet in

@@ -47,8 +47,7 @@ DEFAULT_RETENTION_MS = 31 * 13 * 86_400_000  # ~13 months, like the reference
 # (collect / decode) stay accurate for the fallback/oracle path instead
 # of silently absorbing fused time.
 _PHASE = {
-    ph: metricslib.REGISTRY.float_counter(
-        f'vm_fetch_phase_seconds_total{{phase="{ph}"}}')
+    ph: metricslib.fetch_phase(ph)
     for ph in ("index_search", "collect", "decode", "assemble",
                "assemble_native", "queue_wait", "pending_convert")
 }

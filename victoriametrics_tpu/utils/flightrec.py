@@ -31,12 +31,16 @@ times of a request partition the wall between its root's entry and
 exit exactly.  On exit one call feeds the ring (inclusive ``dur`` plus
 the nesting ``depth``), the current query's cost tracker (self wall +
 self thread-CPU, bucket = name), ``vm_query_phase_seconds_total{phase=}``
-(only on a thread that holds a request root) and — through
-:func:`set_annotator`, installed by the device engine, because this
-module must import without jax — a ``vm:<name>`` annotation on the
-profiler's host plane.  Regions with no natural block (background
-flush/merge workers, the pool's task wrapper, instants) still call
-:func:`rec` directly.
+and its CPU twin (only on a thread that holds a request root) and —
+through :func:`set_annotator`, installed by the device engine, because
+this module must import without jax — a ``vm:<name>`` annotation on the
+profiler's host plane.  The background flushes, merges and spill
+conversions are phases too, off the stack: each also keeps its kind's
+busy-clock, which every request root reads at entry and exit
+(``vm_query_background_overlap_seconds_total{kind=}``); gc collections
+keep the ``gc`` clock from the gc callback.  Regions with no natural
+block (the pool's task wrapper, waits, instants) still call :func:`rec`
+directly.
 
 Cross-thread attribution: a serving thread opens a *flight context*
 (:func:`set_ctx`, an integer id per refresh/query); utils/workpool
@@ -72,7 +76,7 @@ from . import metrics as metricslib
 
 __all__ = ["enabled", "rec", "instant", "phase", "set_annotator",
            "new_ctx", "set_ctx", "get_ctx", "ctx_events", "clear_ctx",
-           "RECORDER", "FlightRecorder", "reconfigure"]
+           "RECORDER", "FlightRecorder", "reconfigure", "background_kind"]
 
 #: ring capacity per thread (events); power of two for mask arithmetic
 _DEFAULT_CAP = 1 << 13
@@ -287,14 +291,109 @@ QUERY_PHASES = (
     "device:download", "device:compile")
 
 
-def _query_phase_counter(name: str):
-    return metricslib.REGISTRY.float_counter(
-        f'vm_query_phase_seconds_total{{phase="{name}"}}')
+def _query_phase_counters(name: str):
+    """(wall, thread-CPU) counters of one query phase."""
+    return (metricslib.REGISTRY.float_counter(
+                f'vm_query_phase_seconds_total{{phase="{name}"}}'),
+            metricslib.REGISTRY.float_counter(
+                f'vm_query_phase_cpu_seconds_total{{phase="{name}"}}'))
 
 
-_QUERY_PHASE = {n: _query_phase_counter(n) for n in QUERY_PHASES}
+_QUERY_PHASE = {n: _query_phase_counters(n) for n in QUERY_PHASES}
 _QUERY_WALL = metricslib.REGISTRY.float_counter(
     "vm_query_wall_seconds_total")
+
+# the fetch family (made, twin and all, by metricslib.fetch_phase where
+# each stage is owned) and its CPU twin's name
+_FETCH_FAMILY = "vm_fetch_phase_seconds_total{"
+_FETCH_CPU_FAMILY = "vm_fetch_phase_cpu_seconds_total{"
+
+# a phase's own counter -> the counter of its thread-CPU, looked up by
+# name (None: the family has no twin, as the ingest family has none)
+_cpu_twins: dict = {}
+
+
+def _cpu_twin(counter):
+    try:
+        return _cpu_twins[counter]
+    except KeyError:
+        twin = None
+        if counter.name.startswith(_FETCH_FAMILY):
+            twin = metricslib.REGISTRY.float_counter(
+                _FETCH_CPU_FAMILY + counter.name[len(_FETCH_FAMILY):])
+        # benign double-create, as the query family's fill below
+        _cpu_twins[counter] = twin  # vmt: disable=VMT015
+        return twin
+
+
+# -- background work: one busy-clock a kind -----------------------------------
+
+#: the kinds of background work a query can meet; each has a busy-clock
+#: that counts the seconds in which at least one region of its kind ran
+#: on any thread (an interval union, kept incrementally)
+BACKGROUND_KINDS = ("flush", "merge", "convert", "gc")
+_KIND_OF = {"flush:table": "flush", "flush:part": "flush",
+            "flush:index": "flush", "merge:table": "merge",
+            "merge:part": "merge", "merge:index": "merge",
+            "ingest:convert": "convert"}
+_CLOCK_OF = {name: BACKGROUND_KINDS.index(kind)
+             for name, kind in _KIND_OF.items()}
+_GC_CLOCK = BACKGROUND_KINDS.index("gc")
+
+
+def background_kind(name: str) -> str | None:
+    """The kind of background work a region of this name is, or None:
+    the one map behind the busy-clocks and :func:`summarize`'s overlap
+    buckets.  Waits (``merge:yield``, ``merge:gate_wait``) are none."""
+    kind = _KIND_OF.get(name)
+    if kind is None and name.startswith("gc:"):
+        return "gc"
+    return kind
+
+
+# one (active regions, since, accumulated seconds) a kind, replaced whole
+# so a reader takes a consistent state without the lock.  flush, merge
+# and convert change under _busy_lock at their regions' edges; gc only
+# in the gc callback, which runs with the GIL held and may fire while
+# this thread holds _busy_lock, so it never takes it
+_busy = [(0, 0.0, 0.0)] * len(BACKGROUND_KINDS)
+_busy_lock = threading.Lock()
+
+_OVERLAP = [metricslib.REGISTRY.float_counter(
+    f'vm_query_background_overlap_seconds_total{{kind="{k}"}}')
+    for k in BACKGROUND_KINDS]
+
+
+def _busy_edge(i: int, step: int) -> None:
+    with _busy_lock:
+        now = time.perf_counter()
+        n, since, acc = _busy[i]
+        if step > 0:
+            _busy[i] = (n + 1, now if n == 0 else since, acc)
+        elif n == 1:
+            _busy[i] = (0, 0.0, acc + now - since)
+        elif n > 1:
+            _busy[i] = (n - 1, since, acc)
+
+
+def _busy_at(state: tuple, now: float) -> float:
+    """A clock's busy seconds up to `now` (a region opened after `now`
+    but before the state was read adds nothing)."""
+    n, since, acc = state
+    return acc + now - since if n and now > since else acc
+
+
+def _charge_overlap(states0: list, t0: float, now: float,
+                    dur: float) -> None:
+    """Charge each kind's busy seconds between a root's entry (`states0`
+    read at `t0`) and its exit at `now`."""
+    for i, s1 in enumerate(_busy):
+        s0 = states0[i]
+        if s1 is s0 and not s0[0]:
+            continue    # idle throughout: the common case
+        d = _busy_at(s1, now) - _busy_at(s0, t0)
+        if d > 0.0:
+            _OVERLAP[i].inc(d if d < dur else dur)
 
 # name -> context-manager factory for the profiler's host plane
 # (jax.profiler.TraceAnnotation once the device engine has started);
@@ -320,8 +419,8 @@ class phase:
     child phases took.  On exit (also when the body raises) the region
     is recorded once, everywhere: a ring event (inclusive duration +
     nesting depth), a lap of the self time in the current query's cost
-    tracker under ``name``, the phase's counter, and the profiler
-    annotation held open since entry.
+    tracker under ``name``, the phase's counter and its CPU twin, and
+    the profiler annotation held open since entry.
 
     `counter` names a family of the phase's OWN
     (``vm_fetch_phase_seconds_total``, ``vm_ingest_phase_seconds_total``):
@@ -333,11 +432,28 @@ class phase:
     as a pooled one does).  Without `counter` the self time goes to
     ``vm_query_phase_seconds_total{phase=name}``, but only on a thread
     that holds a request root: that family is a partition of one
-    thread's wall and must never sum past it.
+    thread's wall and must never sum past it.  Each charge of the query
+    and fetch families has a twin in ``vm_query_phase_cpu_seconds_total``
+    / ``vm_fetch_phase_cpu_seconds_total``: the thread-CPU of the same
+    stretch, carved by the same rule and never more than its wall, so
+    wall - cpu is the time the thread was off the CPU (waiting for the
+    GIL, a core, a lock or I/O).
 
     ``root=True`` opens a request: the outermost such phase on a thread
     gives the request its flight context (unless one is installed) and
-    adds its whole wall to ``vm_query_wall_seconds_total`` on exit.
+    adds its whole wall to ``vm_query_wall_seconds_total`` on exit, and
+    to ``vm_query_background_overlap_seconds_total{kind=}`` the seconds
+    of that wall in which background work of each kind ran.
+
+    A background region (a name :func:`background_kind` knows: the
+    flushes, the merges, the spill conversion) stays OFF the thread's
+    phase stack: it is the work of no request, run by whatever thread
+    got to it (a serving thread that helps the pool included), so the
+    phase it interrupts keeps its time, as when such regions were plain
+    :func:`rec` calls.  It feeds its kind's busy-clock, its counter, the
+    ring (depth 0) and the annotation; on a thread that holds a request
+    root it leaves the busy-clock alone, since the request waited for
+    that work in a phase of its own (``fetch:pending_convert``).
 
     :meth:`lap` ends the region under its current name and starts the
     next one in the same frame — a chain of back-to-back stages inside
@@ -345,7 +461,9 @@ class phase:
     learns only afterwards that it compiled)."""
 
     __slots__ = ("name", "arg", "counter", "root", "t0", "dur", "_child",
-                 "_acct", "_staged", "_cpu", "_cpu_mark", "_ann", "_own_ctx")
+                 "_acct", "_staged", "_cpu", "_cpu_mark", "_cpu_child",
+                 "_cpu_acct", "_cpu_staged", "_ann", "_own_ctx", "_clock",
+                 "_edge", "_busy0")
 
     def __init__(self, name: str, arg=None, counter=None,
                  root: bool = False):
@@ -354,8 +472,20 @@ class phase:
         self.counter = counter
         self.root = root
         self.dur = 0.0
+        self._clock = _CLOCK_OF.get(name)
 
     def __enter__(self):
+        if self._clock is not None:
+            # on a thread that holds a request root (a serving thread
+            # helping the pool while it waits) the work is the request's
+            # own, already inside its phases: no busy-clock edge
+            stack = getattr(_tls, "stack", None)
+            self._edge = not (stack and stack[0].root)
+            if self._edge:
+                _busy_edge(self._clock, 1)
+            self._open()
+            self.t0 = time.perf_counter()
+            return self
         stack = getattr(_tls, "stack", None)
         if stack is None:
             stack = _tls.stack = []
@@ -373,6 +503,8 @@ class phase:
             parent._cpu += now_cpu - parent._cpu_mark
         stack.append(self)
         self._cpu_mark = now_cpu
+        if self.root:
+            self._busy0 = list(_busy)
         self.t0 = time.perf_counter()
         return self
 
@@ -380,8 +512,10 @@ class phase:
         # _child: inclusive seconds of direct children (self = dur - it);
         # _acct: seconds inside this region that descendants already
         # charged to the query family; _staged: seconds that descendants
-        # charged to counters of their own
+        # charged to counters of their own; the _cpu_* the same in
+        # thread-CPU (_cpu itself is the self CPU)
         self._child = self._acct = self._staged = self._cpu = 0.0
+        self._cpu_child = self._cpu_acct = self._cpu_staged = 0.0
         ann = None
         if _annotator is not None and _ENABLED:
             ann = _annotator("vm:" + self.name)
@@ -405,6 +539,16 @@ class phase:
 
     def __exit__(self, *exc):
         now = time.perf_counter()
+        if self._clock is not None:
+            if self._ann is not None:
+                self._ann.__exit__(None, None, None)
+            if self._edge:
+                _busy_edge(self._clock, -1)
+            dur = self.dur = now - self.t0
+            rec(self.name, self.t0, dur, self.arg)
+            if self.counter is not None:
+                self.counter.inc(dur)
+            return False
         now_cpu = time.thread_time()
         stack = _tls.stack
         # a child left open by a generator abandoned mid-phase must not
@@ -418,7 +562,9 @@ class phase:
             return False
         self._close(now, None, 1, self.root)
         if self.root:
-            _QUERY_WALL.inc(self.dur)
+            dur = self.dur
+            _QUERY_WALL.inc(dur)
+            _charge_overlap(self._busy0, self.t0, now, dur)
             if self._own_ctx:
                 _tls.ctx = 0
         return False
@@ -430,30 +576,45 @@ class phase:
             self._ann.__exit__(None, None, None)
         name = self.name
         dur = self.dur = now - self.t0
+        cpu = self._cpu + self._cpu_child
         rec(name, self.t0, dur, self.arg, depth)
         tr = costacc.current()
         if tr is not None:
             tr.lap(name, dur - self._child, self._cpu)
         if parent is not None:
             parent._child += dur
+            parent._cpu_child += cpu
         if self.counter is not None:
             # a family of its own: the time no descendant charged stays
             # with the enclosing query phase
-            self.counter.inc(max(dur - self._staged, 0.0))
+            wall = max(dur - self._staged, 0.0)
+            self.counter.inc(wall)
+            twin = _cpu_twin(self.counter)
+            own = cpu - self._cpu_staged
+            if twin is not None and own > 0.0:
+                twin.inc(own if own < wall else wall)
             if parent is not None:
                 parent._acct += self._acct
                 parent._staged += dur
+                parent._cpu_acct += self._cpu_acct
+                parent._cpu_staged += cpu
             return
         if parent is not None:
             parent._acct += dur
             parent._staged += self._staged
+            parent._cpu_acct += cpu
+            parent._cpu_staged += self._cpu_staged
         if rooted:
-            c = _QUERY_PHASE.get(name)
-            if c is None:
+            pair = _QUERY_PHASE.get(name)
+            if pair is None:
                 # benign double-create: REGISTRY.float_counter dedups by
-                # name, so two racing fills store the same object
-                c = _QUERY_PHASE[name] = _query_phase_counter(name)  # vmt: disable=VMT015
-            c.inc(max(dur - self._acct, 0.0))
+                # name, so two racing fills store the same objects
+                pair = _QUERY_PHASE[name] = _query_phase_counters(name)  # vmt: disable=VMT015
+            wall = max(dur - self._acct, 0.0)
+            pair[0].inc(wall)
+            cpu -= self._cpu_acct
+            if cpu > 0.0:
+                pair[1].inc(cpu if cpu < wall else wall)
 
 
 # -- flight context (cross-thread query attribution) --------------------------
@@ -791,7 +952,10 @@ def summarize(trace_events: list[dict], focus_ctx: int = 0) -> dict:
             if name.endswith((":queue_wait", ":gate_wait", ":yield")):
                 waiting.setdefault(name, []).append((lo, hi))
                 continue
-            cat = name.split(":", 1)[0]
+            # the busy-clocks' kinds by the same map, so a capture and
+            # vm_query_background_overlap_seconds_total name the same
+            # work; any other name by its prefix
+            cat = background_kind(name) or name.split(":", 1)[0]
             overlap.setdefault(cat, []).append((lo, hi))
         # interval UNION per bucket, not a sum: nested spans (the
         # flush:table fan span contains its workers' flush:part spans)
@@ -845,7 +1009,29 @@ def slow_refresh_threshold_ms() -> float:
 
 # -- gc visibility ------------------------------------------------------------
 
+# the open collection's host-plane mark: collections run one at a time,
+# with the GIL held, and start and stop on one thread
+_gc_ann = [None]
+
+
+def _gc_start(t0: float) -> None:
+    # the gc busy-clock takes no lock (see _busy); a collection holds the
+    # GIL, so it stops the serving thread wherever it runs
+    _busy[_GC_CLOCK] = (1, t0, _busy[_GC_CLOCK][2])
+    if _annotator is not None and _ENABLED:
+        ann = _annotator("vm:gc")
+        ann.__enter__()
+        _gc_ann[0] = ann
+
+
 def _gc_hook(t0: float, dur: float, gen) -> None:
+    n, _since, acc = _busy[_GC_CLOCK]
+    if n:
+        _busy[_GC_CLOCK] = (0, 0.0, acc + dur)
+    ann = _gc_ann[0]
+    if ann is not None:
+        _gc_ann[0] = None
+        ann.__exit__(None, None, None)
     # gc callbacks fire on whatever thread triggered the collection —
     # possibly INSIDE rec()'s slot stores, or inside a _rings_lock
     # critical section (ring creation / capture allocate).  Recording
@@ -874,7 +1060,10 @@ def install_gc_events() -> None:
     """Record every gc collection as a flight span on the thread that
     triggered it (gc pauses are a serving-latency suspect).  Piggybacks
     on utils/metrics' single gc callback — the one timing of each
-    collection feeds both vm_gc_pause_seconds_total and the timeline."""
+    collection feeds both vm_gc_pause_seconds_total and the timeline,
+    the gc busy-clock and the ``vm:gc`` mark."""
+    if _gc_start not in metricslib.gc_start_hooks:
+        metricslib.gc_start_hooks.append(_gc_start)
     if _gc_hook not in metricslib.gc_pause_hooks:
         metricslib.gc_pause_hooks.append(_gc_hook)
 

@@ -394,6 +394,9 @@ _gc_pause_t0 = [0.0]
 #: without registering a SECOND gc callback that re-times the same
 #: collection
 gc_pause_hooks: list = []
+#: (t0,) observers invoked as each collection starts (flightrec's gc
+#: busy-clock and host-plane mark open here)
+gc_start_hooks: list = []
 
 # bound at import, NOT imported inside the callback: gc callbacks still
 # fire during interpreter shutdown, when `import time` raises
@@ -405,7 +408,9 @@ def _gc_pause_callback(phase: str, info: dict) -> None:
     # the collecting thread holds the GIL for the whole collection, so
     # start/stop pair up on one thread and a plain slot is race-free
     if phase == "start":
-        _gc_pause_t0[0] = _gc_clock()
+        t0 = _gc_pause_t0[0] = _gc_clock()
+        for hook in gc_start_hooks:
+            hook(t0)
     elif phase == "stop" and _gc_pause_t0[0]:
         t0 = _gc_pause_t0[0]
         _gc_pause_t0[0] = 0.0
@@ -436,6 +441,18 @@ def ingest_phase(phase: str) -> FloatCounter:
     (background part merges).  Shared by storage/partition/mergeset."""
     return REGISTRY.float_counter(
         f'vm_ingest_phase_seconds_total{{phase="{phase}"}}')
+
+
+def fetch_phase(phase: str) -> FloatCounter:
+    """Per-stage read-path counter: seconds spent in one stage of a
+    fetch, ``vm_fetch_phase_seconds_total{phase=}``.  Its thread-CPU
+    twin ``vm_fetch_phase_cpu_seconds_total{phase=}`` is made beside it,
+    so the module that owns a stage exports both members at 0 from its
+    import (utils/flightrec charges the twin from the phase's exit)."""
+    REGISTRY.float_counter(
+        f'vm_fetch_phase_cpu_seconds_total{{phase="{phase}"}}')
+    return REGISTRY.float_counter(
+        f'vm_fetch_phase_seconds_total{{phase="{phase}"}}')
 
 
 # -- exposition utilities ----------------------------------------------------

@@ -75,8 +75,7 @@ _TASKS_TOTAL = metricslib.REGISTRY.counter("vm_workpool_tasks_total")
 # phase family lives in storage/storage.py; this member is owned here
 # because the gate is the thing that queues) — with it the phase split
 # sums to contended wall time instead of silently losing the queue wait
-_QUEUE_WAIT = metricslib.REGISTRY.float_counter(
-    'vm_fetch_phase_seconds_total{phase="queue_wait"}')
+_QUEUE_WAIT = metricslib.fetch_phase("queue_wait")
 
 # whole-refresh serve sections (the HTTP cached range executor wraps each
 # refresh): together with the SearchGate occupancy below this is the
