@@ -125,6 +125,20 @@ def test_append_tile_donated(one):
         "append_tile no longer aliases the donated tile"
 
 
+def test_rewind_append_tile_donated(one):
+    """The rolling refresh under a dedup interval: the rows' samples past
+    the rewind boundary dropped, the refetched (boundary, end] survivors
+    padded to 8 columns appended, old buffers donated."""
+    from victoriametrics_tpu.ops.device_rollup import rewind_append_tile
+    c = rewind_append_tile.lower(*_tile(one), one((S, 8), np.int32),
+                                 one((S, 8), np.float32),
+                                 one((S,), np.int32),
+                                 one((), np.int32)).compile()
+    assert c.memory_analysis().alias_size_in_bytes >= S * N * 8, \
+        "rewind_append_tile no longer aliases the donated tile"
+    _fits(c)
+
+
 def test_compact_tile_donated(one):
     from victoriametrics_tpu.ops.device_rollup import compact_tile
     i32 = one((), np.int32)

@@ -22,7 +22,8 @@ sample axis, which XLA fuses into plain VPU work:
   rollup.go:921) over the whole row, before the pass.
 - ``rollup_aggregate_tile`` / ``fleet_rollup_aggregate_*`` fuse the group
   reduction (a one-hot matmul for sums, segment min / max) behind it;
-  ``append_tile`` / ``compact_tile`` maintain the resident window.
+  ``append_tile`` / ``compact_tile`` maintain the resident window
+  (``rewind_append_tile`` under a dedup interval).
 
 Inputs are padded ragged tiles:
   ts:     int32 [S, N]  sample timestamps, ms, relative to cfg.start,
@@ -743,6 +744,29 @@ def append_tile(ts: jnp.ndarray, values: jnp.ndarray, counts: jnp.ndarray,
     positions beyond new_counts[row] scatter out of bounds and are dropped."""
     return _append_tile_body(ts, values, counts, new_ts, new_values,
                              new_counts)
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1, 2))
+def rewind_append_tile(ts: jnp.ndarray, values: jnp.ndarray,
+                       counts: jnp.ndarray, new_ts: jnp.ndarray,
+                       new_values: jnp.ndarray, new_counts: jnp.ndarray,
+                       cut_rel):
+    """Rolling-tile advance under a dedup interval: each row first drops
+    its samples later than `cut_rel` (tile-relative ms, traced, so every
+    tick reuses one executable; rows are sorted, so they are a suffix),
+    the freed positions go back to TS_PAD, then the slice, which holds
+    the storage's survivors of (cut, end], scatters onto the shortened
+    tails as in append_tile. Donated like append_tile; the returned
+    counts are each row's new length."""
+    S, N = ts.shape
+    k = jnp.arange(N, dtype=jnp.int32)[None, :]
+    keep = jnp.sum((k < counts[:, None]) & (ts <= jnp.int32(cut_rel)),
+                   axis=1, dtype=counts.dtype)
+    live = k < keep[:, None]
+    return _append_tile_body(
+        jnp.where(live, ts, TS_PAD),
+        jnp.where(live, values, jnp.zeros((), values.dtype)), keep,
+        new_ts, new_values, new_counts)
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1, 2))

@@ -1069,6 +1069,18 @@ def device_window_ready(ec: EvalConfig, e: Expr) -> bool:
     return True
 
 
+def _dedup_on_mesh(ec: EvalConfig) -> bool:
+    """A resident window under a dedup interval advances by a rewind that
+    has no series-sharded form: on a mesh none is made (counted as a
+    decline each time one would have been)."""
+    if not getattr(ec.storage, "dedup_interval_ms", 0) or \
+            ec.tpu.series_shards() == 1:
+        return False
+    from .tpu_engine import DEDUP_ON_MESH, decline_roll
+    decline_roll(ec.tpu, DEDUP_ON_MESH)
+    return True
+
+
 def _try_device_fused_aggr(ec: EvalConfig, ae: AggrFuncExpr
                            ) -> list[Timeseries] | None:
     """aggr by (...)(rollup(selector)) fused on device: rollup + segment
@@ -1186,7 +1198,7 @@ def _try_device_fused_aggr(ec: EvalConfig, ae: AggrFuncExpr
                                      func)
             if advance_rolling(ec.tpu, rt, ec.storage, filters, start,
                                fetch_lo, end, ec.max_series, ec.tenant,
-                               drop_stale, tracer=qt):
+                               drop_stale, tracer=qt, step=ec.step):
                 ec.check_deadline()
                 ec.count_samples(rt.samples_in_range(fetch_lo))
                 cfg2 = RollupConfig(start=start, end=end, step=ec.step,
@@ -1220,7 +1232,18 @@ def _try_device_fused_aggr(ec: EvalConfig, ae: AggrFuncExpr
                 # sliding advance only; anything else recomputes fresh.)
                 n_new = rb.try_advance(start, end, ec.step, lookback) \
                     if rb is not None else None
-                if n_new == 0:
+                # under a dedup interval an advance may have replaced
+                # samples later than a rewind boundary: the committed
+                # columns later than it are recomputed with the new ones
+                # (a sample at ts moves no column before ts)
+                redo = 0
+                if n_new is not None:
+                    cut = rt.cut_since(rb.tile_appends)
+                    if cut is not None:
+                        redo = rb.columns_after(cut)
+                        if n_new + redo >= rb.T:
+                            n_new = None
+                if n_new == 0 and not redo:
                     rows_out = rb.commit(start, end, None)
                     qt.printf("pure shift: %d columns reused", rb.T)
                 elif n_new is not None:
@@ -1236,12 +1259,13 @@ def _try_device_fused_aggr(ec: EvalConfig, ae: AggrFuncExpr
                     # maxPrevInterval rule (rollup.go:719-728) and flip
                     # prev gating
                     grid_end = start + ((end - start) // ec.step) * ec.step
+                    w = n_new + redo
                     tail = kernel(RollupConfig(
-                        start=grid_end - n_new * ec.step, end=grid_end,
+                        start=grid_end - w * ec.step, end=grid_end,
                         step=ec.step, window=lookback))[:, 1:]
                     rows_out = rb.commit(start, end, tail)
                     qk.donef("[%d, %d] tail, %d columns reused",
-                             len(group_keys), n_new, rb.T - n_new)
+                             len(group_keys), w, rb.T - w)
                 else:
                     qk = qt.new_child("fused kernel + D2H")
                     out = kernel(cfg2)
@@ -1252,6 +1276,8 @@ def _try_device_fused_aggr(ec: EvalConfig, ae: AggrFuncExpr
                         rows_out = rb.rows()
                     else:
                         rows_out = list(out)
+                if rb is not None:
+                    rb.tile_appends = rt.appends
                 qt.donef("advanced tile (%d appends), %d groups",
                          rt.appends, len(group_keys))
                 count_window_hit()
@@ -1335,8 +1361,8 @@ def _try_device_fused_aggr(ec: EvalConfig, ae: AggrFuncExpr
                      n_fetched, qx))
         if roll_state_key is not None and adj is None and \
                 tile_key is not None and not ec._partial[0] and \
-                not getattr(ec.storage, "dedup_interval_ms", 0) and \
-                all(sd.raw_name is not None for sd in series):
+                all(sd.raw_name is not None for sd in series) and \
+                not _dedup_on_mesh(ec):
             tiles_now = ec.tpu.cache().get(tile_key)
             if tiles_now is not None:
                 wcache = ec.tpu.window_cache()

@@ -223,7 +223,7 @@ class RingBlock:
     views-stable contract)."""
 
     __slots__ = ("buf", "G", "T", "col_off", "start", "end", "step",
-                 "window", "out_refs")
+                 "window", "out_refs", "tile_appends")
 
     def __init__(self, out, start: int, end: int, step: int, window: int):
         out = np.asarray(out, dtype=np.float64)
@@ -236,6 +236,9 @@ class RingBlock:
         self.buf = np.empty((self.G, self.T + COL_HEADROOM))
         self.buf[:, :self.T] = out
         self.out_refs: tuple = ()
+        # the resident tile's append count the committed columns were
+        # computed at (RollingTile.cut_since), set by the device plane
+        self.tile_appends = 0
 
     def reset(self, out, start: int, end: int, step: int,
               window: int) -> None:
@@ -279,12 +282,20 @@ class RingBlock:
             return None  # disjoint windows: nothing reusable
         return n_new
 
+    def columns_after(self, t_ms: int) -> int:
+        """How many committed columns lie later than `t_ms`."""
+        last = self.start + (self.T - 1) * self.step
+        if t_ms >= last:
+            return 0
+        return min(self.T, (last - t_ms - 1) // self.step + 1)
+
     def commit(self, start: int, end: int, tail) -> list[np.ndarray]:
         """Advance in place per a successful try_advance: scatter the
-        (G, n_new) tail columns, move the window offset, return fresh
-        read-only row views.  When handed-out rows are still alive or the
-        headroom is exhausted, the live columns compact into a FRESH
-        buffer so earlier responses' views stay intact."""
+        (G, w) tail columns over the window's last w (the n_new fresh
+        ones, and any recomputed before them), move the window offset,
+        return fresh read-only row views.  When handed-out rows are still
+        alive or the headroom is exhausted, the live columns compact into
+        a FRESH buffer so earlier responses' views stay intact."""
         n_new = (end - self.end) // self.step
         shift = (start - self.start) // self.step
         col_off = self.col_off + shift
@@ -298,8 +309,9 @@ class RingBlock:
             self.buf = nb
             col_off = 0
             self.out_refs = ()
-        if n_new:
-            self.buf[:, col_off + self.T - n_new:col_off + self.T] = tail
+        if tail is not None:
+            w = tail.shape[1]
+            self.buf[:, col_off + self.T - w:col_off + self.T] = tail
         self.col_off = col_off
         self.start = start
         self.end = end

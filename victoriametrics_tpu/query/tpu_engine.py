@@ -198,6 +198,29 @@ _TILE_BUILD_ROWS = {
     for path in ("native", "python", "dense")}
 # series-axis size of the newest engine's mesh (0 until an engine is built)
 _SERIES_SHARDS = metricslib.REGISTRY.gauge("vm_device_series_shards")
+# resident-window advances that fell back to a cold rebuild, by reason, and
+# advances under a dedup interval that dropped provisional samples before
+# appending (the two dedup reasons and the rewinds read 0 from import)
+DEDUP_ON_MESH = "dedup interval on a series mesh"
+REWIND_TOO_DEEP = "late data beyond the rewind bound"
+_ROLL_REWINDS = metricslib.REGISTRY.counter("vm_device_roll_rewinds_total")
+
+
+def _roll_declines(reason: str):
+    return metricslib.REGISTRY.counter(metricslib.format_name(
+        "vm_device_roll_declines_total", {"reason": reason}))
+
+
+for _reason in (DEDUP_ON_MESH, REWIND_TOO_DEEP):
+    _roll_declines(_reason)
+
+
+def decline_roll(engine, reason: str) -> bool:
+    """Record why a resident window was not advanced (or not made):
+    engine.last_roll_decline plus the reason's counter. Returns False."""
+    engine.last_roll_decline = reason
+    _roll_declines(reason).inc()
+    return False
 
 
 def _register_compile_listeners():
@@ -768,7 +791,7 @@ class RollingTile:
 
     __slots__ = ("tiles", "base_ms", "n_cap", "lo_ms", "hi_ms", "version",
                  "structural", "counts_host", "row_of_raw", "n_samples",
-                 "adopted_key", "appends", "segments")
+                 "adopted_key", "appends", "segments", "cuts")
 
     def __init__(self, tiles, base_ms, n_cap, lo_ms, hi_ms, version,
                  structural, counts_host, row_of_raw, n_samples,
@@ -789,19 +812,46 @@ class RollingTile:
         # -search.maxSamplesPerQuery charge only segments a query's fetch
         # range would actually touch, not the tile's whole history
         self.segments = [(lo_ms, hi_ms, n_samples)]
+        # (appends after it, boundary ms) of each rewinding append under a
+        # dedup interval, oldest first: samples later than the boundary were
+        # replaced, so every [G, T] column later than it is stale for a
+        # consumer that last committed before that append. Bounded by
+        # folding the two oldest entries into one (the newer count, the
+        # lower boundary): a consumer that far behind recomputes more
+        self.cuts: list[tuple[int, int]] = []
 
     def samples_in_range(self, fetch_lo: int) -> int:
         return sum(n for _, seg_hi, n in self.segments if seg_hi >= fetch_lo)
 
+    def cut_since(self, appends: int) -> int | None:
+        """The lowest rewind boundary of the appends after append number
+        `appends`, or None when none of them replaced a sample."""
+        return min((c for a, c in self.cuts if a > appends), default=None)
+
+    def log_cut(self, cut: int) -> None:
+        self.cuts.append((self.appends, cut))
+        if len(self.cuts) > 32:
+            (_, c0), (a1, c1) = self.cuts[:2]
+            self.cuts[:2] = [(a1, min(c0, c1))]
+
 
 def advance_rolling(engine: TPUEngine, rt: RollingTile, storage, filters,
                     start: int, fetch_lo: int, end: int, max_series, tenant,
-                    drop_stale: bool, tracer=None) -> bool:
+                    drop_stale: bool, tracer=None, step: int = 0) -> bool:
     """Bring `rt` up to date with storage for a query fetching
     [fetch_lo, end]: fetch only the slice newer than the tile's covered
     range and append it on device. Returns False when the tile cannot be
     advanced (late/backfilled data, deletes, new series, capacity/int32
     exhausted) — the caller rebuilds via the cold path.
+
+    Under a dedup interval I the storage keeps one sample a window
+    ((b-1)I, bI], so a sample the tile holds is final only when its window
+    lies wholly at or before both the covered end and the oldest new
+    sample: the advance rewinds to the last window boundary B before
+    those, drops the tile's samples later than B and appends the refetch
+    of (B, end], deduplicated whole by the storage. A rewind deeper than
+    one query step (`step`) plus one interval declines; rt.cuts records
+    B for the [G, T] columns it makes stale.
 
     Timed as device:tile_build: its self time is the host bookkeeping
     and staging around the slice fetch (fetch:wait), the window slide
@@ -810,22 +860,20 @@ def advance_rolling(engine: TPUEngine, rt: RollingTile, storage, filters,
     with flightrec.phase("device:tile_build"):
         return _advance_rolling(engine, rt, storage, filters, start,
                                 fetch_lo, end, max_series, tenant,
-                                drop_stale, tracer)
+                                drop_stale, tracer, step)
 
 
 def _advance_rolling(engine: TPUEngine, rt: RollingTile, storage, filters,
                      start: int, fetch_lo: int, end: int, max_series,
-                     tenant, drop_stale: bool, tracer) -> bool:
+                     tenant, drop_stale: bool, tracer, step: int) -> bool:
     def no(reason: str) -> bool:
-        engine.last_roll_decline = reason
-        return False
+        return decline_roll(engine, reason)
 
     ver = getattr(storage, "data_version", None)
     if ver is None or \
             getattr(storage, "structural_version", None) != rt.structural:
         return no("deletes/retention changed visible data")
-    if getattr(storage, "dedup_interval_ms", 0):
-        return no("dedup interval set")  # buckets could straddle the append
+    interval = getattr(storage, "dedup_interval_ms", 0)
     if rt.lo_ms > fetch_lo:
         return no("tile history does not reach this query's lookback")
     if start < rt.base_ms:
@@ -840,21 +888,34 @@ def _advance_rolling(engine: TPUEngine, rt: RollingTile, storage, filters,
         if not compact_window(engine, rt, fetch_lo) or \
                 end - rt.base_ms >= 2**31 - 1:
             return no("int32 rebase exhausted")
+    late = False
     if ver != rt.version:
         try:
             lo_new = storage.min_appended_since(rt.version)
         except LookupError:
             return no("append log trimmed past tile version")
-        if lo_new is not None and lo_new <= rt.hi_ms:
+        late = lo_new is not None and lo_new <= rt.hi_ms
+        if late and not interval:
             return no("late data landed inside the covered range")
-    if end > rt.hi_ms:
+    cut = None
+    if interval and (late or end > rt.hi_ms):
+        b = ((lo_new if late else rt.hi_ms + 1) - 1) // interval * interval
+        if b < rt.hi_ms:
+            if rt.hi_ms - b > step + interval:
+                return no(REWIND_TOO_DEEP)
+            if b < rt.lo_ms:
+                return no("rewind reaches before the tile's history")
+            cut = b
+    if end > rt.hi_ms or cut is not None:
         # extend coverage: anything in (hi, end] — new ingest OR data that
         # simply lay beyond the previous query's fetch bound — appends in
-        # one slice fetch
-        qt = tracer.new_child("slice fetch (%d, %d]", rt.hi_ms, end) \
+        # one slice fetch; a rewind refetches from its boundary on
+        lo_f = rt.hi_ms if cut is None else cut
+        hi_f = max(end, rt.hi_ms)
+        qt = tracer.new_child("slice fetch (%d, %d]", lo_f, hi_f) \
             if tracer is not None else None
         try:
-            cols = storage.search_columns(filters, rt.hi_ms + 1, end,
+            cols = storage.search_columns(filters, lo_f + 1, hi_f,
                                           max_series=max_series,
                                           tenant=tenant)
         except ResourceWarning as e:
@@ -871,13 +932,13 @@ def _advance_rolling(engine: TPUEngine, rt: RollingTile, storage, filters,
         if cols.n_series:
             qa = tracer.new_child("device append") if tracer is not None \
                 else None
-            ok = _append_cols(engine, rt, cols, fetch_lo)
+            ok = _append_cols(engine, rt, cols, fetch_lo, cut)
             if qa is not None:
                 qa.donef("%d samples -> row tails", cols.n_samples)
             if not ok:
                 return no(engine.last_roll_decline)
-            rt.segments.append((rt.hi_ms + 1, end, cols.n_samples))
-        rt.hi_ms = end
+            rt.segments.append((lo_f + 1, hi_f, cols.n_samples))
+        rt.hi_ms = hi_f
     rt.version = ver
     return True
 
@@ -926,8 +987,10 @@ def compact_window(engine: TPUEngine, rt: RollingTile,
 
 
 def _append_cols(engine: TPUEngine, rt: RollingTile, cols,
-                 fetch_lo: int) -> bool:
-    """Scatter a fetched slice (ColumnarSeries) onto the tile tails."""
+                 fetch_lo: int, cut: int | None = None) -> bool:
+    """Scatter a fetched slice (ColumnarSeries) onto the tile tails; with
+    a rewind boundary `cut` (absolute ms) each row first drops its samples
+    later than it (the slice then holds their replacements)."""
     from ..ops.device_rollup import append_tile
     rows_idx = np.empty(cols.n_series, dtype=np.int64)
     for i, rn in enumerate(cols.raw_names):
@@ -936,6 +999,8 @@ def _append_cols(engine: TPUEngine, rt: RollingTile, cols,
             engine.last_roll_decline = "new series appeared"
             return False
         rows_idx[i] = r
+    # under a rewind the host does not know how many samples each row
+    # drops: the headroom check counts them as kept
     new_n = rt.counts_host[rows_idx] + cols.counts
     if int(new_n.max()) > rt.n_cap:
         # window-slide compaction before giving up: free the columns
@@ -979,7 +1044,15 @@ def _append_cols(engine: TPUEngine, rt: RollingTile, cols,
         engine.cache().invalidate(rt.adopted_key)
         rt.adopted_key = None
     ts_t, v_t, counts_t = ts_t0, v_t0, counts_t0
-    if engine.series_shards() > 1:
+    if cut is not None:
+        # the dedup rewind has no sharded twin: a mesh never makes a
+        # resident window under a dedup interval (eval: DEDUP_ON_MESH)
+        from ..ops.device_rollup import rewind_append_tile
+        cut_rel = np.int32(cut - rt.base_ms)
+
+        def append(*a):
+            return rewind_append_tile(*a, cut_rel)
+    elif engine.series_shards() > 1:
         # the same body with the tail's shardings declared from the rule
         # table: each device is sent the staged rows it holds, no more
         from ..parallel.mesh import cached_sharded_append_tile
@@ -995,10 +1068,39 @@ def _append_cols(engine: TPUEngine, rt: RollingTile, cols,
         new_ts.nbytes + new_vals.nbytes + new_counts.nbytes,
         lambda: append(ts_t, v_t, counts_t, new_ts, new_vals,
                        new_counts)) + (v0,)
-    rt.counts_host[rows_idx] = new_n
-    rt.n_samples += cols.n_samples
     rt.appends += 1
+    if cut is None:
+        rt.counts_host[rows_idx] = new_n
+        rt.n_samples += cols.n_samples
+        return True
+    # the rows' new lengths come back from the device (S int32)
+    counts = _pull_host(rt.tiles[2], np.int32)[
+        :rt.counts_host.shape[0]].astype(np.int64)
+    total = int(counts.sum())
+    dropped = rt.n_samples + cols.n_samples - total
+    rt.counts_host = counts
+    rt.n_samples = total
+    _drop_segments_after(rt, cut, dropped)
+    rt.log_cut(cut)
+    if dropped:
+        _ROLL_REWINDS.inc()
     return True
+
+
+def _drop_segments_after(rt: RollingTile, cut: int, dropped: int) -> None:
+    """Take `dropped` samples later than `cut` out of the sample-accounting
+    segments: those wholly later go, the one that holds `cut` ends there
+    (segments are disjoint ranges [lo, hi], oldest first)."""
+    segs = []
+    for lo, hi, n in rt.segments:
+        if lo > cut:
+            dropped -= n
+        else:
+            segs.append((lo, hi, n))
+    if segs and segs[-1][1] > cut:
+        lo, _, n = segs[-1]
+        segs[-1] = (lo, cut, max(n - max(dropped, 0), 0))
+    rt.segments = segs
 
 
 def aux_cache(engine: TPUEngine):
