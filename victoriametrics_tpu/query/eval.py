@@ -605,8 +605,11 @@ def _rollup_from_storage(ec: EvalConfig, func: str, re_: RollupExpr,
             return _cache_rollup(ec, ckey,
                                  _finish_rollup(series, out_rows,
                                                 keep_name))
-        if ec.tpu is not None:
-            from .tpu_engine import try_rollup_tpu
+        if ec.tpu is None:
+            return _cache_rollup(ec, ckey, _host_rollup(
+                ec, func, series, cfg, args, keep_name))
+        from .tpu_engine import try_rollup_tpu, wide_hand_off
+        with wide_hand_off() as wide:
             with ec.tracer.new_child("tpu rollup %s", func) as qt:
                 got = try_rollup_tpu(ec.tpu, func, series, cfg, args,
                                      cache_key=_tile_cache_key(ec, me, cfg,
@@ -617,28 +620,31 @@ def _rollup_from_storage(ec: EvalConfig, func: str, re_: RollupExpr,
                                          _finish_rollup(series, got,
                                                         keep_name))
                 qt.donef("fell back to host")
+            wide.to_host()
+            return _cache_rollup(ec, ckey, _host_rollup(
+                ec, func, series, cfg, args, keep_name))
 
-        with ec.tracer.new_child("host rollup %s", func) as qt:
-            if len(series) >= 8 and _rnp.batch_supported(func, args):
-                from ..ops import rollup_np
-                rows = rollup_np.rollup_batch(
-                    func, [(sd.timestamps, sd.values) for sd in series],
-                    cfg, args)
-                if rows is not None:
-                    qt.donef("%d series (batched)", len(series))
-                    return _cache_rollup(
-                        ec, ckey, _finish_rollup(series, list(rows),
-                                                 keep_name))
-            out_rows = []
-            for i, sd in enumerate(series):
-                if i % 256 == 0:
-                    ec.check_deadline()
-                vals = rollup_series(func, sd.timestamps, sd.values, cfg,
-                                     args)
-                out_rows.append(vals)
-            qt.donef("%d series", len(out_rows))
-        return _cache_rollup(ec, ckey,
-                             _finish_rollup(series, out_rows, keep_name))
+
+def _host_rollup(ec: EvalConfig, func: str, series, cfg: RollupConfig,
+                 args: tuple, keep_name: bool) -> list[Timeseries]:
+    """The host's f64 rollup of fetched series, batched where it can."""
+    from ..ops import rollup_np
+    with ec.tracer.new_child("host rollup %s", func) as qt:
+        if len(series) >= 8 and rollup_np.batch_supported(func, args):
+            rows = rollup_np.rollup_batch(
+                func, [(sd.timestamps, sd.values) for sd in series],
+                cfg, args)
+            if rows is not None:
+                qt.donef("%d series (batched)", len(series))
+                return _finish_rollup(series, list(rows), keep_name)
+        out_rows = []
+        for i, sd in enumerate(series):
+            if i % 256 == 0:
+                ec.check_deadline()
+            out_rows.append(rollup_series(func, sd.timestamps, sd.values,
+                                          cfg, args))
+        qt.donef("%d series", len(out_rows))
+    return _finish_rollup(series, out_rows, keep_name)
 
 
 def _aggregate_absent_over_time(ec: EvalConfig, expr,
@@ -1803,11 +1809,19 @@ def _try_host_fused_aggr(ec: EvalConfig, ae) -> list[Timeseries] | None:
 
 
 def _eval_aggr(ec: EvalConfig, ae: AggrFuncExpr) -> list[Timeseries]:
-    name = ae.name
+    if ec.tpu is None:
+        return _eval_aggr_host(ec, ae)
+    from .tpu_engine import wide_hand_off
+    with wide_hand_off() as wide:
+        fused = _try_device_fused_aggr(ec, ae)
+        if fused is not None:
+            return fused
+        wide.to_host()
+        return _eval_aggr_host(ec, ae)
 
-    fused = _try_device_fused_aggr(ec, ae)
-    if fused is not None:
-        return fused
+
+def _eval_aggr_host(ec: EvalConfig, ae: AggrFuncExpr) -> list[Timeseries]:
+    name = ae.name
     chunked = _try_host_chunked_aggr(ec, ae)
     if chunked is not None:
         return chunked
@@ -1827,14 +1841,16 @@ def _eval_aggr(ec: EvalConfig, ae: AggrFuncExpr) -> list[Timeseries]:
         k = float(eval_expr(ec, ae.args[0])[0].values[0])
         if np.isnan(k) or k < 0:
             k = 0.0  # getIntK clamps (aggr.go:793)
-        if name not in ("limitk", "outliersk") and not np.isinf(k):
+        if ec.tpu is None or name in ("limitk", "outliersk") or \
+                np.isinf(k):
+            return _eval_topk_host(ec, ae, name, k, remaining)
+        from .tpu_engine import wide_hand_off
+        with wide_hand_off() as wide:
             got = _try_device_topk(ec, ae, name, k, remaining)
             if got is not None:
                 return got
-        series = eval_expr(ec, ae.args[1])
-        if np.isinf(k):
-            k = float(len(series))
-        return _eval_topk_family(ec, ae, name, k, series, remaining)
+            wide.to_host()
+            return _eval_topk_host(ec, ae, name, k, remaining)
     if name == "quantile":
         phi = float(eval_expr(ec, ae.args[0])[0].values[0])
         series = eval_expr(ec, ae.args[1])
@@ -2063,6 +2079,13 @@ def _try_device_topk(ec, ae, name: str, k: float,
     return _finish_rollup_names(
         (series[i].metric_name for i, _ in got),
         [vals for _, vals in got], keep_name)
+
+
+def _eval_topk_host(ec, ae, name: str, k: float, remaining):
+    series = eval_expr(ec, ae.args[1])
+    if np.isinf(k):
+        k = float(len(series))
+    return _eval_topk_family(ec, ae, name, k, series, remaining)
 
 
 def _eval_topk_family(ec, ae, name, k, series,

@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import threading
 import time
 
 import numpy as np
@@ -198,6 +199,15 @@ _TILE_BUILD_ROWS = {
     for path in ("native", "python", "dense")}
 # series-axis size of the newest engine's mesh (0 until an engine is built)
 _SERIES_SHARDS = metricslib.REGISTRY.gauge("vm_device_series_shards")
+# live rows of f32 tiles declined for their range (V0Info.wide_range), by
+# the path that rolled them up: "host" the f64 fallback, "device" a device
+# path for such tiles, which none serves yet (both 0 from import)
+_WIDE_ROWS = {
+    path: metricslib.REGISTRY.counter(metricslib.format_name(
+        "vm_device_wide_rows_total", {"path": path}))
+    for path in ("host", "device")}
+# this thread's pending wide decline (`rows`) and open hand-off (`open`)
+_WIDE = threading.local()
 # resident-window advances that fell back to a cold rebuild, by reason, and
 # advances under a dedup interval that dropped provisional samples before
 # appending (the two dedup reasons and the rewinds read 0 from import)
@@ -211,7 +221,9 @@ def _roll_declines(reason: str):
         "vm_device_roll_declines_total", {"reason": reason}))
 
 
-for _reason in (DEDUP_ON_MESH, REWIND_TOO_DEEP):
+# an append whose rebased values reach F32_SAFE_RANGE (V0Info.wide_range)
+WIDE_APPEND = "append exceeds the f32-safe rebased range"
+for _reason in (DEDUP_ON_MESH, REWIND_TOO_DEEP, WIDE_APPEND):
     _roll_declines(_reason)
 
 
@@ -412,7 +424,7 @@ def try_rollup_tpu(engine: TPUEngine, func: str, series, cfg: RollupConfig,
         # run straight on HBM-resident data
         cache.put_device(key, tiles)
     from ..ops.device_rollup import MIN_TS_NONE, normalized_cfg
-    if _counter_unsafe(engine, func, tiles):
+    if _counter_unsafe(engine, func, tiles, len(series)):
         return None
     ts_t, v_t, counts, v0 = tiles
     out = timed_kernel_call("rollup_tile", rollup_tile, func, ts_t, v_t,
@@ -469,7 +481,7 @@ def try_topk_rollup_tpu(engine: TPUEngine, name: str, k: float, func: str,
     if tiles is None:
         tiles = _upload_tiles(engine, series, cfg)
         cache.put_device(key, tiles)
-    if _counter_unsafe(engine, func, tiles):
+    if _counter_unsafe(engine, func, tiles, len(series)):
         return None
     ts_t, v_t, counts, v0 = tiles
     v0d = _v0_dev(engine, v0)
@@ -545,7 +557,7 @@ def try_aggr_rollup_tpu(engine: TPUEngine, aggr: str, func: str, series,
     if tiles is None:
         tiles = _upload_tiles(engine, series, cfg)
         cache.put_device(key, tiles)
-    if _counter_unsafe(engine, func, tiles):
+    if _counter_unsafe(engine, func, tiles, len(series)):
         return None
     return _dispatch_fused(engine, aggr, func, tiles,
                            place_series_vector(engine, "group_ids", gids),
@@ -604,11 +616,58 @@ def _v0_dev(engine: TPUEngine, v0):
     return v0.dev
 
 
-def _counter_unsafe(engine: TPUEngine, func: str, tiles) -> bool:
+def _counter_unsafe(engine: TPUEngine, func: str, tiles, rows: int) -> bool:
     """True when `func` reads sample values but this f32 tile's rebased
-    dynamic range exceeds the f32-safe bound (see V0Info.wide_range)."""
+    dynamic range exceeds the f32-safe bound (see V0Info.wide_range).  A
+    decline leaves the tile's `rows` live rows to this thread's
+    wide_hand_off, which counts them as the host takes them."""
     v0 = tiles[3]
-    return v0 is not None and v0.wide_range and func not in VALUE_FREE_FUNCS
+    if v0 is None or not v0.wide_range or func in VALUE_FREE_FUNCS:
+        return False
+    _WIDE.rows = rows
+    return True
+
+
+class wide_hand_off:
+    """One device attempt and the host path after it::
+
+        with wide_hand_off() as wide:
+            got = <device attempt>
+            if got is None:
+                wide.to_host()
+                <host path>
+
+    Where the attempt declined a tile for its range (_counter_unsafe),
+    to_host() counts the tile's live rows in
+    vm_device_wide_rows_total{path="host"} and times the rest of the
+    scope as the query phase eval:wide_host: the host's f64 rollup and
+    aggregation of those rows (the refetch, the cache and the device
+    phases nested in it keep their own members).  A scope inside an open
+    one counts and times nothing again: the host path of a declined
+    fused aggregate meets the same tile at the per-series entry."""
+
+    __slots__ = ("_phase",)
+
+    def __enter__(self):
+        _WIDE.rows = 0
+        self._phase = None
+        return self
+
+    def to_host(self) -> None:
+        rows, _WIDE.rows = getattr(_WIDE, "rows", 0), 0
+        if rows and self._phase is None and \
+                not getattr(_WIDE, "open", False):
+            _WIDE_ROWS["host"].inc(rows)
+            _WIDE.open = True
+            self._phase = flightrec.phase("eval:wide_host")
+            self._phase.__enter__()
+
+    def __exit__(self, *exc):
+        _WIDE.rows = 0
+        if self._phase is not None:
+            _WIDE.open = False
+            self._phase.__exit__(*exc)
+        return False
 
 
 def place_series_vector(engine: TPUEngine, name: str, a: np.ndarray,
@@ -936,7 +995,7 @@ def _advance_rolling(engine: TPUEngine, rt: RollingTile, storage, filters,
             if qa is not None:
                 qa.donef("%d samples -> row tails", cols.n_samples)
             if not ok:
-                return no(engine.last_roll_decline)
+                return False    # _append_cols counted its reason
             rt.segments.append((lo_f + 1, hi_f, cols.n_samples))
         rt.hi_ms = hi_f
     rt.version = ver
@@ -996,8 +1055,7 @@ def _append_cols(engine: TPUEngine, rt: RollingTile, cols,
     for i, rn in enumerate(cols.raw_names):
         r = rt.row_of_raw.get(rn)
         if r is None:
-            engine.last_roll_decline = "new series appeared"
-            return False
+            return decline_roll(engine, "new series appeared")
         rows_idx[i] = r
     # under a rewind the host does not know how many samples each row
     # drops: the headroom check counts them as kept
@@ -1006,12 +1064,10 @@ def _append_cols(engine: TPUEngine, rt: RollingTile, cols,
         # window-slide compaction before giving up: free the columns
         # holding samples older than this query's fetch bound
         if not compact_window(engine, rt, fetch_lo):
-            engine.last_roll_decline = "column headroom exhausted"
-            return False
+            return decline_roll(engine, "column headroom exhausted")
         new_n = rt.counts_host[rows_idx] + cols.counts
         if int(new_n.max()) > rt.n_cap:
-            engine.last_roll_decline = "column headroom exhausted"
-            return False
+            return decline_roll(engine, "column headroom exhausted")
     ts_t0, v_t0, counts_t0, v0 = rt.tiles
     S_tile = int(ts_t0.shape[0])
     K = int(cols.ts.shape[1])
@@ -1033,9 +1089,7 @@ def _append_cols(engine: TPUEngine, rt: RollingTile, cols,
         finite = sub[np.isfinite(sub)]
         if not v0.wide_range and finite.size and \
                 float(np.abs(finite).max()) >= F32_SAFE_RANGE:
-            engine.last_roll_decline = \
-                "append exceeds the f32-safe rebased range"
-            return False
+            return decline_roll(engine, WIDE_APPEND)
     new_vals[rows_idx, :K] = vals_in
     new_counts[rows_idx] = cols.counts
     # the old buffers are donated: drop the TileCache reference first so no
@@ -1191,7 +1245,7 @@ def try_quantile_rollup_tpu(engine: TPUEngine, phi: float, func: str,
     if tiles is None:
         tiles = _upload_tiles(engine, series, cfg)
         cache.put_device(key, tiles)
-    if _counter_unsafe(engine, func, tiles):
+    if _counter_unsafe(engine, func, tiles, len(series)):
         return None
     return run_quantile_on_tiles(
         engine, phi, func, tiles,

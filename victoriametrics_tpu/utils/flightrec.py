@@ -285,7 +285,8 @@ def instant(name: str, arg=None) -> None:
 #: first use.
 QUERY_PHASES = (
     "serve:admission", "serve:rows", "serve:json", "serve:send",
-    "serve:other", "eval:other", "eval:transform", "fetch:wait",
+    "serve:other", "eval:other", "eval:transform", "eval:wide_host",
+    "fetch:wait",
     "cache:get", "cache:put", "cache:merge",
     "device:tile_build", "device:upload", "device:execute",
     "device:download", "device:compile")
