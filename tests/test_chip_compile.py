@@ -108,6 +108,41 @@ def test_rollup_tile(one, func):
                             one((S,), np.float32)).compile())
 
 
+# the network panel's window (node-net8k): 4096 rows x 1856 columns of
+# reset-corrected increments, 1024 hosts; the cold grid and the resident
+# window's 4-step tail
+NS, NN, NG = 4096, 1856, 1024
+
+
+@pytest.mark.parametrize("func,t", [("rate", T), ("rate", 5),
+                                    ("increase", T), ("irate", T)])
+def test_fused_increments(one, func, t):
+    from victoriametrics_tpu.ops.device_rollup import rollup_aggregate_tile
+    i32 = one((), np.int32)
+    lowered = rollup_aggregate_tile.lower(
+        func, "sum", *_tile(one, NS, NN), one((NS,), np.int32),
+        _cfg(func, t), NG, i32, i32, one((NS,), np.float32),
+        increments=True)
+    assert "HIGHEST" in lowered.as_text()
+    _fits(lowered.compile())
+
+
+def test_increments_tail_and_slide(one):
+    """The wide window's append (16 f32 columns, donated) and its slide,
+    which also sums what it drops (still donated)."""
+    from victoriametrics_tpu.ops.device_rollup import (append_tile,
+                                                       compact_tile)
+    c = append_tile.lower(*_tile(one, NS, NN), one((NS, 16), np.int32),
+                          one((NS, 16), np.float32),
+                          one((NS,), np.int32)).compile()
+    assert c.memory_analysis().alias_size_in_bytes >= NS * NN * 8
+    i32 = one((), np.int32)
+    c = compact_tile.lower(*_tile(one, NS, NN), i32, i32,
+                           dropped_sums=True).compile()
+    assert c.memory_analysis().alias_size_in_bytes >= NS * NN * 8
+    _fits(c)
+
+
 def test_topk_select_tile(one):
     from victoriametrics_tpu.ops.device_rollup import topk_select_tile
     _fits(topk_select_tile.lower("rate", *_tile(one), _cfg("rate"), 10,

@@ -106,17 +106,28 @@ def test_counter_resets_small_base(engine):
 
 
 def test_counter_resets_large_base_falls_back(engine):
-    """A reset from a 1e9 base pushes the REBASED magnitude past 2^24:
-    every value-dependent func must refuse the tile (host f64 handles it);
-    value-free funcs still run."""
+    """A reset from a 1e9 base pushes the REBASED magnitude past 2^24: the
+    tile's value plane holds reset-corrected increments, on which the
+    counter family runs (rate and increase equal the host's f64 rows, the
+    fused sum too); every other value-dependent func refuses the tile
+    (host f64 handles it); value-free funcs still run."""
     # distinct seed: the tile fingerprint keys on (name, count, last ts)
     # and would otherwise collide with the small-base variant's tile
     series = _series(resets=True, seed=11)  # base 1e9, resets to ~1e6
-    for func in ("rate", "increase", "delta", "min_over_time"):
+    for func in ("rate", "increase"):
+        rows = try_rollup_tpu(engine, func, series, CFG, ())
+        assert rows is not None, func
+        _assert_close(np.stack(rows), _host_rows(func, series), 1e-6,
+                      f"{func} on a wide tile")
+    for func in ("delta", "min_over_time"):
         assert try_rollup_tpu(engine, func, series, CFG, ()) is None, func
     gids = np.zeros(len(series), np.int32)
-    assert try_aggr_rollup_tpu(engine, "sum", "rate", series, gids, 1,
-                               CFG) is None
+    out = try_aggr_rollup_tpu(engine, "sum", "rate", series, gids, 1, CFG)
+    assert out is not None
+    host = _host_rows("rate", series)
+    _assert_close(out[0], np.where(np.isnan(host).all(axis=0), np.nan,
+                                   np.nansum(host, axis=0)),
+                  1e-6, "sum(rate) on a wide tile")
     # value-free funcs are immune to value error: stay on device
     rows = try_rollup_tpu(engine, "count_over_time", series, CFG, ())
     assert rows is not None
@@ -128,8 +139,11 @@ def test_fractional_scale_wide_mantissa_falls_back(engine):
     """Value range < 2^24 but MANTISSA range >= 2^24 (fractional decimal
     scale 10^-3): the one f32 rounding happens on the rebased mantissa, so
     the value-space gate alone would silently cost integer exactness for
-    equality-sensitive funcs. Value-dependent funcs must refuse the tile;
-    value-free funcs still run (round-4 advisor finding)."""
+    equality-sensitive funcs. The tile is wide, so its value plane holds
+    increments taken in f64 (each 100-200, rounded once to f32, with the
+    reset classification in f64): rate runs there and equals the host's
+    rows; the other value-dependent funcs must refuse the tile; value-free
+    funcs still run (round-4 advisor finding)."""
     rng = np.random.default_rng(21)
     n = 140
     series = []
@@ -140,8 +154,12 @@ def test_fractional_scale_wide_mantissa_falls_back(engine):
         v = np.round(np.cumsum(rng.uniform(100.0, 200.0, n)), 3)
         mn = MetricName.from_dict({"__name__": "frac", "i": str(i)})
         series.append(SeriesData(mn, ts, v, raw_name=mn.marshal()))
-    for func in ("changes", "rate", "delta"):
+    for func in ("changes", "delta"):
         assert try_rollup_tpu(engine, func, series, CFG, ()) is None, func
+    rows = try_rollup_tpu(engine, "rate", series, CFG, ())
+    assert rows is not None
+    _assert_close(np.stack(rows), _host_rows("rate", series), 1e-6,
+                  "rate on wide-mantissa tile")
     rows = try_rollup_tpu(engine, "count_over_time", series, CFG, ())
     assert rows is not None
     _assert_close(np.stack(rows), _host_rows("count_over_time", series),

@@ -275,3 +275,72 @@ class TestRollingTile:
                 _check(host, dev, f"advance {k}")
         finally:
             store.close()
+
+    def test_strides_of_one_to_three_steps_compile_nothing_new(
+            self, tmp_path):
+        """A panel asked at strides of one to three steps in any order
+        (two panels of one dashboard asked in turn, in rounds): once a
+        3-step stride has run, the window's tails and appends run the
+        programs it compiled, whatever stride comes next, and every
+        answer matches the host."""
+        from victoriametrics_tpu.ops import device_rollup as dr
+        from victoriametrics_tpu.query.tpu_engine import TPUEngine
+        store = _mk_store(tmp_path, n_series=24)
+        try:
+            engine = TPUEngine(min_series=4)
+            # increase: rate's prev-sample gate reads the scrape-interval
+            # estimate, which reused columns keep from when they were
+            # computed (RollingTile's accuracy contract)
+            q = "sum by (instance)(increase(rt[5m]))"
+            end = T0
+            _run(store, q, engine, end - 300_000, end)
+            frontier = T0 + 285_000 + 15_000
+
+            def tick(k):
+                nonlocal end, frontier
+                _ingest_newer(store, frontier, n=4 * k, n_series=24)
+                frontier += 4 * k * 15_000
+                end += k * STEP
+                _check(_run(store, q, None, end - 300_000, end),
+                       _run(store, q, engine, end - 300_000, end),
+                       f"stride {k}")
+
+            tick(3)
+            sizes = (dr.rollup_aggregate_tile._cache_size(),
+                     dr.append_tile._cache_size())
+            for k in (1, 2, 3, 1, 2, 1):
+                tick(k)
+            assert (dr.rollup_aggregate_tile._cache_size(),
+                    dr.append_tile._cache_size()) == sizes
+            (rt,) = _rolling_tiles(engine)
+            assert rt.appends == 7
+            assert (rt.tail_floor, rt.append_floor) == (4, 16)
+        finally:
+            store.close()
+
+
+def _fresh_window():
+    from victoriametrics_tpu.query.tpu_engine import RollingTile
+    return RollingTile(None, T0, 0, T0, T0, 0, None, np.zeros(1, np.int64),
+                       {}, 0, None)
+
+
+@pytest.mark.parametrize("asks, steps", [
+    ([1, 1, 1], [1, 1, 1]),             # refreshed every step: as always
+    ([2, 1, 3, 1], [4, 4, 4, 4]),
+    ([1, 3, 1, 2], [1, 4, 4, 4]),
+    ([110, 1, 5, 1], [110, 1, 5, 1]),   # a catch-up leaves the floor
+])
+def test_tail_steps_stick_to_the_small_tail_the_window_ran(asks, steps):
+    rt = _fresh_window()
+    assert [rt.tail_steps(w) for w in asks] == steps
+
+
+@pytest.mark.parametrize("asks, cols", [
+    ([4, 5, 3], [8, 8, 8]),             # one step of 15 s scrapes a tick
+    ([9, 5, 13, 4], [16, 16, 16, 16]),
+    ([440, 5, 17, 5], [440, 8, 24, 8]),
+])
+def test_append_cols_stick_to_the_small_append_the_window_ran(asks, cols):
+    rt = _fresh_window()
+    assert [rt.append_cols(k) for k in asks] == cols

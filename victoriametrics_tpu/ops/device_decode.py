@@ -232,6 +232,34 @@ def stage_rows(ts_rows, val_rows, start_ms: int, value_dtype=np.float32,
     return StagedRows(planes, v0, risky, path, vals, counts)
 
 
+def counter_increments(vals: np.ndarray, counts: np.ndarray,
+                       prev: np.ndarray | None = None):
+    """Each row's reset-corrected increments, in float64, for the value
+    plane of a wide float32 tile (tpu_engine.V0Info): `vals` holds the
+    rows back to back, `counts` samples a row. inc[i] = v[i] - v[i-1]
+    where the counter did not fall; where it fell, upstream's
+    removeCounterResets rule (rollup_np.remove_counter_resets): a drop
+    under 1/8 of the value before it adds 0, a larger one v[i]. A row's
+    first sample adds 0, unless `prev` gives the row's value before it
+    (NaN: none). Returns (inc, last): `last` is each row's last value,
+    `prev` where a row is empty."""
+    counts = np.asarray(counts, np.int64)
+    starts = _row_starts(counts)
+    full = counts > 0
+    before = np.empty_like(vals)
+    before[1:] = vals[:-1]
+    before[starts[full]] = np.nan if prev is None else prev[full]
+    with np.errstate(invalid="ignore"):
+        inc = vals - before
+        fell = inc < 0
+        b, v = before[fell], vals[fell]
+        inc[fell] = np.where((b - v) * 8 < b, 0.0, v)
+    inc[np.isnan(before)] = 0.0
+    last = np.full(counts.size, np.nan) if prev is None else prev.copy()
+    last[full] = vals[starts[full] + counts[full] - 1]
+    return inc, last
+
+
 def _reconstruct(first, fdelta, d2, counts, n):
     """Device: values[i] = first + sum_{k<i} d1[k], d1 = [fdelta, fdelta+cum
     d2...] — double prefix sum in int32."""

@@ -19,7 +19,9 @@ sample axis, which XLA fuses into plain VPU work:
   min / max of monotone planes), and finishes on ``[S, T]`` blocks; the
   few row gathers left (``_gather``) read neighbours of ``lo`` / ``hi``.
 - counter resets: prefix sum of negative jumps (removeCounterResets,
-  rollup.go:921) over the whole row, before the pass.
+  rollup.go:921) over the whole row, before the pass; a wide float32 tile
+  holds the reset-corrected increments instead (``_rollup_increments``:
+  the counter family as window sums of them).
 - ``rollup_aggregate_tile`` / ``fleet_rollup_aggregate_*`` fuse the group
   reduction (a one-hot matmul for sums, segment min / max) behind it;
   ``append_tile`` / ``compact_tile`` maintain the resident window
@@ -236,10 +238,71 @@ def _masked_window_reduce(ts: jnp.ndarray, cfg: RollupConfig, specs):
     return res, grid
 
 
-@functools.partial(jax.jit, static_argnames=("func", "cfg"))
+# counter functions a tile of reset-corrected increments serves
+INCREMENT_FUNCS = frozenset({"increase", "increase_pure", "rate", "irate"})
+
+
+def _rollup_increments(func: str, ts: jnp.ndarray, inc: jnp.ndarray,
+                       counts: jnp.ndarray, cfg: RollupConfig, min_ts,
+                       v0) -> jnp.ndarray:
+    """The counter family on a plane of reset-corrected increments (a wide
+    float32 tile, tpu_engine.V0Info: inc[i] is the corrected counter's
+    step from sample i - 1 to i). Its window differences are window sums:
+    c_last - c_prev is the sum of inc over the window, c_last - c_first
+    that sum less the first window sample's inc, irate's last step the inc
+    at hi - 1, so no large cumulative value is ever rounded to float32 and
+    a row's first column is never read by a difference. The absolute
+    values increase's new-series baseline reads are v0 plus the sums of
+    inc before and in the window: coarse, but small where they decide."""
+    S, N = ts.shape
+    dtype = inc.dtype
+    nan = jnp.asarray(jnp.nan, dtype)
+    valid = _valid_mask(counts, N)
+    im = jnp.where(valid, inc, jnp.zeros((), dtype))
+    extra = {"rate": [(ts, "le_hi", "max"), (ts, "win", "min")],
+             "irate": [(ts, "le_hi", "max")],
+             "increase": [(im, "le_lo", "sum")],
+             "increase_pure": [(im, "le_lo", "sum")]}[func]
+    res, grid = _masked_window_reduce(
+        ts, cfg, [(None, "le_lo", "sum"), (None, "le_hi", "sum"),
+                  (ts, "le_lo", "max"), (im, "win", "sum")] + extra)
+    lo, hi, t_prev_i, wsum = res[:4]
+    have = hi > lo
+    has_prev = (lo >= 1) & (t_prev_i >= jnp.int32(min_ts))
+    two = hi - lo >= 2
+    if func in ("increase", "increase_pure"):
+        v0c = jnp.zeros((), dtype) if v0 is None else \
+            v0[:, None].astype(dtype)
+        before = v0c + res[4]               # the counter at lo - 1
+        nb = before + wsum                  # counted from 0
+        if func == "increase":
+            first = _gather(im, lo)
+            d = jnp.where(two, _gather(im, lo + 1), jnp.zeros((), dtype))
+            born = jnp.abs(before + first) < 10.0 * (jnp.abs(d) + 1.0)
+            nb = jnp.where(born, nb, wsum - first)
+        return jnp.where(have, jnp.where(has_prev, wsum, nb), nan)
+    mpi = _max_prev_interval_tile(ts, counts, cfg, min_ts)
+    has_gprev = has_prev & (
+        t_prev_i > (grid - cfg.lookback)[None, :] - mpi[:, None])
+    ok = have & (has_gprev | two)
+    # time spans are int32 differences, rounded once: a grid hours long
+    # puts its timestamps past f32's exact integers
+    if func == "rate":
+        dv = jnp.where(has_gprev, wsum, wsum - _gather(im, lo))
+        dt = jnp.where(has_gprev, res[4] - t_prev_i, res[4] - res[5])
+    else:
+        dv = _gather(im, hi - 1)
+        dt = res[4] - jnp.where(two, _gather(jnp.where(valid, ts, 0),
+                                              hi - 2), t_prev_i)
+    dt = dt.astype(dtype) / 1e3
+    return jnp.where(ok & (dt > 0), dv / dt, nan)
+
+
+@functools.partial(jax.jit, static_argnames=("func", "cfg", "increments"))
 def rollup_tile(func: str, ts: jnp.ndarray, values: jnp.ndarray,
                 counts: jnp.ndarray, cfg: RollupConfig,
-                min_ts=MIN_TS_NONE, v0=None) -> jnp.ndarray:
+                min_ts=MIN_TS_NONE, v0=None,
+                increments: bool = False) -> jnp.ndarray:
     """Windowed rollup over a padded tile -> [S, T] float array (NaN = gap).
 
     `min_ts` (traced) reproduces the evaluator's fetch truncation on tiles
@@ -247,7 +310,12 @@ def rollup_tile(func: str, ts: jnp.ndarray, values: jnp.ndarray,
     samples older than min_ts never seed prevValue / boundary transitions,
     exactly as if the fetch had started there. Window samples themselves
     are always newer than any fetch bound, so only prev-sample accesses are
-    gated."""
+    gated. `increments` (static) says `values` holds reset-corrected
+    increments (a wide float32 tile): only INCREMENT_FUNCS run there."""
+    if increments:
+        if func not in INCREMENT_FUNCS:
+            raise ValueError(f"{func!r} does not run on an increments tile")
+        return _rollup_increments(func, ts, values, counts, cfg, min_ts, v0)
     S, N = ts.shape
     dtype = values.dtype
     nan = jnp.asarray(jnp.nan, dtype)
@@ -696,12 +764,14 @@ def fleet_rollup_aggregate_tile(rollup_func: str, cfg: RollupConfig,
                                        fleet_min_ts, fleet_v0)
 
 
-@functools.partial(jax.jit, static_argnames=("rollup_func", "aggr", "cfg", "num_groups"))
+@functools.partial(jax.jit, static_argnames=("rollup_func", "aggr", "cfg",
+                                             "num_groups", "increments"))
 def rollup_aggregate_tile(rollup_func: str, aggr: str, ts: jnp.ndarray,
                           values: jnp.ndarray, counts: jnp.ndarray,
                           group_ids: jnp.ndarray, cfg: RollupConfig,
                           num_groups: int, shift=0,
-                          min_ts=MIN_TS_NONE, v0=None) -> jnp.ndarray:
+                          min_ts=MIN_TS_NONE, v0=None,
+                          increments: bool = False) -> jnp.ndarray:
     """Fused aggr(rollup(m[d])) over one tile -> [G, T].
 
     `shift` (traced int32, ms) rebases tile timestamps onto the cfg grid:
@@ -709,9 +779,10 @@ def rollup_aggregate_tile(rollup_func: str, aggr: str, ts: jnp.ndarray,
     query grid advances, so shift = query_start - tile_base. Time-valued
     funcs are not supported with shift != 0 (dispatch excludes them).
     `min_ts` is the query's fetch lower bound in the SHIFTED frame (see
-    rollup_tile); `v0` the per-series rebase offsets of f32 tiles."""
+    rollup_tile); `v0` the per-series rebase offsets of f32 tiles;
+    `increments` as rollup_tile's."""
     rolled = rollup_tile(rollup_func, ts - jnp.int32(shift), values, counts,
-                         cfg, min_ts, v0)
+                         cfg, min_ts, v0, increments)
     return aggregate_groups(aggr, rolled, group_ids, num_groups)
 
 
@@ -782,12 +853,13 @@ def fleet_append_tile(fleet_ts: jnp.ndarray, fleet_values: jnp.ndarray,
 
 
 def _compact_tile_body(ts: jnp.ndarray, values: jnp.ndarray,
-                       counts: jnp.ndarray, cutoff_rel, delta):
+                       counts: jnp.ndarray, cutoff_rel, delta,
+                       dropped_sums: bool = False):
     S, N = ts.shape
     k = jnp.arange(N, dtype=jnp.int32)[None, :]
     valid = k < counts[:, None]
-    drop = jnp.sum(valid & (ts < jnp.int32(cutoff_rel)), axis=1,
-                   dtype=jnp.int32)
+    gone = valid & (ts < jnp.int32(cutoff_rel))
+    drop = jnp.sum(gone, axis=1, dtype=jnp.int32)
     new_counts = counts - drop
     idx = jnp.clip(drop[:, None] + k, 0, N - 1)
     live = k < new_counts[:, None]
@@ -796,12 +868,16 @@ def _compact_tile_body(ts: jnp.ndarray, values: jnp.ndarray,
                     TS_PAD)
     v2 = jnp.where(live, jnp.take_along_axis(values, idx, axis=1),
                    jnp.zeros((), values.dtype))
+    if dropped_sums:
+        return ts2, v2, new_counts, jnp.sum(
+            jnp.where(gone, values, jnp.zeros((), values.dtype)), axis=1)
     return ts2, v2, new_counts
 
 
-@functools.partial(jax.jit, donate_argnums=(0, 1))
+@functools.partial(jax.jit, donate_argnums=(0, 1),
+                   static_argnames=("dropped_sums",))
 def compact_tile(ts: jnp.ndarray, values: jnp.ndarray, counts: jnp.ndarray,
-                 cutoff_rel, delta):
+                 cutoff_rel, delta, dropped_sums: bool = False):
     """Window-slide compaction of a rolling tile: drop each row's samples
     older than `cutoff_rel` (tile-relative ms, exclusive — samples AT the
     cutoff survive, matching the inclusive fetch lower bound), shift the
@@ -816,8 +892,13 @@ def compact_tile(ts: jnp.ndarray, values: jnp.ndarray, counts: jnp.ndarray,
     rollup (window masks exclude them; prev-sample accesses are gated by
     min_ts — see rollup_tile), so compacting at the CURRENT fetch_lo is
     invisible to this and every later query whose fetch bound is >= it;
-    older-reaching queries decline via RollingTile.lo_ms and rebuild."""
-    return _compact_tile_body(ts, values, counts, cutoff_rel, delta)
+    older-reaching queries decline via RollingTile.lo_ms and rebuild.
+
+    `dropped_sums` (static) adds a fourth output, each row's sum of the
+    values it drops [S]: what an increments tile's rows gained before the
+    slide (tpu_engine.compact_window folds it into their offsets)."""
+    return _compact_tile_body(ts, values, counts, cutoff_rel, delta,
+                              dropped_sums)
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1))

@@ -1102,9 +1102,9 @@ def _try_device_fused_aggr(ec: EvalConfig, ae: AggrFuncExpr
     from ..ops import rollup_np
     from .rollup_result_cache import RingBlock
     from .tpu_engine import (FUSED_AGGRS, RollingTile, advance_rolling,
-                             aux_get, aux_put, group_slots,
-                             place_series_vector, run_fused_on_tiles,
-                             run_quantile_on_tiles,
+                             aux_get, aux_put, count_wide_device,
+                             group_slots, place_series_vector,
+                             run_fused_on_tiles, run_quantile_on_tiles,
                              try_aggr_rollup_tpu, try_quantile_rollup_tpu)
     if func not in rollup_np.CORE_SUPPORTED or \
             (phi is None and ae.name not in FUSED_AGGRS):
@@ -1173,6 +1173,7 @@ def _try_device_fused_aggr(ec: EvalConfig, ae: AggrFuncExpr
                                                  tiles, gids_dev,
                                                  len(group_keys), cfg2)
                     qt.donef("resident tile, %d groups", len(group_keys))
+                count_wide_device(tiles, func, int(gids_dev.shape[0]))
                 count_window_hit()
                 return _emit(out, group_keys)
 
@@ -1267,8 +1268,9 @@ def _try_device_fused_aggr(ec: EvalConfig, ae: AggrFuncExpr
                     grid_end = start + ((end - start) // ec.step) * ec.step
                     w = n_new + redo
                     tail = kernel(RollupConfig(
-                        start=grid_end - w * ec.step, end=grid_end,
-                        step=ec.step, window=lookback))[:, 1:]
+                        start=grid_end - rt.tail_steps(w) * ec.step,
+                        end=grid_end, step=ec.step,
+                        window=lookback))[:, -w:]
                     rows_out = rb.commit(start, end, tail)
                     qk.donef("[%d, %d] tail, %d columns reused",
                              len(group_keys), w, rb.T - w)
@@ -1286,6 +1288,7 @@ def _try_device_fused_aggr(ec: EvalConfig, ae: AggrFuncExpr
                     rb.tile_appends = rt.appends
                 qt.donef("advanced tile (%d appends), %d groups",
                          rt.appends, len(group_keys))
+                count_wide_device(rt.tiles, func, len(rt.counts_host))
                 count_window_hit()
                 wcache.invalidate(("roll-declines",) + roll_state_key)
                 return _emit(rows_out, group_keys)

@@ -117,23 +117,39 @@ class V0Info:
 
     `wide_range` is True when any series' REBASED magnitude |v - v0|
     reaches 2^24 (f32's exact-integer limit) — e.g. a large-base counter
-    that resets mid-tile, or one that grows >16M within the window. The
-    rebase guarantees nothing there: every value-dependent func would see
-    ulp(|v - v0|)-sized noise, so they all fall back to the f64 host path
-    for such tiles (per-series patching is possible future work).
-    Value-free funcs (counts, timestamps) still run.
+    that resets mid-tile, or one that grows >16M within the window. A
+    rebased value plane guarantees nothing there (ulp(|v - v0|)-sized
+    noise), so such a tile's value plane holds each row's reset-corrected
+    INCREMENTS instead (device_decode.counter_increments: computed in f64
+    on the host, each rounded once to f32), and `last` keeps each row's
+    last raw value (float64) for the next append's first increment. The
+    counter family (device_rollup.INCREMENT_FUNCS) runs on that plane on
+    one device; every other value-dependent func falls back to the f64
+    host path, as do all of them where `last` is None (a wide tile with a
+    non-finite value carries rebased values, not increments). Value-free
+    funcs (counts, timestamps) still run. `offsets` of an increments tile
+    are each row's first value, plus what a window slide dropped
+    (compact_window): offsets + the row's increments is the corrected
+    counter.
 
     `dev` is the mesh engine's f32 copy on the devices, placed by the rule
     table at the first fused query and kept with the tile through every
     append and slide (they hand this object on), so a warm query sends
     nothing per-series up."""
 
-    __slots__ = ("offsets", "wide_range", "dev")
+    __slots__ = ("offsets", "wide_range", "last", "dev")
 
-    def __init__(self, offsets: np.ndarray, wide_range: bool):
+    def __init__(self, offsets: np.ndarray, wide_range: bool,
+                 last: np.ndarray | None = None):
         self.offsets = offsets
         self.wide_range = wide_range
+        self.last = last
         self.dev = None
+
+    @property
+    def increments(self) -> bool:
+        """True when the value plane holds reset-corrected increments."""
+        return self.last is not None
 
     def __getitem__(self, i):
         return self.offsets[i]
@@ -199,9 +215,9 @@ _TILE_BUILD_ROWS = {
     for path in ("native", "python", "dense")}
 # series-axis size of the newest engine's mesh (0 until an engine is built)
 _SERIES_SHARDS = metricslib.REGISTRY.gauge("vm_device_series_shards")
-# live rows of f32 tiles declined for their range (V0Info.wide_range), by
-# the path that rolled them up: "host" the f64 fallback, "device" a device
-# path for such tiles, which none serves yet (both 0 from import)
+# live rows of wide f32 tiles (V0Info.wide_range), once a query, by the
+# path that rolled them up: "host" the f64 fallback after a decline,
+# "device" the counter family on the increments plane (both 0 from import)
 _WIDE_ROWS = {
     path: metricslib.REGISTRY.counter(metricslib.format_name(
         "vm_device_wide_rows_total", {"path": path}))
@@ -223,7 +239,13 @@ def _roll_declines(reason: str):
 
 # an append whose rebased values reach F32_SAFE_RANGE (V0Info.wide_range)
 WIDE_APPEND = "append exceeds the f32-safe rebased range"
-for _reason in (DEDUP_ON_MESH, REWIND_TOO_DEEP, WIDE_APPEND):
+# a dedup rewind on an increments tile: the host no longer knows the last
+# raw value of the rows it shortens
+WIDE_REWIND = "dedup rewind on a wide tile"
+# a non-finite value in a tail bound for an increments tile
+WIDE_NONFINITE = "non-finite value in a wide tile's tail"
+for _reason in (DEDUP_ON_MESH, REWIND_TOO_DEEP, WIDE_APPEND, WIDE_REWIND,
+                WIDE_NONFINITE):
     _roll_declines(_reason)
 
 
@@ -424,14 +446,16 @@ def try_rollup_tpu(engine: TPUEngine, func: str, series, cfg: RollupConfig,
         # run straight on HBM-resident data
         cache.put_device(key, tiles)
     from ..ops.device_rollup import MIN_TS_NONE, normalized_cfg
-    if _counter_unsafe(engine, func, tiles, len(series)):
+    if _counter_unsafe(engine, func, tiles, len(series), increments=True):
         return None
     ts_t, v_t, counts, v0 = tiles
     out = timed_kernel_call("rollup_tile", rollup_tile, func, ts_t, v_t,
                             counts, normalized_cfg(func, cfg), MIN_TS_NONE,
-                            _v0_dev(engine, v0))
+                            _v0_dev(engine, v0),
+                            **_increments_kw(v0, func))
     # mesh tiles are row-padded; only the live rows come back
     rows = _pull_host(out)[:len(series)]
+    count_wide_device(tiles, func, len(series))
     if mode == "addback":
         rows = rows + v0[:len(series), None]  # NaN gaps stay NaN
     return list(rows)
@@ -557,11 +581,13 @@ def try_aggr_rollup_tpu(engine: TPUEngine, aggr: str, func: str, series,
     if tiles is None:
         tiles = _upload_tiles(engine, series, cfg)
         cache.put_device(key, tiles)
-    if _counter_unsafe(engine, func, tiles, len(series)):
+    if _counter_unsafe(engine, func, tiles, len(series), increments=True):
         return None
-    return _dispatch_fused(engine, aggr, func, tiles,
-                           place_series_vector(engine, "group_ids", gids),
-                           num_groups, cfg)
+    out = _dispatch_fused(engine, aggr, func, tiles,
+                          place_series_vector(engine, "group_ids", gids),
+                          num_groups, cfg)
+    count_wide_device(tiles, func, len(series))
+    return out
 
 
 def warmup(engine: TPUEngine, funcs=("rate", "increase", "default_rollup"),
@@ -616,16 +642,46 @@ def _v0_dev(engine: TPUEngine, v0):
     return v0.dev
 
 
-def _counter_unsafe(engine: TPUEngine, func: str, tiles, rows: int) -> bool:
+def _increments(v0, func: str) -> bool:
+    """The static kernel flag: `func` reads the increments this tile's
+    value plane holds (a value-free func reads no value plane at all)."""
+    from ..ops.device_rollup import INCREMENT_FUNCS
+    return v0 is not None and v0.increments and func in INCREMENT_FUNCS
+
+
+def _increments_kw(v0, func: str) -> dict:
+    """The kernels' `increments` keyword, given only where it is True: a
+    rebased tile calls the program it always did (an explicit default is a
+    jit cache entry of its own)."""
+    return {"increments": True} if _increments(v0, func) else {}
+
+
+def _counter_unsafe(engine: TPUEngine, func: str, tiles, rows: int,
+                    increments: bool = False) -> bool:
     """True when `func` reads sample values but this f32 tile's rebased
-    dynamic range exceeds the f32-safe bound (see V0Info.wide_range).  A
-    decline leaves the tile's `rows` live rows to this thread's
-    wide_hand_off, which counts them as the host takes them."""
+    dynamic range exceeds the f32-safe bound (see V0Info.wide_range),
+    unless the entry serves the tile's increments (`increments`: the
+    counter family on one device).  A decline leaves the tile's `rows`
+    live rows to this thread's wide_hand_off, which counts them as the
+    host takes them."""
     v0 = tiles[3]
     if v0 is None or not v0.wide_range or func in VALUE_FREE_FUNCS:
         return False
+    if increments and _increments(v0, func) and \
+            engine.series_shards() == 1:
+        return False
     _WIDE.rows = rows
     return True
+
+
+def count_wide_device(tiles, func: str, rows: int) -> None:
+    """Count a query's `rows` live rows of an increments tile that `func`
+    read as rolled up on the device
+    (vm_device_wide_rows_total{path="device"}); once a query, by the entry
+    that served it: the cold per-series or fused entry, or the resident
+    window's advance."""
+    if _increments(tiles[3], func):
+        _WIDE_ROWS["device"].inc(rows)
 
 
 class wide_hand_off:
@@ -713,6 +769,7 @@ def _dispatch_fused(engine: TPUEngine, aggr: str, func: str, tiles,
     gids_dev = _pad_rows(gids_dev, ts_t.shape[0], 0)
     cfg = normalized_cfg(func, cfg)
     if engine.series_shards() > 1:
+        # _counter_unsafe declines an increments tile on a mesh
         from ..parallel.mesh import cached_sharded_rollup_aggregate
         fn = cached_sharded_rollup_aggregate(engine.mesh, func, aggr, cfg,
                                              num_groups)
@@ -726,7 +783,8 @@ def _dispatch_fused(engine: TPUEngine, aggr: str, func: str, tiles,
                                 rollup_aggregate_tile, func, aggr, ts_t,
                                 v_t, counts, gids_dev, cfg, num_groups,
                                 np.int32(shift), np.int32(min_ts),
-                                _v0_dev(engine, v0))
+                                _v0_dev(engine, v0),
+                                **_increments_kw(v0, func))
     return _pull_host(out)
 
 
@@ -778,6 +836,11 @@ def _build_tiles(engine: TPUEngine, series, cfg: RollupConfig):
     staged = dd.stage_rows(ts_rows, val_rows, cfg.start, engine.value_dtype,
                            rebase=f32, gate=F32_SAFE_RANGE)
     planes, v0, risky = staged.planes, staged.v0, staged.risky
+    last = None
+    if f32 and risky and np.isfinite(staged.vals).all():
+        # a wide tile: its dense value plane holds the rows' increments
+        inc, last = dd.counter_increments(staged.vals, staged.counts)
+        planes = None
     _TILE_BUILD_ROWS[staged.path if planes is not None else "dense"].inc(
         len(series))
     if planes is not None:
@@ -799,7 +862,9 @@ def _build_tiles(engine: TPUEngine, series, cfg: RollupConfig):
         ts_t, v_t = dd.decode_tiles(*dev[:6], dev[6], dev[7], n_cap,
                                     engine.value_dtype, rebase=f32)
         return ts_t, v_t, dev[7], _pad_v0(v0, int(ts_t.shape[0]), risky)
-    if f32:  # the dense tile holds v - v0 (v0: the first finite value)
+    if last is not None:
+        val_rows = np.split(inc, np.cumsum(staged.counts)[:-1])
+    elif f32:  # the dense tile holds v - v0 (v0: the first finite value)
         val_rows = np.split(staged.vals - np.repeat(v0, staged.counts),
                             np.cumsum(staged.counts)[:-1])
     ts, vals, counts = pack_series(
@@ -808,17 +873,21 @@ def _build_tiles(engine: TPUEngine, series, cfg: RollupConfig):
         dtype=engine.value_dtype)
     ts_d = _put(ts, TS_PAD, name="ts")
     return (ts_d, _put(vals, name="values"), _put(counts, name="counts"),
-            _pad_v0(v0, int(ts_d.shape[0]), risky))
+            _pad_v0(v0, int(ts_d.shape[0]), risky, last))
 
 
-def _pad_v0(v0, n_rows: int, risky):
-    """Row-pad the host float64 rebase vector to the tile's padded row
-    count and wrap it as V0Info (None passes through for f64 engines)."""
+def _pad_v0(v0, n_rows: int, risky, last=None):
+    """Row-pad the host float64 rebase vector (and an increments tile's
+    last values, NaN in the padding) to the tile's padded row count and
+    wrap it as V0Info (None passes through for f64 engines)."""
     if v0 is None:
         return None
-    if v0.shape[0] < n_rows:
-        v0 = np.concatenate([v0, np.zeros(n_rows - v0.shape[0])])
-    return V0Info(v0, bool(risky))
+    pad = n_rows - v0.shape[0]
+    if pad > 0:
+        v0 = np.concatenate([v0, np.zeros(pad)])
+        if last is not None:
+            last = np.concatenate([last, np.full(pad, np.nan)])
+    return V0Info(v0, bool(risky), last)
 
 
 def tile_capacity(n: int) -> int:
@@ -826,6 +895,12 @@ def tile_capacity(n: int) -> int:
     columns) rounded to a multiple of 64, so rolling appends have room and
     rebuilt tiles land on few distinct compiled shapes."""
     return (max(n + 32, n * 5 // 4) + 63) // 64 * 64
+
+
+# The resident window's tail kernel and append compile one program a width
+# (RollingTile.tail_steps / append_cols): these keep the widths few.
+SMALL_TAIL_STEPS = 4
+APPEND_QUANTUM = 8
 
 
 class RollingTile:
@@ -850,7 +925,8 @@ class RollingTile:
 
     __slots__ = ("tiles", "base_ms", "n_cap", "lo_ms", "hi_ms", "version",
                  "structural", "counts_host", "row_of_raw", "n_samples",
-                 "adopted_key", "appends", "segments", "cuts")
+                 "adopted_key", "appends", "segments", "cuts",
+                 "tail_floor", "append_floor")
 
     def __init__(self, tiles, base_ms, n_cap, lo_ms, hi_ms, version,
                  structural, counts_host, row_of_raw, n_samples,
@@ -878,6 +954,36 @@ class RollingTile:
         # folding the two oldest entries into one (the newer count, the
         # lower boundary): a consumer that far behind recomputes more
         self.cuts: list[tuple[int, int]] = []
+        # the widest small tail kernel (grid steps) and append (columns)
+        # this window has run: see tail_steps / append_cols
+        self.tail_floor = 1
+        self.append_floor = APPEND_QUANTUM
+
+    def tail_steps(self, w: int) -> int:
+        """Grid steps of the tail kernel that computes the window's `w`
+        newest columns (the caller keeps the last `w`; one more leading
+        column keeps the sub-grid's start before its end). A tail of two
+        to SMALL_TAIL_STEPS steps runs SMALL_TAIL_STEPS, and once the
+        window has run that, its one-step tails run it too. A panel
+        refreshed every step runs the one-step program alone; one whose
+        refreshes come back at strides of one to three steps in any order
+        runs whatever its first strides compiled, never a new program
+        inside a steady stretch of them."""
+        if w > SMALL_TAIL_STEPS:    # a catch-up: the floor stays
+            return w
+        if w > 1:
+            self.tail_floor = SMALL_TAIL_STEPS
+        return self.tail_floor
+
+    def append_cols(self, k: int) -> int:
+        """Staged columns of an append of `k` samples a row: a multiple of
+        APPEND_QUANTUM, and never narrower than the widest append of up to
+        2 x APPEND_QUANTUM columns this window has run (as tail_steps)."""
+        cols = -(-k // APPEND_QUANTUM) * APPEND_QUANTUM
+        if cols > 2 * APPEND_QUANTUM:
+            return cols
+        self.append_floor = max(self.append_floor, cols)
+        return self.append_floor
 
     def samples_in_range(self, fetch_lo: int) -> int:
         return sum(n for _, seg_hi, n in self.segments if seg_hi >= fetch_lo)
@@ -1027,9 +1133,17 @@ def compact_window(engine: TPUEngine, rt: RollingTile,
         engine.cache().invalidate(rt.adopted_key)
         rt.adopted_key = None
     ts_t, v_t, counts_t, v0 = rt.tiles
-    new_ts, new_vals, new_counts = timed_kernel_call(
+    inc_tile = v0 is not None and v0.increments
+    # a narrow tile calls the program it always did (as _increments_kw)
+    out = timed_kernel_call(
         "compact_tile", compact_tile, ts_t, v_t, counts_t,
-        np.int32(cutoff_rel), np.int32(cutoff_rel))
+        np.int32(cutoff_rel), np.int32(cutoff_rel),
+        **({"dropped_sums": True} if inc_tile else {}))
+    new_ts, new_vals, new_counts = out[:3]
+    if inc_tile:
+        # the increments the slide drops move into the offsets, so offsets
+        # + a row's increments stays the corrected counter (S values down)
+        v0.offsets = v0.offsets + _pull_host(out[3])
     counts_host = np.asarray(new_counts).astype(np.int64)
     rt.tiles = (new_ts, new_vals, new_counts, v0)
     rt.counts_host = counts_host
@@ -1071,13 +1185,30 @@ def _append_cols(engine: TPUEngine, rt: RollingTile, cols,
     ts_t0, v_t0, counts_t0, v0 = rt.tiles
     S_tile = int(ts_t0.shape[0])
     K = int(cols.ts.shape[1])
-    K_pad = (K + 7) // 8 * 8  # few distinct compiled append shapes
+    K_pad = rt.append_cols(K)
+    inc_tile = v0 is not None and v0.increments
     new_ts = np.zeros((S_tile, K_pad), dtype=np.int32)
-    new_vals = np.zeros((S_tile, K_pad), dtype=np.float64)
+    # an increments tail is rounded to f32 here, once
+    new_vals = np.zeros((S_tile, K_pad),
+                        dtype=np.float32 if inc_tile else np.float64)
     new_counts = np.zeros(S_tile, dtype=np.int32)
     new_ts[rows_idx, :K] = (cols.ts - rt.base_ms).astype(np.int32)
     vals_in = cols.vals
-    if v0 is not None:
+    last = None
+    if inc_tile:
+        # the tail's increments against each row's last raw value, the
+        # reset rule at the seam included (f64; new_vals rounds each once)
+        if cut is not None:
+            return decline_roll(engine, WIDE_REWIND)
+        live = np.arange(K)[None, :] < cols.counts[:, None]
+        flat = cols.vals[live]
+        if not np.isfinite(flat).all():
+            return decline_roll(engine, WIDE_NONFINITE)
+        from ..ops.device_decode import counter_increments
+        inc, last = counter_increments(flat, cols.counts, v0.last[rows_idx])
+        vals_in = np.zeros_like(cols.vals)
+        vals_in[live] = inc
+    elif v0 is not None:
         # f32 tiles hold rebased values: rebase the appended slice by the
         # SAME per-row offsets (f64 host subtraction, one f32 rounding).
         # An append pushing the rebased magnitude past the f32-safe range
@@ -1123,6 +1254,8 @@ def _append_cols(engine: TPUEngine, rt: RollingTile, cols,
         lambda: append(ts_t, v_t, counts_t, new_ts, new_vals,
                        new_counts)) + (v0,)
     rt.appends += 1
+    if last is not None:
+        v0.last[rows_idx] = last
     if cut is None:
         rt.counts_host[rows_idx] = new_n
         rt.n_samples += cols.n_samples
