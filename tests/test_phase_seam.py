@@ -293,7 +293,7 @@ want = [f'vm_query_phase_cpu_seconds_total{{phase="{n}"}}'
 want += [f'vm_fetch_phase_cpu_seconds_total{{phase="{n}"}}' for n in fetch]
 want += [f'vm_query_background_overlap_seconds_total{{kind="{k}"}}'
          for k in ("flush", "merge", "convert", "gc")]
-assert len(want) == 17 + 8 + 4
+assert len(want) == 18 + 8 + 4
 for name in want:
     assert float(lines[name]) == 0.0, name
 print("ok")

@@ -44,6 +44,15 @@ from .server import HTTPServer, Request, Response, StreamingResponse
 #: scatter-gather responses that came back incomplete (a storage node
 #: was down/slow) — whether served as isPartial=true or denied as 503
 _PARTIAL_TOTAL = metricslib.REGISTRY.counter("vm_partial_results_total")
+#: query_range calls by the route that served them: "window" the device's
+#: resident window (a full eval), "cache_hit" / "cache_partial" /
+#: "cache_miss" the rollup result cache (a full hit, a tail eval merged
+#: into the cached prefix, a full eval stored), "direct" no cache at all
+#: (nocache=1, a one-point grid, a wall-clock function); 0 from import
+_ROUTE = {route: metricslib.REGISTRY.counter(metricslib.format_name(
+    "vm_serve_route_total", {"route": route}))
+    for route in ("window", "cache_hit", "cache_partial", "cache_miss",
+                  "direct")}
 
 
 def parse_time(s: str, default_ms: int) -> int:
@@ -548,6 +557,7 @@ class PrometheusAPI:
                     if req.arg("nocache") == "1":
                         # reference -search.disableCache / nocache=1 arg
                         ec.disable_cache = True
+                        _ROUTE["direct"].inc()
                         rows = exec_query(ec, q)
                     else:
                         rows = self._exec_range_cached(ec, q, now)
@@ -743,8 +753,10 @@ class PrometheusAPI:
         cacheable = (ec.n_points > 1
                      and not self._UNCACHEABLE_RE.search(q))
         if not cacheable:
+            _ROUTE["direct"].inc()
             return exec_query(ec, q)
         if ec.tpu is not None and _device_window_ready(ec, q):
+            _ROUTE["window"].inc()
             # device-resident serving: the device plane holds a rolling
             # window for this query shape, so the FULL eval is O(new
             # samples) — advance_rolling fetches/uploads only the tail
@@ -759,6 +771,7 @@ class PrometheusAPI:
             return rows
         cached, new_start = rcache.get(ec, q, now_ms)
         if cached is not None and new_start > ec.end:
+            _ROUTE["cache_hit"].inc()
             ec.tracer.printf("rollup cache: full hit")
             # same shape as the partial-hit return below: an in-place
             # merge keeps append-ordered rows (and all-NaN churned rows)
@@ -770,6 +783,7 @@ class PrometheusAPI:
             rows.sort(key=lambda ts: ts.raw)
             return rows
         if cached is not None:
+            _ROUTE["cache_partial"].inc()
             ec.tracer.printf("rollup cache: partial hit, computing from %d",
                              new_start)
             # single-column tails widen by one leading column (dropped
@@ -805,6 +819,7 @@ class PrometheusAPI:
             rows.sort(key=lambda ts: ts.raw)
             trust = True
         else:
+            _ROUTE["cache_miss"].inc()
             rows = exec_query(ec, q)
             trust = False
         if not getattr(self.storage, "last_partial", False):

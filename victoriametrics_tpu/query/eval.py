@@ -8,6 +8,8 @@ aggr(rollup(selector)) shapes when EvalConfig.tpu is set).
 
 from __future__ import annotations
 
+import threading
+
 import numpy as np
 
 from ..ops.rollup_np import RollupConfig
@@ -1811,16 +1813,71 @@ def _try_host_fused_aggr(ec: EvalConfig, ae) -> list[Timeseries] | None:
     return rows
 
 
+class _aggr_nesting:
+    """One aggregate's evaluation on this thread, as it nests in others.
+    An aggregate over an aggregate is served as any aggregate is: no
+    device shape takes an aggregate argument (_device_aggr_shape wants a
+    rollup), so the outer reduction runs on the host over the rows its
+    inner aggregates answer.  For such an outer aggregate this scope
+    counts vm_device_nested_aggr_total{inner=} once ("device" where every
+    inner aggregate was served fused on the device) and the inner rows in
+    vm_device_nested_rows_total, and times the outer's own work as the
+    query phase eval:outer_aggr.  An inner aggregate's evaluation keeps
+    the phase it has at top level (eval:other)."""
+
+    __slots__ = ("ae", "parent", "prev", "phase", "inner", "rows")
+    _tls = threading.local()
+
+    def __init__(self, ae: AggrFuncExpr):
+        self.ae = ae
+        self.inner, self.rows = [], 0
+
+    def __enter__(self):
+        self.prev = top = getattr(self._tls, "top", None)
+        self.parent = top if top is not None and \
+            any(a is self.ae for a in top.ae.args) else None
+        self.phase = None
+        if any(isinstance(a, AggrFuncExpr) for a in self.ae.args):
+            self._tls.top = self
+            self.phase = flightrec.phase("eval:outer_aggr")
+        elif self.parent is not None:
+            self.phase = flightrec.phase("eval:other")
+        if self.phase is not None:
+            self.phase.__enter__()
+        return self
+
+    def served(self, rows: list, fused: bool) -> list:
+        """`rows` as the aggregate answers them; `fused` where the device
+        served them fused, told to the outer aggregate it is an argument
+        of."""
+        if self.parent is not None:
+            self.parent.inner.append(fused)
+            self.parent.rows += len(rows)
+        return rows
+
+    def __exit__(self, *exc):
+        if self.phase is not None:
+            self.phase.__exit__(*exc)
+        if getattr(self._tls, "top", None) is self:
+            self._tls.top = self.prev
+            if exc[0] is None:
+                from .tpu_engine import count_nested_aggr
+                count_nested_aggr(bool(self.inner) and all(self.inner),
+                                  self.rows)
+        return False
+
+
 def _eval_aggr(ec: EvalConfig, ae: AggrFuncExpr) -> list[Timeseries]:
-    if ec.tpu is None:
-        return _eval_aggr_host(ec, ae)
-    from .tpu_engine import wide_hand_off
-    with wide_hand_off() as wide:
-        fused = _try_device_fused_aggr(ec, ae)
-        if fused is not None:
-            return fused
-        wide.to_host()
-        return _eval_aggr_host(ec, ae)
+    with _aggr_nesting(ae) as nesting:
+        if ec.tpu is None:
+            return nesting.served(_eval_aggr_host(ec, ae), False)
+        from .tpu_engine import wide_hand_off
+        with wide_hand_off() as wide:
+            fused = _try_device_fused_aggr(ec, ae)
+            if fused is not None:
+                return nesting.served(fused, True)
+            wide.to_host()
+            return nesting.served(_eval_aggr_host(ec, ae), False)
 
 
 def _eval_aggr_host(ec: EvalConfig, ae: AggrFuncExpr) -> list[Timeseries]:

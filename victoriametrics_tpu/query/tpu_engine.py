@@ -224,6 +224,14 @@ _WIDE_ROWS = {
     for path in ("host", "device")}
 # this thread's pending wide decline (`rows`) and open hand-off (`open`)
 _WIDE = threading.local()
+# aggregates whose argument is an aggregate (query/eval.py _aggr_nesting),
+# by where the inner aggregate was served: "device" fused on the device,
+# else "host"; and the rows the inner aggregates handed to the outer ones
+# (all 0 from import)
+_NESTED_AGGR = {inner: metricslib.REGISTRY.counter(metricslib.format_name(
+    "vm_device_nested_aggr_total", {"inner": inner}))
+    for inner in ("device", "host")}
+_NESTED_ROWS = metricslib.REGISTRY.counter("vm_device_nested_rows_total")
 # resident-window advances that fell back to a cold rebuild, by reason, and
 # advances under a dedup interval that dropped provisional samples before
 # appending (the two dedup reasons and the rewinds read 0 from import)
@@ -682,6 +690,14 @@ def count_wide_device(tiles, func: str, rows: int) -> None:
     window's advance."""
     if _increments(tiles[3], func):
         _WIDE_ROWS["device"].inc(rows)
+
+
+def count_nested_aggr(device: bool, rows: int) -> None:
+    """Count one aggregate over aggregates: `device` where every inner
+    aggregate was served fused on the device, `rows` the rows they handed
+    to the outer one."""
+    _NESTED_AGGR["device" if device else "host"].inc()
+    _NESTED_ROWS.inc(rows)
 
 
 class wide_hand_off:

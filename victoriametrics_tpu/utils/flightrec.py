@@ -286,7 +286,7 @@ def instant(name: str, arg=None) -> None:
 QUERY_PHASES = (
     "serve:admission", "serve:rows", "serve:json", "serve:send",
     "serve:other", "eval:other", "eval:transform", "eval:wide_host",
-    "fetch:wait",
+    "eval:outer_aggr", "fetch:wait",
     "cache:get", "cache:put", "cache:merge",
     "device:tile_build", "device:upload", "device:execute",
     "device:download", "device:compile")
